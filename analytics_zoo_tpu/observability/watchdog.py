@@ -2,7 +2,7 @@
 
 Two failure modes metrics alone cannot catch in time:
 
-* **stalls** — a hung collective, a wedged tunnel RPC, a deadlocked
+* **stalls** — a hung collective, a wedged device dispatch, a deadlocked
   queue: the process is alive, every gauge is frozen, and nothing
   fires.  `Watchdog` is a daemon thread fed heartbeats (`beat()`) by
   the hot loops (one per training step / decode iteration); when no

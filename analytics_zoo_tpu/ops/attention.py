@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 
 def dot_product_attention(q, k, v, mask=None, causal: bool = False,
@@ -172,10 +173,35 @@ def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
     if block_gather is None:
         block_gather = tuned_paged_block_gather(
             bs, s, h, d, k_pool.dtype, mb=block_tables.shape[1])
-    return paged_decode_pallas(
-        q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len,
-        k_scale=k_scale, v_scale=v_scale, block_gather=block_gather,
-        interpret=interpret)
+
+    def kernel(q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len,
+               *scales):
+        k_scale, v_scale = scales or (None, None)
+        return paged_decode_pallas(
+            q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len,
+            k_scale=k_scale, v_scale=v_scale,
+            block_gather=block_gather, interpret=interpret)
+
+    args = (q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len)
+    if k_scale is not None:
+        args += (k_scale, v_scale)
+    from analytics_zoo_tpu.parallel.sharding import (
+        mesh_axis_size, shard_map_compat, traced_mesh)
+    mesh = traced_mesh()
+    if mesh is not None:
+        # a program over several devices (the tp engine) carries the
+        # kernel in a shard_map: attention is head-local, so each
+        # device runs it over its own heads of the head-sharded pool
+        # (serving/distributed/tp.py) with the tables, lengths and
+        # per-token scales whole
+        n_tp = mesh_axis_size("tp", mesh)
+        tp = "tp" if n_tp > 1 and h % n_tp == 0 else None
+        lane, pool = P(None, tp, None), P(None, None, tp, None)
+        kernel = shard_map_compat(
+            kernel, mesh=mesh,
+            in_specs=(lane,) * 3 + (pool,) * 2 + (P(),) * (len(args) - 5),
+            out_specs=lane)
+    return kernel(*args)
 
 
 def paged_verify_attention(q, new_k, new_v, k_pool, v_pool,

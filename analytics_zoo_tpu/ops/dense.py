@@ -50,6 +50,12 @@ def dense_bias_gelu(x, w, b, *, impl: str = "auto",
     m = 1
     for s in x.shape[:-1]:
         m *= s
+    # a program over several devices carries the kernel in a shard_map
+    # (parallel/sharding.py): each device multiplies its share of the
+    # rows by the whole weight, so that share is what has to tile
+    from analytics_zoo_tpu.parallel.sharding import place_row_kernel
+    place, shards = place_row_kernel(x)
+    m //= shards
     if impl == "auto":
         impl = "pallas" if _pallas_supported(m, k, n) else "xla"
     if impl == "xla":
@@ -70,9 +76,13 @@ def dense_bias_gelu(x, w, b, *, impl: str = "auto",
         block_m = block_m or cfg["block_m"]
         block_n = block_n or cfg["block_n"]
         block_k = block_k or cfg["block_k"]
-    return fused_dense.dense_bias_gelu_pallas(
-        x, w, b, block_m=block_m, block_n=block_n, block_k=block_k,
-        interpret=interpret)
+
+    def kernel(x, w, b):
+        return fused_dense.dense_bias_gelu_pallas(
+            x, w, b, block_m=block_m, block_n=block_n,
+            block_k=block_k, interpret=interpret)
+
+    return place(kernel)(x, w, b)
 
 
 def bias_gelu_candidates(m: int, k: int, n: int):

@@ -66,6 +66,12 @@ def layer_norm(x, scale, bias, *, eps: float = 1e-6, impl: str = "auto",
         rows *= s
     if out_dtype is None:
         out_dtype = jnp.result_type(x.dtype, scale.dtype, bias.dtype)
+    # a program over several devices carries the kernel in a shard_map
+    # (parallel/sharding.py): each device normalizes its share of the
+    # rows, so that share is what has to tile
+    from analytics_zoo_tpu.parallel.sharding import place_row_kernel
+    place, shards = place_row_kernel(x)
+    rows //= shards
     if impl == "auto":
         impl = "pallas" if _pallas_supported(rows, d) else "xla"
     if impl == "xla":
@@ -84,9 +90,13 @@ def layer_norm(x, scale, bias, *, eps: float = 1e-6, impl: str = "auto",
                         if r <= rows],
             bench=_make_bench(rows, d, out_dtype))
         block_rows = cfg["block_rows"]
-    return ln_kernel.layer_norm_pallas(
-        x, scale, bias, eps=eps, block_rows=block_rows,
-        out_dtype=out_dtype, interpret=interpret)
+
+    def kernel(x, scale, bias):
+        return ln_kernel.layer_norm_pallas(
+            x, scale, bias, eps=eps, block_rows=block_rows,
+            out_dtype=out_dtype, interpret=interpret)
+
+    return place(kernel)(x, scale, bias)
 
 
 def _make_bench(rows: int, d: int, dtype):

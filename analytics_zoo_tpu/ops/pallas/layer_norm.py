@@ -5,10 +5,12 @@ that each round-trip the [rows, d] activation through HBM; this kernel
 streams every row block through VMEM exactly once per pass.  The
 forward emits the per-row mean and rstd (f32 [rows, 1]) so the
 backward never recomputes the statistics; the backward emits dx plus
-PER-BLOCK partial sums for dscale/dbias ([num_blocks, d] f32, reduced
-to [d] by one tiny XLA sum outside the kernel — emitting partials
-keeps every grid step's output block disjoint, so the kernel needs no
-cross-step accumulation state).
+PER-BLOCK partial sums for dscale/dbias ([num_blocks, 1, d] f32,
+reduced to [d] by one tiny XLA sum outside the kernel — emitting
+partials keeps every grid step's output block disjoint, so the kernel
+needs no cross-step accumulation state; the unit middle axis makes
+each step's (1, d) block the array's own last two dims, which is what
+Mosaic's (8, 128) block rule asks of a one-row block).
 
 Numerics match `flax.linen.LayerNorm` defaults on purpose (same
 formula, same order): stats in f32 with the fast-variance form
@@ -111,8 +113,8 @@ def _ln_bwd(x, scale, mean, rstd, g, *, block_rows: int, interpret: bool):
     dx, dscale_p, dbias_p = pl.pallas_call(
         _ln_bwd_kernel,
         out_shape=[jax.ShapeDtypeStruct((rows, d), x.dtype),
-                   jax.ShapeDtypeStruct((nb, d), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, d), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, 1, d), jnp.float32)],
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0),
@@ -129,14 +131,14 @@ def _ln_bwd(x, scale, mean, rstd, g, *, block_rows: int, interpret: bool):
         out_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (i, 0),
+            pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (i, 0),
+            pl.BlockSpec((None, 1, d), lambda i: (i, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         interpret=interpret,
     )(x, scale.reshape(1, d), mean, rstd, g)
-    return dx, dscale_p.sum(axis=0), dbias_p.sum(axis=0)
+    return dx, dscale_p.sum(axis=(0, 1)), dbias_p.sum(axis=(0, 1))
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

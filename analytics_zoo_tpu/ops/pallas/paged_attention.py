@@ -87,7 +87,7 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
     # scalar prefetch: tbl_ref [S, MB] block tables, cl_ref [S] ctx
     # lengths.  q/nk/nv_ref: [1, h, d] lane tiles.  rest: g gathered
     # K blocks [1, bs, h, d], g V blocks, (g k-scale + g v-scale
-    # [1, bs] when quantized), then o_ref [1, h, d] and the o/m/l
+    # [1, bs] rows when quantized), then o_ref [1, h, d] and the o/m/l
     # VMEM scratch carried across the block axis.
     rest = list(rest)
     ks = [rest.pop(0) for _ in range(g)]
@@ -118,19 +118,24 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
     # shapes stay static, which is the zero-recompile contract)
     @pl.when(j * g * bs < cl)
     def _compute():
-        qv = q_ref[0].astype(jnp.float32)
+        # Mosaic's matmul wants the batch (head) dimension LEADING on
+        # both operands and a non-contracting dimension on each: q
+        # rides as [h, 1, d] and the staged [bs, h, d] tile is turned
+        # to [h, bs, d] in VMEM (the pool's layout is untouched)
+        qv = q_ref[0].astype(jnp.float32)[:, None, :]    # [h, 1, d]
         for i in range(g):
-            k = ks[i][0].astype(jnp.float32)             # [bs, h, d]
-            v = vs[i][0].astype(jnp.float32)
+            kt = jnp.swapaxes(ks[i][0].astype(jnp.float32), 0, 1)
+            vt = jnp.swapaxes(vs[i][0].astype(jnp.float32), 0, 1)
             pos = (j * g + i) * bs + jax.lax.broadcasted_iota(
                 jnp.int32, (1, bs), 1)
             valid = pos < cl                             # [1, bs]
             s = jax.lax.dot_general(
-                qv, k, (((1,), (2,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32) * scale  # [h, bs]
+                qv, kt, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32
+            )[:, 0, :] * scale                           # [h, bs]
             if quantized:
                 # dequant-on-read, folded into the score columns
-                s = s * kscl[i][0:1]
+                s = s * kscl[i][...]
             s = jnp.where(valid, s, NEG_INF)
             m_prev = m_scr[:, 0:1]
             l_prev = l_scr[:, 0:1]
@@ -140,10 +145,10 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
             if quantized:
-                p = p * vscl[i][0:1]
+                p = p * vscl[i][...]
             o_scr[:] = o_scr[:] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32)      # [h, d]
+                p[:, None, :], vt, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)[:, 0, :]  # [h, d]
             m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
             l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
@@ -196,18 +201,21 @@ def paged_decode_pallas(q, new_k, new_v, k_pool, v_pool, block_tables,
                                                0, 0, 0), i=i))
 
     def _scale_spec(i):
+        # over the [num_blocks, 1, bs] view below: a (1, bs) block of a
+        # (num_blocks, bs) array breaks Mosaic's (8, 128) block rule,
+        # while here it is the array's own last two dims
         return pl.BlockSpec(
-            (1, bs),
-            partial(lambda si, j, tbl, cl, i: (tbl[si, j * g + i], 0),
-                    i=i))
+            (None, 1, bs),
+            partial(lambda si, j, tbl, cl, i: (tbl[si, j * g + i],
+                                               0, 0), i=i))
 
     in_specs = ([lane, lane, lane]
                 + [_pool_spec(i) for i in range(g)] * 2)
     args = [q, new_k, new_v] + [k_pool] * g + [v_pool] * g
     if quantized:
         in_specs += [_scale_spec(i) for i in range(g)] * 2
-        args += [k_scale.astype(jnp.float32)] * g \
-            + [v_scale.astype(jnp.float32)] * g
+        args += [k_scale.astype(jnp.float32).reshape(nb, 1, bs)] * g \
+            + [v_scale.astype(jnp.float32).reshape(nb, 1, bs)] * g
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -24,17 +24,58 @@ from analytics_zoo_tpu.common.context import DATA_AXES, OrcaContext
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs,
                      check_vma: bool = False):
-    """`jax.shard_map` across jax versions: newer jax exposes it
-    top-level with `check_vma`; older releases (e.g. 0.4.x) only have
-    `jax.experimental.shard_map.shard_map`, where the same knob is
-    spelled `check_rep`.  Every shard_map consumer in the package goes
-    through this shim so the parallel runtimes run on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
+    """`jax.shard_map` with the package's default: no replication
+    (`vma`) checking — the bodies here hold Pallas kernels and explicit
+    collectives, neither of which carries replication rules.  Every
+    shard_map consumer in the package goes through this."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
+
+
+def declare_mesh(mesh: Mesh):
+    """Context for the body of a jitted function that GSPMD partitions
+    over `mesh`: inside it the code being traced can see which mesh
+    that is (`traced_mesh`).  The owners of multi-device programs —
+    SPMDEngine around the model call, the tp engine around its steps —
+    enter it; nothing else about the trace changes."""
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
+
+
+def traced_mesh():
+    """The mesh GSPMD partitions the program now being traced over, or
+    None for a one-device program and inside a shard_map (where the
+    code is already per-device).  Mosaic refuses a Pallas kernel that
+    GSPMD would have to partition ("Mosaic kernels cannot be
+    automatically partitioned"), so the kernel dispatchers in `ops/`
+    ask this and put their kernel in a shard_map of their own."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return None
+    return mesh
+
+
+def place_row_kernel(x):
+    """Where a row-wise Pallas kernel `fn(x, *params) -> y` goes in the
+    program now being traced.  Returns `(place, shards)`: `place(fn)`
+    is `fn` itself in a one-device program, and in a program over a
+    mesh a shard_map over every mesh axis — x and y split on dim 0 over
+    the data axes where those divide it (whole on every device
+    otherwise: a serving mesh has no data axis), the parameters whole
+    on every device; `shards` is the number of pieces dim 0 is split
+    into, so the caller can ask whether one piece tiles."""
+    mesh = traced_mesh() if x.ndim > 1 else None
+    if mesh is None:
+        return (lambda fn: fn), 1
+    axes, shards = data_axes(mesh), data_parallelism(mesh)
+    if not axes or x.shape[0] % shards:
+        axes, shards = None, 1
+    spec = P(axes)
+
+    def place(fn):
+        return lambda x, *params: shard_map_compat(
+            fn, mesh=mesh, in_specs=(spec,) + (P(),) * len(params),
+            out_specs=spec)(x, *params)
+    return place, shards
 
 
 def _present_axes(mesh: Mesh, axes: Sequence[str]) -> Tuple[str, ...]:

@@ -35,12 +35,14 @@ degrades per-parameter instead of failing the whole model.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from analytics_zoo_tpu.parallel.sharding import (
+    declare_mesh,
     infer_param_shardings,
     mesh_axis_size,
     shard_map_compat,
@@ -142,7 +144,15 @@ class TensorParallelPlacement:
         outputs feed the next step with identical layouts and the
         zero-recompile contract holds with tp armed."""
         outs = (self.kv_sharding,) + (self.replicated,) * (n_outputs - 1)
-        return jax.jit(fn, donate_argnums=donate_argnums,
+
+        @functools.wraps(fn)
+        def step(*args):
+            # traced knowing the mesh, so the paged kernel can place
+            # itself over "tp" (ops/attention.py)
+            with declare_mesh(self.mesh):
+                return fn(*args)
+
+        return jax.jit(step, donate_argnums=donate_argnums,
                        out_shardings=outs)
 
     # -- collectives / introspection ----------------------------------

@@ -1,0 +1,213 @@
+"""The Pallas kernels of the training and generation paths, compiled
+for a TPU v5e that is described and not attached (the chip's own
+compiler is installed here) at the shapes `chip_smoke.py` runs.
+
+Interpret mode accepts kernels Mosaic refuses — a `dot_general` with
+the batch dim in the middle, a one-row block of a many-row array — so
+these compiles are what keeps a later PR from shipping a kernel that
+cannot start on the chip.  A compile is not a run: nothing here says
+anything about results or times.
+
+All in ONE file, the topology described inside a module-scoped fixture:
+only one process may hold the TPU library, and under xdist
+(`--dist loadfile`) this file goes to one worker.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # or the compiler logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip (the next run warns
+    # and compiles again): cache off around these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# ---------------------------------------------------------------------
+# cases: name -> builder(place) returning (fn, abstract args); `place`
+# maps (shape, dtype[, spec]) to a ShapeDtypeStruct on the described
+# device(s)
+# ---------------------------------------------------------------------
+
+def _paged(pool_dtype, block_gather, lanes=8, d=64, bs=16):
+    """The engine's decode attention, by default at the smoke's serve
+    shapes: 8 bf16 lanes, 12 heads x 64, block 16, tables for a 1024
+    context, the full lanes*blocks+1-block pool."""
+    def build(place):
+        from analytics_zoo_tpu.ops.pallas.paged_attention import (
+            paged_decode_pallas)
+        h, mb = 12, 1024 // bs
+        nb = lanes * mb + 1
+        lane = place((lanes, h, d), jnp.bfloat16)
+        pool = place((nb, bs, h, d), pool_dtype)
+        args = [lane, lane, lane, pool, pool,
+                place((lanes, mb), jnp.int32), place((lanes,), jnp.int32)]
+        if pool_dtype == jnp.int8:
+            args += [place((nb, bs), jnp.float32)] * 2
+
+        def fn(q, nk, nv, kp, vp, tbl, cl, *scales):
+            ks, vs = scales or (None, None)
+            return paged_decode_pallas(
+                q, nk, nv, kp, vp, tbl, cl, k_scale=ks, v_scale=vs,
+                block_gather=block_gather, interpret=False)
+        return fn, args
+    return build
+
+
+def _layer_norm(dtype):
+    """BERT-base's LayerNorm (batch 32 x seq 128 rows, hidden 768),
+    forward and backward."""
+    def build(place):
+        from analytics_zoo_tpu.ops.pallas.layer_norm import (
+            layer_norm_pallas)
+
+        def loss(x, scale, bias):
+            return layer_norm_pallas(
+                x, scale, bias, interpret=False
+            ).astype(jnp.float32).sum()
+        return (jax.grad(loss, argnums=(0, 1, 2)),
+                [place((4096, 768), dtype),
+                 place((768,), jnp.float32),
+                 place((768,), jnp.float32)])
+    return build
+
+
+def _bias_gelu(place):
+    """BERT-base's fc1 (768 -> 3072) with the GELU epilogue, forward
+    (its backward is plain XLA)."""
+    from analytics_zoo_tpu.ops.pallas.fused_dense import (
+        dense_bias_gelu_pallas)
+    return (lambda x, w, b: dense_bias_gelu_pallas(
+                x, w, b, interpret=False),
+            [place((4096, 768), jnp.bfloat16),
+             place((768, 3072), jnp.bfloat16),
+             place((3072,), jnp.bfloat16)])
+
+
+def _flash(b, t):
+    """Flash attention forward and backward at BERT-base's heads."""
+    def build(place):
+        from analytics_zoo_tpu.ops.pallas.flash_attention import (
+            flash_attention)
+
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, interpret=False).astype(jnp.float32).sum()
+        qkv = place((b, t, 12, 64), jnp.bfloat16)
+        return jax.grad(loss, argnums=(0, 1, 2)), [qkv, qkv, qkv]
+    return build
+
+
+def _paged_tp(place):
+    """The paged kernel as the tp=4 engine places it: the dispatcher
+    itself (`ops.attention.paged_decode_attention`, impl pinned since
+    `auto` asks the backend, which is the CPU here) inside a program
+    GSPMD partitions over a 4-device mesh, pool and lanes head-sharded
+    as `serving/distributed/tp.py` shards them.  Mosaic refuses a
+    kernel GSPMD would have to partition, so this compiles only while
+    the dispatcher carries the kernel in a shard_map."""
+    from analytics_zoo_tpu.ops.attention import paged_decode_attention
+    from analytics_zoo_tpu.parallel.sharding import declare_mesh
+    s, h, d, bs, mb = 8, 12, 64, 16, 64
+    nb = s * mb + 1
+    lane = place((s, h, d), jnp.bfloat16, P(None, "tp", None))
+    pool = place((nb, bs, h, d), jnp.bfloat16,
+                 P(None, None, "tp", None))
+    mesh = lane.sharding.mesh
+
+    def fn(q, nk, nv, kp, vp, tbl, cl):
+        with declare_mesh(mesh):
+            return paged_decode_attention(
+                q, nk, nv, kp, vp, tbl, cl, impl="pallas",
+                block_gather=8, interpret=False)
+    return fn, [lane, lane, lane, pool, pool,
+                place((s, mb), jnp.int32), place((s,), jnp.int32)]
+
+
+CASES = {
+    "paged_bf16_g1": _paged(jnp.bfloat16, 1),
+    # 8 = what ops/tuning/default_tables.json names for this key
+    "paged_bf16_g8": _paged(jnp.bfloat16, 8),
+    "paged_int8_g8": _paged(jnp.int8, 8),
+    "layer_norm_fwd_bwd_bf16": _layer_norm(jnp.bfloat16),
+    "layer_norm_fwd_bwd_f32": _layer_norm(jnp.float32),
+    "bias_gelu_fwd_bf16": _bias_gelu,
+    "flash_fwd_bwd_b32_t128": _flash(32, 128),
+    "flash_fwd_bwd_b8_t512": _flash(8, 512),
+    "paged_bf16_g8_tp4": _paged_tp,
+}
+
+
+def _one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding)
+
+
+def _assert_kernel_compiles(build, place, what):
+    fn, args = build(place)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), (
+        f"{what}: compiled for v5e without the Pallas kernel in it")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(topo, case):
+    if case.endswith("_tp4"):
+        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("tp",))
+
+        def place(shape, dtype, spec=P()):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=NamedSharding(mesh, spec))
+    else:
+        place = _one_chip(topo)
+    _assert_kernel_compiles(CASES[case], place, case)
+
+
+def test_tuning_table_block_gathers_compile_for_v5e(topo):
+    """Every `block_gather` that `ops/tuning/default_tables.json` names
+    for a `paged_decode|tpu|*` key is one Mosaic accepts at that key's
+    shape (the rows are warm starts nobody measured; this keeps them
+    at least runnable)."""
+    import json
+    import re
+
+    from analytics_zoo_tpu.ops.tuning.autotuner import DEFAULT_TABLE_PATH
+    with open(DEFAULT_TABLE_PATH) as f:
+        entries = json.load(f)["entries"]
+    rows = {k: v for k, v in entries.items()
+            if k.startswith("paged_decode|tpu|")}
+    assert rows, "no paged_decode|tpu rows left in the default table"
+    for key, row in rows.items():
+        _, _, dtype, dims = key.split("|")
+        dim = {k: int(v) for k, v in re.findall(r"(\w+)=(\d+)", dims)}
+        build = _paged(jnp.dtype(dtype), row["config"]["block_gather"],
+                       lanes=dim["lanes"], d=dim["d"], bs=dim["bs"])
+        _assert_kernel_compiles(build, _one_chip(topo), key)
