@@ -53,6 +53,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from analytics_zoo_tpu.observability import tracing
 from analytics_zoo_tpu.observability.registry import (
     get_registry,
     now,
@@ -84,10 +85,34 @@ def _sample_every() -> int:
     return max(1, int(OrcaContext.goodput_sample_every))
 
 
+class _Phase:
+    """One phase of a step: the profiler span while it is open, the
+    record's lap when it closes."""
+
+    __slots__ = ("_rec", "_bucket", "_span")
+
+    def __init__(self, rec: "_StepRecord", name: str,
+                 bucket: Optional[str]):
+        self._rec = rec
+        self._bucket = bucket
+        self._span = tracing.phase(name)
+
+    def __enter__(self) -> "_StepRecord":
+        self._span.__enter__()
+        return self._rec
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._rec.lap(self._bucket)
+
+
 class _StepRecord:
     """One in-flight step.  `lap(bucket)` attributes the time since the
     previous lap (or `begin`) to `bucket` (None discards it into the
-    residual); `end()` closes the step and folds the residual into
+    residual); `with phase(name, bucket):` is the same boundary with a
+    name known when it opens, so that the phase is also a span of a
+    profiler trace (`tracing.phase`) — one list of boundaries gives
+    both; `end()` closes the step and folds the residual into
     ``overhead`` when the step was fenced."""
 
     __slots__ = ("_clock", "_t0", "_t_last", "_laps", "fenced", "cold",
@@ -113,6 +138,11 @@ class _StepRecord:
         if bucket is not None:
             self._laps[bucket] = self._laps.get(bucket, 0.0) + dt
         return dt
+
+    def phase(self, name: str, bucket: Optional[str] = None) -> _Phase:
+        """The enclosed block as the span ``azt:<name>`` of a profiler
+        trace; on leaving it, `lap(bucket)`."""
+        return _Phase(self, name, bucket)
 
     def end(self) -> None:
         wall = now() - self._t0

@@ -13,6 +13,14 @@ served by the serving frontend's GET /spans), are recorded as a
 duration histogram `span_<name>_seconds` in the global MetricsRegistry,
 and are appended to the JSONL event sink when
 `OrcaContext.observability_dir` is set.
+
+Every span is also a `jax.profiler.TraceAnnotation` named
+``azt:<name>``: while a profiler session is open (an operator's, the
+benchmark's — the program opens none) the span lands on the host plane
+of the profiler's own trace, on the clock of the device's events, so an
+idle gap of the device can be put down to the span that was open in it.
+With no session open the annotation records nothing.  `phase(name)` is
+that annotation alone, for the phases of a hot loop.
 """
 
 from __future__ import annotations
@@ -81,6 +89,26 @@ class Span:
         return d
 
 
+#: what every span is called in a profiler trace, before its own name
+TRACE_PREFIX = "azt:"
+_annotation = None
+
+
+def phase(name: str):
+    """The light form of a span, for a phase of a hot loop (a dozen a
+    round): a context manager that is the profiler annotation
+    ``azt:<name>`` and nothing else — no `Span`, no ring entry, no
+    histogram, no lock, no clock read.  It shows only in a profiler
+    trace, never in `recent_spans`."""
+    global _annotation
+    if _annotation is None:
+        # here and not at the top: a host-only consumer of this package
+        # imports no jax, and the import initialises no backend
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(TRACE_PREFIX + name)
+
+
 def current_span() -> Optional[Span]:
     """The innermost open span of this thread/context (None outside any
     `trace` block).  Capture it before handing work to another thread
@@ -126,7 +154,8 @@ def trace(name: str, parent: Any = _MISSING, record_metric: bool = True,
     span = Span(name, parent=p, attrs=attrs)
     token = _CURRENT.set(span)
     try:
-        yield span
+        with phase(name):
+            yield span
     except BaseException as e:
         span.error = f"{type(e).__name__}: {e}"
         raise

@@ -43,6 +43,7 @@ from analytics_zoo_tpu.observability import (
     profiling,
     step_clock,
     trace,
+    tracing,
 )
 from analytics_zoo_tpu.resilience.faults import fault_point
 from analytics_zoo_tpu.parallel.sharding import (
@@ -807,10 +808,10 @@ class SPMDEngine:
             # happened inside the PREVIOUS step's device window); at
             # depth 0 it assembles + device_puts inline, so the whole
             # host-input cost lands in this lap
-            batch = pre.pop()
+            with rec.phase("spmd.input_wait", "host_input"):
+                batch = pre.pop()
             if batch is None:
                 break
-            rec.lap("host_input")
             # fault-injection site: "raise"/"crash" kill the worker
             # here, "stall" wedges the loop for the watchdogs, "nan"
             # poisons this batch host-side (zero-recompile — see
@@ -834,41 +835,49 @@ class SPMDEngine:
                 # double buffering: assemble + device_put the NEXT
                 # batch while THIS step runs on the device — on a
                 # fenced step the staging wall hides inside the
-                # device_compute wait below
-                pre.stage(1)
+                # device's own time, and is counted with it (an
+                # unfenced step keeps no lap but the input's)
+                with rec.phase("spmd.stage_next", "device_compute"):
+                    pre.stage(1)
             if rec.fenced:
                 # opt-in / sampled: blocking per step defeats async
                 # dispatch, but gives true per-step wall time
                 # (reference torch_runner profile=True semantics) and
                 # the goodput device bucket
-                jax.block_until_ready(stats["_count"])
-                rec.lap("device_compute")
-                bsz = jax.tree_util.tree_leaves(batch)[0].shape[0]
-                profiling.record_work(
-                    "train_step" if train else "eval_step",
-                    now() - t0, tokens=bsz,
-                    flops=profiling.train_step_flops(
-                        self.param_count, bsz, train))
-            if profile:
-                self.last_profile.append(
-                    {"step": step,
-                     "step_time_s": now() - t0})
-            if sentinel:
-                self._sentinel_check(stats, batch, step)
-            if totals is None:
-                totals = jax.tree_util.tree_map(jnp.zeros_like, stats)
-            totals = self._accum(totals, stats)
-            flight_recorder.record("spmd_step", loop=kind, step=step)
-            if self.watchdog is not None:
-                self.watchdog.beat()
-            if train and on_step is not None:
-                on_step(step)
-            rec.end()
+                with rec.phase("spmd.fence", "device_compute"):
+                    jax.block_until_ready(stats["_count"])
+            with rec.phase("spmd.account"):
+                if rec.fenced:
+                    bsz = jax.tree_util.tree_leaves(batch)[0].shape[0]
+                    profiling.record_work(
+                        "train_step" if train else "eval_step",
+                        now() - t0, tokens=bsz,
+                        flops=profiling.train_step_flops(
+                            self.param_count, bsz, train))
+                if profile:
+                    self.last_profile.append(
+                        {"step": step,
+                         "step_time_s": now() - t0})
+                if sentinel:
+                    self._sentinel_check(stats, batch, step)
+                if totals is None:
+                    totals = jax.tree_util.tree_map(jnp.zeros_like,
+                                                    stats)
+                totals = self._accum(totals, stats)
+                flight_recorder.record("spmd_step", loop=kind,
+                                       step=step)
+                if self.watchdog is not None:
+                    self.watchdog.beat()
+                if train and on_step is not None:
+                    on_step(step)
+            with tracing.phase("spmd.account"):
+                rec.end()       # the goodput commit is accounting too
         if train:
             self.host_step = step
         if totals is None:
             return {}
-        return self._fetch_totals(totals)
+        with tracing.phase("spmd.epoch_end"):
+            return self._fetch_totals(totals)
 
     def _epoch_unroll(self, steps: int) -> int:
         """Resolve OrcaContext.epoch_scan_unroll for an epoch of `steps`.

@@ -78,6 +78,7 @@ from analytics_zoo_tpu.observability import (
     profiling,
     request_log,
     step_clock,
+    tracing,
 )
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
@@ -1013,41 +1014,54 @@ class GenerationEngine:
         if reason:
             self._finish(seq, reason)
 
+    def _end_step(self, rec) -> None:
+        """Close a step record: the goodput commit (counters, the
+        timeline ring, the memory sampler) is accounting like the rest,
+        and shows as such in a profiler trace."""
+        with tracing.phase("generation.account"):
+            rec.end()
+
     def _prefill_seq(self, seq: Sequence) -> None:
         rec = self._clock_prefill.begin(force_fence=True)
-        ctx = seq.prompt + seq.generated
-        L = len(ctx)
-        bucket = self.scheduler.bucket_for(L)
-        MB = self.scheduler.max_blocks_per_seq
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :L] = ctx
-        table = np.zeros(MB, np.int32)
-        table[:len(seq.block_table)] = seq.block_table
-        rec.lap("host_input")
-        t0 = now()
-        rec.cold = ("prefill", bucket) not in self._goodput_warm
-        kv, scl, nxt, _ = self._prefill_jit(
-            self.params, self.cache.kv, self._kv_scale,
-            jnp.asarray(tokens), jnp.int32(L), jnp.asarray(table),
-            jnp.full(1, seq.temperature, jnp.float32),
-            jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
-        self._store_kv_state(kv, scl)
-        rec.lap(None)
-        nxt = int(nxt)            # token fetch = device fence
-        rec.lap("device_compute")
-        self._goodput_warm.add(("prefill", bucket))
-        dur = now() - t0
-        self._h_prefill.record(dur, L)
-        profiling.record_work(
-            "prefill", dur, tokens=L,
-            flops=self._flops.prefill(L) if self._flops else 0.0)
-        self._c_prefill_tokens.inc(L)
-        request_log.attribute(seq.request_id, "prefill_compute", dur)
-        request_log.event(seq.request_id, "prefill", bucket=bucket,
-                          tokens=L, dur_s=round(dur, 6),
-                          resumed=seq.n_preempted > 0)
-        self._emit(seq, nxt)
-        rec.end()
+        with tracing.phase("generation.prefill"):
+            with rec.phase("generation.stage", "host_input"):
+                ctx = seq.prompt + seq.generated
+                L = len(ctx)
+                bucket = self.scheduler.bucket_for(L)
+                MB = self.scheduler.max_blocks_per_seq
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :L] = ctx
+                table = np.zeros(MB, np.int32)
+                table[:len(seq.block_table)] = seq.block_table
+            t0 = now()
+            rec.cold = ("prefill", bucket) not in self._goodput_warm
+            with rec.phase("generation.dispatch"):
+                kv, scl, nxt, _ = self._prefill_jit(
+                    self.params, self.cache.kv, self._kv_scale,
+                    jnp.asarray(tokens), jnp.int32(L),
+                    jnp.asarray(table),
+                    jnp.full(1, seq.temperature, jnp.float32),
+                    jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
+                self._store_kv_state(kv, scl)
+            with rec.phase("generation.fetch", "device_compute"):
+                nxt = int(nxt)            # token fetch = device fence
+            with rec.phase("generation.account"):
+                self._goodput_warm.add(("prefill", bucket))
+                dur = now() - t0
+                self._h_prefill.record(dur, L)
+                profiling.record_work(
+                    "prefill", dur, tokens=L,
+                    flops=self._flops.prefill(L) if self._flops else 0.0)
+                self._c_prefill_tokens.inc(L)
+                request_log.attribute(seq.request_id, "prefill_compute",
+                                      dur)
+                request_log.event(seq.request_id, "prefill",
+                                  bucket=bucket, tokens=L,
+                                  dur_s=round(dur, 6),
+                                  resumed=seq.n_preempted > 0)
+            with rec.phase("generation.emit"):
+                self._emit(seq, nxt)
+            self._end_step(rec)
 
     # ------------------------------------------------------------------
     # chunked / prefix-cached prefill (the chunk-step path)
@@ -1088,52 +1102,57 @@ class GenerationEngine:
         commits the prompt's full blocks to the prefix cache, samples
         the first new token and flips the lane to running."""
         rec = self._clock_prefill.begin(force_fence=True)
-        ctx = seq.prompt + seq.generated
-        L = seq.context_len
-        start = seq.prefill_pos
-        real = min(bucket, L - start)
-        MB = self.scheduler.max_blocks_per_seq
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :real] = ctx[start:start + real]
-        table = np.zeros(MB, np.int32)
-        table[:len(seq.block_table)] = seq.block_table
-        rec.lap("host_input")
-        t0 = now()
-        rec.cold = ("chunk", bucket) not in self._goodput_warm
-        kv, scl, nxt, _ = self._chunk_jit(
-            self.params, self.cache.kv, self._kv_scale,
-            jnp.asarray(tokens), jnp.int32(start), jnp.int32(real),
-            jnp.asarray(table),
-            jnp.full(1, seq.temperature, jnp.float32),
-            jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
-        self._store_kv_state(kv, scl)
-        rec.lap(None)
-        nxt = int(nxt)            # token fetch = device fence
-        rec.lap("device_compute")
-        self._goodput_warm.add(("chunk", bucket))
-        dur = now() - t0
-        self._h_prefill.record(dur, real)
-        profiling.record_work(
-            "chunk_prefill", dur, tokens=real,
-            flops=(self._flops.prefill(real, ctx_start=start)
-                   if self._flops else 0.0))
-        self._c_prefill_tokens.inc(real)
-        seq.prefill_pos = start + real
-        request_log.attribute(seq.request_id, "prefill_compute", dur)
-        request_log.event(seq.request_id, "prefill", bucket=bucket,
-                          tokens=real, start=start,
-                          dur_s=round(dur, 6),
-                          resumed=seq.n_preempted > 0)
-        if seq.prefill_pos >= L:
-            if self.prefix_cache is not None:
-                # the prompt's KV is now fully written: publish its
-                # full blocks for reuse (deduping against identical
-                # prefixes committed since this lane's lookup)
-                seq.block_table = self.prefix_cache.commit(
-                    seq.prompt, seq.block_table)
-            seq.status = "running"
-            self._emit(seq, nxt)
-        rec.end()
+        with tracing.phase("generation.prefill"):
+            with rec.phase("generation.stage", "host_input"):
+                ctx = seq.prompt + seq.generated
+                L = seq.context_len
+                start = seq.prefill_pos
+                real = min(bucket, L - start)
+                MB = self.scheduler.max_blocks_per_seq
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :real] = ctx[start:start + real]
+                table = np.zeros(MB, np.int32)
+                table[:len(seq.block_table)] = seq.block_table
+            t0 = now()
+            rec.cold = ("chunk", bucket) not in self._goodput_warm
+            with rec.phase("generation.dispatch"):
+                kv, scl, nxt, _ = self._chunk_jit(
+                    self.params, self.cache.kv, self._kv_scale,
+                    jnp.asarray(tokens), jnp.int32(start),
+                    jnp.int32(real), jnp.asarray(table),
+                    jnp.full(1, seq.temperature, jnp.float32),
+                    jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
+                self._store_kv_state(kv, scl)
+            with rec.phase("generation.fetch", "device_compute"):
+                nxt = int(nxt)            # token fetch = device fence
+            with rec.phase("generation.account"):
+                self._goodput_warm.add(("chunk", bucket))
+                dur = now() - t0
+                self._h_prefill.record(dur, real)
+                profiling.record_work(
+                    "chunk_prefill", dur, tokens=real,
+                    flops=(self._flops.prefill(real, ctx_start=start)
+                           if self._flops else 0.0))
+                self._c_prefill_tokens.inc(real)
+                seq.prefill_pos = start + real
+                request_log.attribute(seq.request_id, "prefill_compute",
+                                      dur)
+                request_log.event(seq.request_id, "prefill",
+                                  bucket=bucket, tokens=real,
+                                  start=start, dur_s=round(dur, 6),
+                                  resumed=seq.n_preempted > 0)
+            with rec.phase("generation.emit"):
+                if seq.prefill_pos >= L:
+                    if self.prefix_cache is not None:
+                        # the prompt's KV is now fully written: publish
+                        # its full blocks for reuse (deduping against
+                        # identical prefixes committed since this
+                        # lane's lookup)
+                        seq.block_table = self.prefix_cache.commit(
+                            seq.prompt, seq.block_table)
+                    seq.status = "running"
+                    self._emit(seq, nxt)
+            self._end_step(rec)
 
     # ------------------------------------------------------------------
     # host-tier restore (the device half — prefix_cache.restore calls
@@ -1264,176 +1283,207 @@ class GenerationEngine:
         riders = [seq for seq in self.scheduler.running()
                   if seq.temperature <= 0 and seq not in in_grid]
         rec = self._clock_spec.begin(force_fence=True)
-        S = self.max_slots
-        MB = self.scheduler.max_blocks_per_seq
-        W = 1 + spec.bucket_for(max(len(d) for _, _, d in drafted))
-        tokens = np.zeros((S, W), np.int32)
-        tables = np.zeros((S, MB), np.int32)
-        start = np.zeros(S, np.int32)
-        length = np.zeros(S, np.int32)
-        active = np.zeros(S, bool)
-        for seq, _st, draft in drafted:
-            i = seq.slot
-            tokens[i, 0] = seq.generated[-1] if seq.generated \
-                else seq.prompt[-1]
-            tokens[i, 1:1 + len(draft)] = draft
-            tables[i, :len(seq.block_table)] = seq.block_table
-            start[i] = seq.context_len - 1
-            length[i] = 1 + len(draft)
-            active[i] = True
-        for seq in riders:            # length-1 rows: draft-free decode
-            i = seq.slot
-            tokens[i, 0] = seq.generated[-1] if seq.generated \
-                else seq.prompt[-1]
-            tables[i, :len(seq.block_table)] = seq.block_table
-            start[i] = seq.context_len - 1
-            length[i] = 1
-            active[i] = True
-        rec.lap("host_input")
-        try:
-            # fault site: an injected raise costs exactly one round's
-            # speculation — nothing was emitted or written yet, so the
-            # drafted lanes just rejoin the normal decode step (after
-            # rewinding the blocks grown above); nothing is evicted
-            fault_point("generation.spec_verify",
-                        request_ids=[s.request_id
-                                     for s, _, _ in drafted]
-                        + [s.request_id for s in riders])
-        except FaultInjected:
-            for seq, _st, _draft in drafted:
-                self.scheduler.rollback_speculation(seq)
-            rec.end()
-            return done
-        t0 = now()
-        rec.cold = ("spec", W - 1) not in self._goodput_warm
-        kv, scl, greedy = self._spec_jit(
-            self.params, self.cache.kv, self._kv_scale,
-            jnp.asarray(tokens), jnp.asarray(tables),
-            jnp.asarray(start), jnp.asarray(length),
-            jnp.asarray(active))
-        self._store_kv_state(kv, scl)
-        rec.lap(None)
-        greedy = np.asarray(greedy)   # token fetch = device fence
-        rec.lap("device_compute")
-        self._goodput_warm.add(("spec", W - 1))
-        dur = now() - t0
-        self._h_decode.record(dur, len(drafted) + len(riders))
-        n_rows = len(drafted) + len(riders)
-        ctx_mean = (float(np.sum(start[active])) / n_rows
-                    if n_rows else 0.0)
-        profiling.record_work(
-            "spec_verify", dur, tokens=int(np.sum(length[active])),
-            flops=(self._flops.verify(n_rows, W, ctx_mean)
-                   if self._flops else 0.0))
-        for seq in riders:
-            # a rider's row is an ordinary decode in verify clothing:
-            # it charges no speculation budget, ticks no speculation
-            # counters, and needs no rollback — position 0's argmax is
-            # the round's one token
-            request_log.decode_round(seq.request_id)
-            request_log.attribute(seq.request_id, "decode_active", dur)
-            done.add(seq)
-            self._emit(seq, int(greedy[seq.slot, 0]))
-        for seq, st, draft in drafted:
-            i = seq.slot
-            m = 0
-            while m < len(draft) and draft[m] == greedy[i, m]:
-                m += 1
-            st.record(len(draft), m)
-            self._c_spec_rounds.inc()
-            self._c_spec_proposed.inc(len(draft))
-            self._c_spec_accepted.inc(m)
-            self._h_spec_accepted.record(m)
-            n = st.rounds
-            if n & (n - 1) == 0:      # pow2-sampled, like decode
-                request_log.event(seq.request_id, "spec_propose",
-                                  round=n, proposed=len(draft))
-                request_log.event(seq.request_id, "spec_accept",
-                                  round=n, accepted=m)
-            request_log.decode_round(seq.request_id, spec=True)
-            # blame split of the verify round's wall: the accepted
-            # prefix + bonus token are useful decode ((m+1) of the
-            # (k+1) scored positions); the rejected remainder is
-            # speculation overhead.  The two shares sum to `dur`, so
-            # ledger additivity survives any acceptance rate.
-            k1 = 1 + len(draft)
-            request_log.attribute(seq.request_id, "decode_active",
-                                  dur * (m + 1) / k1)
-            request_log.attribute(seq.request_id,
-                                  "spec_verify_overhead",
-                                  dur * (len(draft) - m) / k1)
-            done.add(seq)
-            # emit the accepted prefix + the bonus token — exactly the
-            # tokens greedy single-step decode would have produced —
-            # stopping at eos/length like the decode loop would
-            for j in range(m + 1):
-                self._emit(seq, int(greedy[i, j]))
-                if seq.status == "finished":
-                    break
-            if seq.status != "finished":
-                # the free-list rewind: drop table blocks past the
-                # next write position (rejected slots decref here)
-                self.scheduler.rollback_speculation(seq)
-        rec.end()
+        with tracing.phase("generation.spec_verify"):
+            with rec.phase("generation.stage", "host_input"):
+                S = self.max_slots
+                MB = self.scheduler.max_blocks_per_seq
+                W = 1 + spec.bucket_for(
+                    max(len(d) for _, _, d in drafted))
+                tokens = np.zeros((S, W), np.int32)
+                tables = np.zeros((S, MB), np.int32)
+                start = np.zeros(S, np.int32)
+                length = np.zeros(S, np.int32)
+                active = np.zeros(S, bool)
+                for seq, _st, draft in drafted:
+                    i = seq.slot
+                    tokens[i, 0] = seq.generated[-1] if seq.generated \
+                        else seq.prompt[-1]
+                    tokens[i, 1:1 + len(draft)] = draft
+                    tables[i, :len(seq.block_table)] = seq.block_table
+                    start[i] = seq.context_len - 1
+                    length[i] = 1 + len(draft)
+                    active[i] = True
+                for seq in riders:    # length-1 rows: draft-free decode
+                    i = seq.slot
+                    tokens[i, 0] = seq.generated[-1] if seq.generated \
+                        else seq.prompt[-1]
+                    tables[i, :len(seq.block_table)] = seq.block_table
+                    start[i] = seq.context_len - 1
+                    length[i] = 1
+                    active[i] = True
+            try:
+                # fault site: an injected raise costs exactly one
+                # round's speculation — nothing was emitted or written
+                # yet, so the drafted lanes just rejoin the normal
+                # decode step (after rewinding the blocks grown above);
+                # nothing is evicted
+                fault_point("generation.spec_verify",
+                            request_ids=[s.request_id
+                                         for s, _, _ in drafted]
+                            + [s.request_id for s in riders])
+            except FaultInjected:
+                for seq, _st, _draft in drafted:
+                    self.scheduler.rollback_speculation(seq)
+                self._end_step(rec)
+                return done
+            t0 = now()
+            rec.cold = ("spec", W - 1) not in self._goodput_warm
+            with rec.phase("generation.dispatch"):
+                kv, scl, greedy = self._spec_jit(
+                    self.params, self.cache.kv, self._kv_scale,
+                    jnp.asarray(tokens), jnp.asarray(tables),
+                    jnp.asarray(start), jnp.asarray(length),
+                    jnp.asarray(active))
+                self._store_kv_state(kv, scl)
+            with rec.phase("generation.fetch", "device_compute"):
+                greedy = np.asarray(greedy)  # token fetch = device fence
+            # accounting for every lane, then emission for every lane:
+            # each request's log keeps its order (decode round, token,
+            # finish), and a trace shows two spans, not two a lane
+            accepted = []
+            with rec.phase("generation.account"):
+                self._goodput_warm.add(("spec", W - 1))
+                dur = now() - t0
+                self._h_decode.record(dur, len(drafted) + len(riders))
+                n_rows = len(drafted) + len(riders)
+                ctx_mean = (float(np.sum(start[active])) / n_rows
+                            if n_rows else 0.0)
+                profiling.record_work(
+                    "spec_verify", dur,
+                    tokens=int(np.sum(length[active])),
+                    flops=(self._flops.verify(n_rows, W, ctx_mean)
+                           if self._flops else 0.0))
+                for seq in riders:
+                    # a rider's row is an ordinary decode in verify
+                    # clothing: it charges no speculation budget, ticks
+                    # no speculation counters, and needs no rollback —
+                    # position 0's argmax is the round's one token
+                    request_log.decode_round(seq.request_id)
+                    request_log.attribute(seq.request_id,
+                                          "decode_active", dur)
+                    done.add(seq)
+                for seq, st, draft in drafted:
+                    i = seq.slot
+                    m = 0
+                    while m < len(draft) and draft[m] == greedy[i, m]:
+                        m += 1
+                    accepted.append(m)
+                    st.record(len(draft), m)
+                    self._c_spec_rounds.inc()
+                    self._c_spec_proposed.inc(len(draft))
+                    self._c_spec_accepted.inc(m)
+                    self._h_spec_accepted.record(m)
+                    n = st.rounds
+                    if n & (n - 1) == 0:  # pow2-sampled, like decode
+                        request_log.event(seq.request_id,
+                                          "spec_propose", round=n,
+                                          proposed=len(draft))
+                        request_log.event(seq.request_id, "spec_accept",
+                                          round=n, accepted=m)
+                    request_log.decode_round(seq.request_id, spec=True)
+                    # blame split of the verify round's wall: the
+                    # accepted prefix + bonus token are useful decode
+                    # ((m+1) of the (k+1) scored positions); the
+                    # rejected remainder is speculation overhead.  The
+                    # two shares sum to `dur`, so ledger additivity
+                    # survives any acceptance rate.
+                    k1 = 1 + len(draft)
+                    request_log.attribute(seq.request_id,
+                                          "decode_active",
+                                          dur * (m + 1) / k1)
+                    request_log.attribute(seq.request_id,
+                                          "spec_verify_overhead",
+                                          dur * (len(draft) - m) / k1)
+                    done.add(seq)
+            with rec.phase("generation.emit"):
+                for seq in riders:
+                    self._emit(seq, int(greedy[seq.slot, 0]))
+                for (seq, _st, _draft), m in zip(drafted, accepted):
+                    # emit the accepted prefix + the bonus token —
+                    # exactly the tokens greedy single-step decode
+                    # would have produced — stopping at eos/length like
+                    # the decode loop would
+                    for j in range(m + 1):
+                        self._emit(seq, int(greedy[seq.slot, j]))
+                        if seq.status == "finished":
+                            break
+                    if seq.status != "finished":
+                        # the free-list rewind: drop table blocks past
+                        # the next write position (rejected slots
+                        # decref here)
+                        self.scheduler.rollback_speculation(seq)
+            self._end_step(rec)
         return done
 
     def _decode_all(self, skip: frozenset = frozenset()) -> None:
         rec = self._clock_decode.begin(force_fence=True)
-        S = self.max_slots
-        MB = self.scheduler.max_blocks_per_seq
-        tokens = np.zeros(S, np.int32)
-        tables = np.zeros((S, MB), np.int32)
-        ctx_len = np.zeros(S, np.int32)
-        active = np.zeros(S, bool)
-        temp = np.zeros(S, np.float32)
-        top_k = np.zeros(S, np.int32)
-        lanes = {}
-        for seq in self.scheduler.running():
-            if seq in skip:
-                continue              # already advanced via verify
-            i = seq.slot
-            lanes[i] = seq
-            tokens[i] = seq.generated[-1] if seq.generated \
-                else seq.prompt[-1]
-            tables[i, :len(seq.block_table)] = seq.block_table
-            ctx_len[i] = seq.context_len - 1    # the pending position
-            active[i] = True
-            temp[i] = seq.temperature
-            top_k[i] = seq.top_k
-        rec.lap("host_input")
-        # fault-injection site: "poison_request" raises
-        # PoisonedRequestError BEFORE dispatch (no KV/state change
-        # happened, so surviving lanes replay this round untouched);
-        # "stall" wedges the loop for the watchdog
-        fault_point("generation.decode",
-                    request_ids=[s.request_id for s in lanes.values()])
-        t0 = now()
-        rec.cold = "decode" not in self._goodput_warm
-        kv, scl, nxt, _ = self._decode_jit(
-            self.params, self.cache.kv, self._kv_scale,
-            jnp.asarray(tokens), jnp.asarray(tables),
-            jnp.asarray(ctx_len), jnp.asarray(active),
-            jnp.asarray(temp), jnp.asarray(top_k), self._next_rng())
-        self._store_kv_state(kv, scl)
-        rec.lap(None)
-        nxt = np.asarray(nxt)     # token fetch = device fence
-        rec.lap("device_compute")
-        self._goodput_warm.add("decode")
-        dur = now() - t0
-        self._h_decode.record(dur, len(lanes))
-        ctx_mean = (float(np.sum(ctx_len[active])) / len(lanes)
-                    if lanes else 0.0)
-        profiling.record_work(
-            "decode", dur, tokens=len(lanes),
-            flops=(self._flops.decode(len(lanes), ctx_mean)
-                   if self._flops else 0.0))
-        for i, seq in lanes.items():
-            request_log.decode_round(seq.request_id)
-            # per-request wall-clock experience: every riding lane
-            # waited out the whole fenced round
-            request_log.attribute(seq.request_id, "decode_active", dur)
-            self._emit(seq, nxt[i])
-        rec.end()
+        # `skip`: the lanes that already advanced via verify
+        lanes = {seq.slot: seq for seq in self.scheduler.running()
+                 if seq not in skip}
+        # the two counts ride in the span's name: a reader of the trace
+        # keeps an event's name and drops its other fields
+        with tracing.phase(
+                f"generation.decode[l={len(lanes)},"
+                f"w={min(len(self.scheduler.waiting), 99)}]"):
+            with rec.phase("generation.stage", "host_input"):
+                S = self.max_slots
+                MB = self.scheduler.max_blocks_per_seq
+                tokens = np.zeros(S, np.int32)
+                tables = np.zeros((S, MB), np.int32)
+                ctx_len = np.zeros(S, np.int32)
+                active = np.zeros(S, bool)
+                temp = np.zeros(S, np.float32)
+                top_k = np.zeros(S, np.int32)
+                for i, seq in lanes.items():
+                    tokens[i] = seq.generated[-1] if seq.generated \
+                        else seq.prompt[-1]
+                    tables[i, :len(seq.block_table)] = seq.block_table
+                    ctx_len[i] = seq.context_len - 1  # pending position
+                    active[i] = True
+                    temp[i] = seq.temperature
+                    top_k[i] = seq.top_k
+            # fault-injection site: "poison_request" raises
+            # PoisonedRequestError BEFORE dispatch (no KV/state change
+            # happened, so surviving lanes replay this round
+            # untouched); "stall" wedges the loop for the watchdog
+            fault_point("generation.decode",
+                        request_ids=[s.request_id
+                                     for s in lanes.values()])
+            t0 = now()
+            rec.cold = "decode" not in self._goodput_warm
+            with rec.phase("generation.dispatch"):
+                kv, scl, nxt, _ = self._decode_jit(
+                    self.params, self.cache.kv, self._kv_scale,
+                    jnp.asarray(tokens), jnp.asarray(tables),
+                    jnp.asarray(ctx_len), jnp.asarray(active),
+                    jnp.asarray(temp), jnp.asarray(top_k),
+                    self._next_rng())
+                self._store_kv_state(kv, scl)
+            with rec.phase("generation.fetch", "device_compute"):
+                nxt = np.asarray(nxt)     # token fetch = device fence
+            # accounting for every lane, then emission for every lane:
+            # each request's log keeps its order (decode round, token,
+            # finish), and a trace shows two spans, not two a lane
+            with rec.phase("generation.account"):
+                self._goodput_warm.add("decode")
+                dur = now() - t0
+                self._h_decode.record(dur, len(lanes))
+                ctx_mean = (float(np.sum(ctx_len[active])) / len(lanes)
+                            if lanes else 0.0)
+                profiling.record_work(
+                    "decode", dur, tokens=len(lanes),
+                    flops=(self._flops.decode(len(lanes), ctx_mean)
+                           if self._flops else 0.0))
+                for seq in lanes.values():
+                    request_log.decode_round(seq.request_id)
+                    # per-request wall-clock experience: every riding
+                    # lane waited out the whole fenced round
+                    request_log.attribute(seq.request_id,
+                                          "decode_active", dur)
+            with rec.phase("generation.emit"):
+                for i, seq in lanes.items():
+                    self._emit(seq, nxt[i])
+            self._end_step(rec)
 
     def _evict_poisoned(self, e: PoisonedRequestError) -> None:
         """Graceful degradation: a step failure attributable to ONE
@@ -1465,12 +1515,13 @@ class GenerationEngine:
         chunk path) → grow/preempt for decode capacity (+ copy-on-
         write un-sharing) → one decode step.  Returns whether any
         device work ran."""
-        with self._lock:
+        with self._lock, tracing.phase("generation.round"):
             did = False
             spec_budget = self.scheduler.prefill_token_budget
-            if self.host_tier is not None:
-                self._stage_host_restores()
-            admitted = self.scheduler.admit()
+            with tracing.phase("generation.admit"):
+                if self.host_tier is not None:
+                    self._stage_host_restores()
+                admitted = self.scheduler.admit()
             if self._use_chunks:
                 chunked, spec_budget = self._prefill_round()
                 did = chunked or did
@@ -1478,9 +1529,10 @@ class GenerationEngine:
                 for seq in admitted:
                     self._prefill_seq(seq)
                     did = True
-            self.scheduler.ensure_decode_capacity()
-            if self.prefix_cache is not None:
-                self._apply_cow()
+            with tracing.phase("generation.capacity"):
+                self.scheduler.ensure_decode_capacity()
+                if self.prefix_cache is not None:
+                    self._apply_cow()
             advanced: set = set()
             if self.speculation is not None \
                     and self.scheduler.running():
@@ -1543,23 +1595,26 @@ class GenerationEngine:
             # replica SIGKILL'd mid-decode still leaves its counters
             # for the fleet harvest (no-op while observability_dir is
             # unset; time-gated otherwise)
-            maybe_spool(self.spool_name, (self.registry,))
-            # metrics history: time-series samples for burn-rate
-            # alerting + replay (disarmed unless
-            # metrics_history_interval_s is set)
-            maybe_record((self.registry,))
+            with tracing.phase("generation.housekeeping"):
+                maybe_spool(self.spool_name, (self.registry,))
+                # metrics history: time-series samples for burn-rate
+                # alerting + replay (disarmed unless
+                # metrics_history_interval_s is set)
+                maybe_record((self.registry,))
             if not self.scheduler.has_work():
                 if self.watchdog is not None:
                     # idle is not a stall: disarm until work arrives
                     self.watchdog.disarm()
-                self._wake.wait(timeout=0.05)
+                with tracing.phase("generation.wait"):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
             if self.watchdog is not None:
                 self.watchdog.arm()
             try:
                 did = self.step()
-                with self._lock:
+                with self._lock, \
+                        tracing.phase("generation.housekeeping"):
                     if did or not self.scheduler.waiting:
                         stuck_rounds = 0
                     else:

@@ -1,0 +1,331 @@
+"""The bridge from the program's spans to the profiler's trace
+(`observability/tracing.py`): under a real `jax.profiler` session on
+the CPU, with the benchmark's `Tracer` and its options, every span of
+PERF.md's span table shows as a host event ``azt:<name>``, children
+inside their parents, the counts in the decode span's name; the light
+phases leave the ring behind `GET /spans` alone; what is served does
+not depend on a session being open; and the program opens none itself.
+"""
+
+import contextlib
+import glob
+import os
+import re
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from analytics_zoo_tpu.observability import goodput, request_log, tracing
+from analytics_zoo_tpu.serving.generation import CausalLM, GenerationEngine
+from benchmarks.harness import span_metrics
+from benchmarks.harness.trace_reduce import Trace
+from benchmarks.harness.tracing import Tracer
+
+START_TRACE = jax.profiler.start_trace
+PROMPTS = [([3, 9, 27, 20, 11], 4), ([5, 7, 11, 13, 17, 19, 23], 6),
+           ([2, 4, 8, 16, 32, 3, 6, 12, 24, 48], 8)]
+LEAVES = {"stage", "dispatch", "fetch", "account", "emit"}
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_session_but_the_tests():
+    """`jax.profiler.start_trace` raises for the whole module: the
+    program never opens a profiler session.  `session()` puts the real
+    one back for the one call that opens the test's own."""
+    patch = pytest.MonkeyPatch()
+
+    def poisoned(*args, **kwargs):
+        raise AssertionError("the program opened a profiler session")
+
+    patch.setattr(jax.profiler, "start_trace", poisoned)
+    yield
+    patch.undo()
+
+
+@contextlib.contextmanager
+def session():
+    tracer = Tracer()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax.profiler, "start_trace", START_TRACE)
+        tracer.start()
+    try:
+        yield tracer
+    finally:
+        if tracer.t_stop is None:
+            tracer.stop()
+        tracer.remove()
+
+
+def reduced(tracer) -> Trace:
+    tracer.stop()
+    return Trace.from_xplane(tracer.path(), 1,
+                             tracer.t_stop - tracer.t_start)
+
+
+def azt(trace, prefix=""):
+    return span_metrics.host_events(trace, span_metrics.PREFIX + prefix)
+
+
+def assert_nested(events):
+    """Spans of one thread: each lies inside the one open around it, or
+    begins after it has ended."""
+    stack = []
+    for name, start, end in events:
+        while stack and stack[-1][2] <= start:
+            stack.pop()
+        if stack:
+            assert end <= stack[-1][2], (name, stack[-1][0])
+        stack.append((name, start, end))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    model = CausalLM(vocab=61, hidden_size=32, n_head=4, n_block=2,
+                     intermediate_size=64, max_position_len=128)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                        jnp.arange(8)[None])["params"]
+    eng = GenerationEngine(model, params, max_slots=4, block_size=8,
+                           max_context=64)
+    eng.warmup()
+    yield eng
+    eng.stop()
+
+
+def serve(engine, temperature=0.0):
+    """The three requests queued, then the loop started: one admission,
+    three prefills, and decode rounds of 3, 3, 3, 2, 2, 1, 1 lanes.
+    Returns the tokens and the lanes of every decode dispatch, counted
+    from the `active` argument the jitted program was given."""
+    lanes, real = [], engine._decode_jit
+
+    def counted(params, kv, scale, tokens, tables, ctx_len, active, *rest):
+        lanes.append(int(np.asarray(active).sum()))
+        return real(params, kv, scale, tokens, tables, ctx_len, active,
+                    *rest)
+
+    engine._decode_jit = counted
+    engine._rng = jax.random.PRNGKey(7)
+    try:
+        streams = [engine.submit(p, max_new_tokens=n,
+                                 temperature=temperature)
+                   for p, n in PROMPTS]
+        engine.ensure_started()
+        tokens = [s.tokens() for s in streams]
+        time.sleep(0.12)            # the idle loop: two waits of 50 ms
+    finally:
+        engine.stop()
+        engine._decode_jit = real
+    return tokens, lanes
+
+
+def test_engine_spans_land_in_a_profiler_trace(engine):
+    plain, plain_lanes = serve(engine)
+    tracing.clear_spans()
+    with session() as tracer:
+        tokens, lanes = serve(engine)
+        trace = reduced(tracer)
+    assert tokens == plain and lanes == plain_lanes
+    assert [len(t) for t in tokens] == [4, 6, 8]
+    assert lanes == [3, 3, 3, 2, 2, 1, 1]
+
+    events = azt(trace, "generation.")
+    names = {span_metrics.short(n) for n, _, _ in events}
+    assert names == LEAVES | {"round", "admit", "capacity", "prefill",
+                              "decode", "wait", "housekeeping"}
+    assert_nested(events)
+
+    spans = span_metrics.engine_spans(trace)
+    for span in spans:
+        if span.name in LEAVES:
+            assert span.parents[-1] in ("prefill", "decode"), span
+            assert span.parents[0] == "round"
+        elif span.name in ("admit", "capacity", "prefill", "decode"):
+            assert span.parents == ("round",), span
+        else:
+            assert span.parents == (), span
+    # one decode span a dispatch, with the lanes and the queue in its name
+    found = [span_metrics.DECODE.match(n) for n, _, _ in events
+             if n.startswith("azt:generation.decode")]
+    assert all(found)
+    assert [int(m.group(1)) for m in found] == lanes
+    assert {int(m.group(2)) for m in found} == {0}
+    assert sum(s.name == "prefill" for s in spans) == 3
+    # each dispatch: one stage, dispatch, fetch and emit, two accounts
+    # (the planes' writes, then the goodput commit)
+    for parent in ("prefill", "decode"):
+        n = sum(s.name == parent for s in spans)
+        for leaf in LEAVES:
+            got = sum(s.name == leaf and s.parents[-1] == parent
+                      for s in spans)
+            assert got == (2 * n if leaf == "account" else n), (parent, leaf)
+
+    # the ring behind GET /spans holds no phase
+    assert not [s for s in tracing.recent_spans(4096)
+                if s["name"].startswith(("generation.", "azt:"))]
+
+    ctx = {"trace": trace}
+    assert span_metrics.decode_counts(ctx, 0) == pytest.approx(15 / 7)
+    assert span_metrics.decode_counts(ctx, 1) == 0.0
+    assert 0 < span_metrics.prefill_time_share(ctx) < 100
+    # no device plane on the CPU: no share of its idle time
+    assert span_metrics.serve_idle(ctx) is None
+
+
+def test_sampled_tokens_do_not_depend_on_a_session(engine):
+    plain, _ = serve(engine, temperature=0.9)
+    with session():
+        traced, _ = serve(engine, temperature=0.9)
+    assert traced == plain
+
+
+def test_each_requests_log_keeps_its_order(engine, monkeypatch):
+    """Accounting for every lane, then emission for every lane: a
+    request still sees its decode round before the round's token, and
+    its finish last."""
+    calls = []
+    log = request_log.get_request_log()
+    for kind in ("decode_round", "token", "finish"):
+        real = getattr(log, kind)
+        monkeypatch.setattr(
+            log, kind, lambda rid, *a, _k=kind, _r=real, **kw: (
+                calls.append((rid, _k[0])), _r(rid, *a, **kw))[1])
+    tokens, lanes = serve(engine)
+    by_request = {}
+    for rid, kind in calls:
+        by_request[rid] = by_request.get(rid, "") + kind
+    assert len(by_request) == 3
+    # the prefill's token, then (round, token) a decode round, then finish
+    assert sorted(by_request.values(), key=len) == [
+        "t" + "dt" * (n - 1) + "f" for n in (4, 6, 8)]
+    # and within one round, every lane's accounting before any emission
+    order = "".join(kind for _, kind in calls)
+    assert re.search(r"ddd" + "ttt", order)
+
+
+def test_fit_spans_land_in_a_profiler_trace():
+    from analytics_zoo_tpu import init_orca_context
+    from analytics_zoo_tpu.common.context import OrcaContext
+    from analytics_zoo_tpu.models.recommendation import NeuralCF
+    from analytics_zoo_tpu.orca.learn import Estimator
+    init_orca_context(cluster_mode="local")
+    rng = np.random.default_rng(0)
+    u, i = rng.integers(1, 21, 96), rng.integers(1, 11, 96)
+    data = {"x": [u, i], "y": ((u + i) % 2).astype(np.int32)}
+    est = Estimator.from_flax(NeuralCF(user_count=20, item_count=10),
+                              loss="sparse_categorical_crossentropy",
+                              optimizer="adam", learning_rate=5e-3)
+    every = OrcaContext.goodput_sample_every
+    OrcaContext.goodput_sample_every = 1      # every step fenced
+    try:
+        est.fit(data, epochs=1, batch_size=32)        # compiles
+        tracing.clear_spans()
+        with session() as tracer:
+            est.fit(data, epochs=1, batch_size=32)
+            trace = reduced(tracer)
+    finally:
+        OrcaContext.goodput_sample_every = every
+    events = azt(trace)
+    count = {}
+    for name, _, _ in events:
+        count[name[4:]] = count.get(name[4:], 0) + 1
+    assert count == {"estimator.fit": 1, "estimator.epoch": 1,
+                     "spmd.input_wait": 4, "spmd.step": 3,
+                     "spmd.stage_next": 3, "spmd.fence": 3,
+                     "spmd.account": 6, "spmd.epoch_end": 1}
+    assert_nested(events)
+    fit = next(e for e in events if e[0] == "azt:estimator.fit")
+    assert all(fit[1] <= s and e <= fit[2] for _, s, e in events)
+    # whole spans are in the ring as before, the phases are not
+    ring = {s["name"] for s in tracing.recent_spans(4096)}
+    assert {"estimator.fit", "estimator.epoch", "spmd.step"} <= ring
+    assert not ring & {"spmd.input_wait", "spmd.stage_next", "spmd.fence",
+                       "spmd.account", "spmd.epoch_end"}
+    wait = span_metrics.train_input_wait_ms({"trace": trace})
+    by_hand = sum(e - s for n, s, e in events
+                  if n == "azt:spmd.input_wait") / 1e6 / 3
+    assert wait == pytest.approx(by_hand) and wait > 0
+
+
+def test_a_phase_is_the_annotation_and_nothing_else():
+    tracing.clear_spans()
+    before = set(m for m in goodput.get_registry().snapshot())
+    with tracing.phase("generation.dispatch") as span:
+        assert tracing.current_span() is None
+    assert isinstance(span, jax.profiler.TraceAnnotation)
+    assert tracing.recent_spans() == []
+    assert set(goodput.get_registry().snapshot()) == before
+
+
+def test_a_step_records_phase_is_its_lap():
+    """One list of boundaries: the phase's end is the lap's, whatever
+    ran between two phases goes to the later one, as between laps."""
+    clock = goodput.StepClock("bridge_test")
+    rec = clock.begin(force_fence=True)
+    with rec.phase("generation.stage", "host_input"):
+        time.sleep(0.002)
+    time.sleep(0.002)                   # between phases
+    with rec.phase("generation.dispatch"):
+        pass
+    with rec.phase("generation.fetch", "device_compute"):
+        time.sleep(0.002)
+    rec.end()
+    table = clock.table()
+    assert table["fenced_steps"] == 1
+    buckets = table["buckets_s"]
+    assert buckets["host_input"] >= 0.002
+    assert buckets["device_compute"] >= 0.002
+    assert buckets["overhead"] >= 0.002       # the gap and the dispatch
+    assert sum(buckets.values()) == pytest.approx(table["fenced_wall_s"],
+                                                  abs=2e-6)
+
+
+def test_the_annotation_is_made_in_one_place():
+    made = []
+    for path in glob.glob(os.path.join(ROOT, "analytics_zoo_tpu", "**",
+                                       "*.py"), recursive=True):
+        with open(path) as f:
+            if "TraceAnnotation" in f.read():
+                made.append(os.path.relpath(path, ROOT))
+    assert made == ["analytics_zoo_tpu/observability/tracing.py"]
+
+
+# --- PERF.md's span table and the code name the same spans ---------------
+
+SPAN_CALL = re.compile(r"(?:\btrace|\bphase)\(\s*f?[\"']([A-Za-z0-9_.]+)")
+
+
+def spans_in_the_code():
+    names = set()
+    for path in glob.glob(os.path.join(ROOT, "analytics_zoo_tpu", "**",
+                                       "*.py"), recursive=True):
+        with open(path) as f:
+            names |= set(SPAN_CALL.findall(f.read()))
+    return names
+
+
+def spans_in_perf_md():
+    """The names in backticks in the first column of the table under
+    the heading "Spans and counts", a count in brackets cut off."""
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        text = f.read()
+    section = text.split("### Spans and counts", 1)[1].split("\n#", 1)[0]
+    rows = [r for r in section.splitlines() if r.startswith("| `")]
+    assert rows, "PERF.md: no span table under 'Spans and counts'"
+    return {name.split("[")[0] for row in rows
+            for name in re.findall(r"`([^`]+)`", row.split("|")[1])}
+
+
+def test_perf_md_names_every_span_and_no_other():
+    code, doc = spans_in_the_code(), spans_in_perf_md()
+    assert {"generation.round", "generation.decode", "spmd.input_wait",
+            "estimator.fit", "serving.http_request"} <= code
+    assert code - doc == set(), "spans of the code PERF.md does not name"
+    assert doc - code == set(), "spans PERF.md names that no code opens"
