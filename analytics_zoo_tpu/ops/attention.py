@@ -95,19 +95,24 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     return out.astype(jnp.float32)
 
 
-def _paged_dequant(flat, flat_scale, tok_idx):
-    """Gather token rows from a flat [ntok, h, d] pool view and
-    dequantize when a flat [ntok] scale vector rides along."""
-    ctx = flat[tok_idx]                                  # [S, C, h, d]
-    if flat_scale is not None:
+def _paged_context(kv_pool, kv_scale, layer, which, block_tables, h):
+    """Every lane's table blocks of `layer`'s K (which=0) or V (1),
+    gathered out of the pool's block view as a [S, C, h, d] context and
+    dequantized when scales ride along.  Heads are split out of what
+    was gathered, never of the pool."""
+    blocks = kv_pool[layer, which, block_tables]     # [S, MB, bs, h*d]
+    s, mb, bs, hd = blocks.shape
+    ctx = blocks.reshape(s, mb * bs, h, hd // h)
+    if kv_scale is not None:
+        scale = kv_scale[layer, which, block_tables].astype(jnp.float32)
         ctx = (ctx.astype(jnp.float32)
-               * flat_scale[tok_idx][:, :, None, None])
+               * scale.reshape(s, mb * bs)[:, :, None, None])
     return ctx
 
 
-def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
-                           block_tables, ctx_len, *, k_scale=None,
-                           v_scale=None, impl: str = "auto",
+def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
+                           ctx_len, *, layer: int, kv_scale=None,
+                           impl: str = "auto",
                            block_gather: Optional[int] = None,
                            compute_dtype=jnp.float32,
                            interpret: Optional[bool] = None):
@@ -117,10 +122,13 @@ def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
 
     q / new_k / new_v: [S, heads, head_dim] — lane S's pending token
     (it attends to itself in addition to the cache).
-    k_pool / v_pool: [num_blocks, block_size, heads, head_dim] — the
-    paged pool (block 0 reserved as the null block).  int8 pools pass
-    `k_scale`/`v_scale` [num_blocks, block_size] f32 per-token-slot
-    dequant scales (serving/generation/kv_cache.py's quantized mode).
+    kv_pool: [n_layers, 2, num_blocks, block_size, heads * head_dim] —
+    the WHOLE paged pool in the form it is stored in (the block view of
+    `PagedKVCache.kv`; block 0 reserved as the null block), `layer`
+    (static) the layer to read: no per-layer slice of the pool is ever
+    an operand.  int8 pools pass `kv_scale` [n_layers, 2, num_blocks,
+    block_size] f32 per-token-slot dequant scales
+    (serving/generation/kv_cache.py's quantized mode).
     block_tables: [S, max_blocks] int32; ctx_len: [S] int32 — cached
     position p of lane s lives at block_tables[s, p // bs], slot
     p % bs; entries past ctx_len are masked (garbage-safe, so
@@ -136,9 +144,7 @@ def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
     kernel's gather width; `interpret=True` runs the kernel on the CPU
     interpreter (tests)."""
     s, h, d = q.shape
-    nb, bs = k_pool.shape[:2]
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
+    bs = kv_pool.shape[3]
     if impl == "auto":
         try:
             platform = jax.default_backend()
@@ -146,19 +152,13 @@ def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
             platform = "cpu"
         impl = "pallas" if platform == "tpu" else "xla"
     if impl == "xla":
-        flat_k = k_pool.reshape(nb * bs, h, d)
-        flat_v = v_pool.reshape(nb * bs, h, d)
-        fk_scale = (None if k_scale is None
-                    else k_scale.reshape(nb * bs).astype(jnp.float32))
-        fv_scale = (None if v_scale is None
-                    else v_scale.reshape(nb * bs).astype(jnp.float32))
-        tok_idx = (block_tables[:, :, None] * bs
-                   + jnp.arange(bs)[None, None, :]).reshape(s, -1)
         out = dot_product_attention(
             q[:, None], new_k[:, None], new_v[:, None],
             compute_dtype=compute_dtype,
-            ctx_k=_paged_dequant(flat_k, fk_scale, tok_idx),
-            ctx_v=_paged_dequant(flat_v, fv_scale, tok_idx),
+            ctx_k=_paged_context(kv_pool, kv_scale, layer, 0,
+                                 block_tables, h),
+            ctx_v=_paged_context(kv_pool, kv_scale, layer, 1,
+                                 block_tables, h),
             ctx_len=ctx_len)
         return out[:, 0]
     if impl != "pallas":
@@ -172,41 +172,43 @@ def paged_decode_attention(q, new_k, new_v, k_pool, v_pool,
         interpret = jax.devices()[0].platform == "cpu"
     if block_gather is None:
         block_gather = tuned_paged_block_gather(
-            bs, s, h, d, k_pool.dtype, mb=block_tables.shape[1])
+            bs, s, h, d, kv_pool.dtype, mb=block_tables.shape[1])
 
-    def kernel(q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len,
-               *scales):
-        k_scale, v_scale = scales or (None, None)
+    def kernel(q, new_k, new_v, kv_pool, block_tables, ctx_len,
+               kv_scale=None):
         return paged_decode_pallas(
-            q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len,
-            k_scale=k_scale, v_scale=v_scale,
+            q, new_k, new_v, kv_pool, block_tables, ctx_len,
+            layer=layer, head_dim=d, kv_scale=kv_scale,
             block_gather=block_gather, interpret=interpret)
 
-    args = (q, new_k, new_v, k_pool, v_pool, block_tables, ctx_len)
-    if k_scale is not None:
-        args += (k_scale, v_scale)
+    # the kernel's rows are the pool's: heads merged into h*d columns
+    args = (q.reshape(s, h * d), new_k.reshape(s, h * d),
+            new_v.reshape(s, h * d), kv_pool, block_tables, ctx_len)
+    if kv_scale is not None:
+        args += (kv_scale,)
     from analytics_zoo_tpu.parallel.sharding import (
         mesh_axis_size, shard_map_compat, traced_mesh)
     mesh = traced_mesh()
     if mesh is not None:
         # a program over several devices (the tp engine) carries the
-        # kernel in a shard_map: attention is head-local, so each
-        # device runs it over its own heads of the head-sharded pool
+        # kernel in a shard_map: attention is head-local and a head
+        # shard is a contiguous slice of the merged axis, so each
+        # device runs it over its own columns of the pool
         # (serving/distributed/tp.py) with the tables, lengths and
         # per-token scales whole
         n_tp = mesh_axis_size("tp", mesh)
         tp = "tp" if n_tp > 1 and h % n_tp == 0 else None
-        lane, pool = P(None, tp, None), P(None, None, tp, None)
+        lane, pool = P(None, tp), P(None, None, None, None, tp)
         kernel = shard_map_compat(
             kernel, mesh=mesh,
-            in_specs=(lane,) * 3 + (pool,) * 2 + (P(),) * (len(args) - 5),
+            in_specs=(lane,) * 3 + (pool,) + (P(),) * (len(args) - 4),
             out_specs=lane)
-    return kernel(*args)
+    return kernel(*args).reshape(s, h, d)
 
 
-def paged_verify_attention(q, new_k, new_v, k_pool, v_pool,
-                           block_tables, ctx_len, *, k_scale=None,
-                           v_scale=None, impl: str = "auto",
+def paged_verify_attention(q, new_k, new_v, kv_pool, block_tables,
+                           ctx_len, *, layer: int, kv_scale=None,
+                           impl: str = "auto",
                            compute_dtype=jnp.float32):
     """Verify-step attention of q_len>1 new tokens per lane over its
     paged KV cache — speculative decoding's scoring pass
@@ -217,7 +219,7 @@ def paged_verify_attention(q, new_k, new_v, k_pool, v_pool,
     ctx_len[s]..ctx_len[s]+T-1; they attend causally over
     [cached context ; themselves], exactly the chunk-prefill read
     semantics (`dot_product_attention`'s ctx path).
-    k_pool / v_pool / block_tables / ctx_len / k_scale / v_scale: as in
+    kv_pool / layer / block_tables / ctx_len / kv_scale: as in
     `paged_decode_attention`.  Returns [S, T, heads, head_dim] float32.
 
     impl: "auto" | "pallas" | "xla" — all three currently run the XLA
@@ -225,23 +227,14 @@ def paged_verify_attention(q, new_k, new_v, k_pool, v_pool,
     dedicated q_len>1 Pallas verify kernel is future TPU-round work,
     so engines pinned to `paged_attention_impl="pallas"` verify
     through the same fallback their CPU parity tests exercise."""
-    s, t, h, d = q.shape
-    nb, bs = k_pool.shape[:2]
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown paged_verify_attention impl "
                          f"{impl!r}; use 'auto', 'pallas' or 'xla'")
-    flat_k = k_pool.reshape(nb * bs, h, d)
-    flat_v = v_pool.reshape(nb * bs, h, d)
-    fk_scale = (None if k_scale is None
-                else k_scale.reshape(nb * bs).astype(jnp.float32))
-    fv_scale = (None if v_scale is None
-                else v_scale.reshape(nb * bs).astype(jnp.float32))
-    tok_idx = (block_tables[:, :, None] * bs
-               + jnp.arange(bs)[None, None, :]).reshape(s, -1)
+    h = q.shape[2]
     return dot_product_attention(
         q, new_k, new_v, compute_dtype=compute_dtype,
-        ctx_k=_paged_dequant(flat_k, fk_scale, tok_idx),
-        ctx_v=_paged_dequant(flat_v, fv_scale, tok_idx),
+        ctx_k=_paged_context(kv_pool, kv_scale, layer, 0, block_tables,
+                             h),
+        ctx_v=_paged_context(kv_pool, kv_scale, layer, 1, block_tables,
+                             h),
         ctx_len=ctx_len)

@@ -11,27 +11,47 @@ answer, TPU-native: the BLOCK TABLE RIDES INTO THE KERNEL as a
 scalar-prefetch operand, the grid walks (lane, block-group), and each
 grid step's BlockSpec index map *reads the table* to aim the HBM->VMEM
 DMA at the lane's next pool block — the gather happens in the DMA
-engine, never as a materialized context tensor.  Per block the kernel
-runs the standard online-softmax update (running max / denominator /
-output in f32 VMEM scratch, exactly the flash_attention bookkeeping at
-q_len=1), masks by the lane's `ctx_len`, folds the new token's
-self-attention into the initialization (a decode token always attends
-to itself), and finalizes to an f32 output.
+engine, never as a materialized context tensor.
+
+The operand is the WHOLE pool in the form it is stored in,
+[n_layers, 2, num_blocks, block_size, heads * head_dim] (the block
+view of `PagedKVCache.kv`, a bitcast), with the layer a static index
+in the K and V index maps `(layer, 0|1, table[lane, j], 0, 0)`: no
+per-layer slice of the pool is ever an operand, so XLA materializes
+none.  A staged tile is [block_size, h*d] — rows of 768 lanes for
+GPT-2's 12 x 64, lane-dense and tile-exact, where a [bs, 12, 64] tile
+padded every (12, 64) plane to (16, 128).  Heads never get an axis of
+their own inside the kernel: with E the [h*d, h] head-indicator matrix
+(E[c, k] = 1 where merged column c belongs to head k),
+
+    scores [n, h]   = ((K * q) @ E) * d**-0.5
+    out    [1, h*d] = sum over rows of ((p @ E.T) * V)
+
+and the usual online softmax (running max / denominator in [1, h],
+output in [1, h*d], f32 VMEM scratch — the flash_attention bookkeeping
+at q_len=1) runs between them over the n = block_gather * block_size
+rows of a grid step at once, masked by the lane's `ctx_len`.  The new
+token's self-attention is folded into the initialization (a decode
+token always attends to itself); finalize divides by the denominator.
+Both matmuls run on the MXU in ONE bf16 pass and are still exact to
+f32: E is 0/1, exact in bf16, and the f32 operand is split into three
+bf16 pieces stacked along the rows (`_dot_exact`) — left to itself
+Mosaic rounds an f32 operand to bf16 (a gap of 2.4e-3 to the XLA form
+on N(0, 1) data, my chip run, PR 29, against 1.3e-6 this way).
 
 Quantized pools (int8 KV, serving/generation/kv_cache.py): when
-`k_scale`/`v_scale` [num_blocks, block_size] ride along, the kernel
-dequantizes ON READ by folding each token's scale into the score /
-probability COLUMNS (s_col *= k_scale[col]; p_col *= v_scale[col])
-— algebraically identical to scaling K/V rows, but it stays in the
-2-D [h, block] layouts the VPU likes and never materializes a
-dequantized block.
+`kv_scale` [n_layers, 2, num_blocks, block_size] rides along, the
+kernel dequantizes ON READ by folding each token's scale into its ROW
+of the scores / probabilities (s_row *= k_scale[row]; p_row *=
+v_scale[row]) — algebraically identical to scaling K/V rows, but on
+the [n, h] tile and never materializing a dequantized block.
 
 The tunable is `block_gather` (G): how many pool blocks one grid step
 processes.  G > 1 passes the pool G times with G table-indexed
 BlockSpecs, so one grid step streams G blocks and amortizes the
-per-step softmax bookkeeping over a G*block_size-wide score tile —
-the decode analog of flash's block_k.  Registered with `ops/tuning`
-under the fwd-only key family
+per-step softmax bookkeeping and both matmuls over a G*block_size-row
+tile — the decode analog of flash's block_k.  Registered with
+`ops/tuning` under the fwd-only key family
 
     paged_decode|<platform>|<pool dtype>|bs=<block_size>,d=<head_dim>,
     lanes=<max_slots>
@@ -49,7 +69,7 @@ bit-matches the pre-PR-6 gather+concat path everywhere else, and
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -64,31 +84,60 @@ DEFAULT_BLOCK_GATHER = 1
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
-def paged_decode_candidates(bs: int, mb: int, h: int, d: int
+def paged_decode_candidates(bs: int, mb: int, h: int, d: int,
+                            itemsize: int = 4
                             ) -> List[Dict[str, int]]:
     """The autotuner's candidate grid: block-gather widths that fit the
-    VMEM budget (k+v staged f32-equivalent, plus q/new-token tiles and
-    the online-softmax scratch) and don't exceed the per-lane table."""
+    VMEM budget and don't exceed the per-lane table.  Per grid step the
+    kernel holds the k+v tiles [g*bs, h*d] in the pool's dtype (double
+    buffered by the pipeline), some six f32 working copies of that tile
+    (the two casts, K*q and its three bf16 pieces, p spread over the
+    columns, p*V), both indicator matrices (bf16, padded to 128 lanes /
+    16 sublanes), the q/new-token/output rows and the [1, h*d]
+    accumulator."""
+    hd = h * d
     out = []
     for g in (1, 2, 4, 8):
         if g > max(1, mb):
             continue
-        vmem = (2 * g * bs * h * d * 4      # k+v tiles
-                + 3 * h * d * 4             # q, new_k, new_v
-                + h * d * 4 + 2 * h * 128 * 4   # o/m/l scratch
-                + 2 * g * bs * 4)           # scale vectors
+        n = g * bs
+        vmem = (2 * 2 * n * hd * itemsize       # k+v tiles, 2 buffers
+                + 6 * n * hd * 4                # f32 working set
+                + 2 * hd * 128 * 2 + 2 * 16 * hd * 2   # E, E.T
+                + 2 * 4 * hd * 4                # q, new_k, new_v, o
+                + hd * 4 + 2 * 8 * 128 * 4      # o/m/l scratch
+                + 2 * 2 * g * 8 * 128 * 4)      # scale rows
         if vmem <= _VMEM_BUDGET:
             out.append({"block_gather": g})
     return out or [{"block_gather": DEFAULT_BLOCK_GATHER}]
 
 
-def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
-            bs: int, num_j: int, quantized: bool, scale: float):
+def _dot_exact(x, w):
+    """x [r, k] f32 @ w [k, n] bf16 in ONE bf16 MXU pass, exact to f32
+    where w is exact in bf16 (the 0/1 indicators): x = hi + mid + lo in
+    bf16 pieces, stacked along the rows so the weights load once."""
+    r = x.shape[0]
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    y = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), w,
+                preferred_element_type=jnp.float32)
+    return y[:r] + y[r:2 * r] + y[2 * r:]
+
+
+def _rows(xs):
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
+
+
+def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
+            g: int, bs: int, num_j: int, quantized: bool, scale: float):
     # scalar prefetch: tbl_ref [S, MB] block tables, cl_ref [S] ctx
-    # lengths.  q/nk/nv_ref: [1, h, d] lane tiles.  rest: g gathered
-    # K blocks [1, bs, h, d], g V blocks, (g k-scale + g v-scale
-    # [1, bs] rows when quantized), then o_ref [1, h, d] and the o/m/l
-    # VMEM scratch carried across the block axis.
+    # lengths.  q/nk/nv_ref: [1, h*d] lane rows; e_ref [h*d, h] and
+    # et_ref [h, h*d] the head indicators (fetched once: their block
+    # never moves).  rest: g gathered K blocks [bs, h*d], g V blocks,
+    # (g k-scale + g v-scale [1, bs] rows when quantized), then o_ref
+    # [1, h*d] and the o/m/l VMEM scratch carried across the block axis.
     rest = list(rest)
     ks = [rest.pop(0) for _ in range(g)]
     vs = [rest.pop(0) for _ in range(g)]
@@ -97,6 +146,17 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
     o_ref, o_scr, m_scr, l_scr = rest
     s_idx = pl.program_id(0)
     j = pl.program_id(1)
+    n = g * bs
+    qv = q_ref[...].astype(jnp.float32)                  # [1, hd]
+
+    def heads(x):        # [r, hd] -> [r, h]: each head's sum
+        return _dot_exact(x, e_ref[...])
+
+    def spread(x):       # [r, h] -> [r, hd]: each head's value, d times
+        return _dot_exact(x, et_ref[...])
+
+    def up8(x):          # a [1, w] row as 8 sublanes: a whole MXU tile
+        return jnp.broadcast_to(x, (8, x.shape[1]))
 
     @pl.when(j == 0)
     def _init():
@@ -104,84 +164,79 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
         # softmax with its own score (p_self = exp(0) = 1, l = 1,
         # o = new_v) instead of a NEG_INF/0 init — no empty-context
         # special case, no 0/0 at finalize
-        qv = q_ref[0].astype(jnp.float32)
-        s_self = (qv * nk_ref[0].astype(jnp.float32)).sum(
-            axis=-1, keepdims=True) * scale              # [h, 1]
-        m_scr[:] = jnp.broadcast_to(s_self, m_scr.shape)
-        l_scr[:] = jnp.ones_like(l_scr)
-        o_scr[:] = nv_ref[0].astype(jnp.float32)
+        s_self = heads(up8(qv * nk_ref[...].astype(jnp.float32)))
+        m_scr[...] = s_self[0:1] * scale                 # [1, h]
+        l_scr[...] = jnp.ones_like(l_scr)
+        o_scr[...] = nv_ref[...].astype(jnp.float32)
 
     cl = cl_ref[s_idx]
 
     # block groups entirely past the lane's context are all-masked:
     # skip their compute (the DMAs still stream by, cheaply — the
     # shapes stay static, which is the zero-recompile contract)
-    @pl.when(j * g * bs < cl)
+    @pl.when(j * n < cl)
     def _compute():
-        # Mosaic's matmul wants the batch (head) dimension LEADING on
-        # both operands and a non-contracting dimension on each: q
-        # rides as [h, 1, d] and the staged [bs, h, d] tile is turned
-        # to [h, bs, d] in VMEM (the pool's layout is untouched)
-        qv = q_ref[0].astype(jnp.float32)[:, None, :]    # [h, 1, d]
-        for i in range(g):
-            kt = jnp.swapaxes(ks[i][0].astype(jnp.float32), 0, 1)
-            vt = jnp.swapaxes(vs[i][0].astype(jnp.float32), 0, 1)
-            pos = (j * g + i) * bs + jax.lax.broadcasted_iota(
-                jnp.int32, (1, bs), 1)
-            valid = pos < cl                             # [1, bs]
-            s = jax.lax.dot_general(
-                qv, kt, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32
-            )[:, 0, :] * scale                           # [h, bs]
-            if quantized:
-                # dequant-on-read, folded into the score columns
-                s = s * kscl[i][...]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_scr[:, 0:1]
-            l_prev = l_scr[:, 0:1]
-            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            p = jnp.where(valid, p, 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_new = l_prev * alpha + p.sum(axis=1, keepdims=True)
-            if quantized:
-                p = p * vscl[i][...]
-            o_scr[:] = o_scr[:] * alpha + jax.lax.dot_general(
-                p[:, None, :], vt, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)[:, 0, :]  # [h, d]
-            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-            l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        kt = _rows([k[...].astype(jnp.float32) for k in ks])  # [n, hd]
+        vt = _rows([v[...].astype(jnp.float32) for v in vs])
+        pos = j * n + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+        valid = pos < cl                                 # [n, 1]
+        s = heads(kt * qv) * scale                       # [n, h]
+        if quantized:
+            # the scales arrive as [1, bs] rows and scale ROWS here:
+            # turn each into a [bs, 1] column through the diagonal
+            eye = (jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+                   == jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1))
+
+            def col(refs):
+                return _rows([jnp.where(eye, r[...], 0.0).sum(
+                    axis=1, keepdims=True) for r in refs])
+            # dequant-on-read, folded into the score rows
+            s = s * col(kscl)
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=0, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)                  # [1, h]
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=0, keepdims=True)
+        m_scr[...] = m_new
+        if quantized:
+            p = p * col(vscl)
+        # p and the rescale factor share one trip through E.T
+        pa = spread(jnp.concatenate([p, up8(alpha)], axis=0))
+        o_scr[...] = (o_scr[...] * pa[n:n + 1]
+                      + (pa[:n] * vt).sum(axis=0, keepdims=True))
 
     @pl.when(j == num_j - 1)
     def _finalize():
-        o_ref[0] = (o_scr[:] / l_scr[:, 0:1]).astype(o_ref.dtype)
+        o_ref[...] = (o_scr[...] / spread(up8(l_scr[...]))[0:1]
+                      ).astype(o_ref.dtype)
 
 
-def paged_decode_pallas(q, new_k, new_v, k_pool, v_pool, block_tables,
-                        ctx_len, *, k_scale=None, v_scale=None,
+def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
+                        *, layer: int, head_dim: int, kv_scale=None,
                         block_gather: int = DEFAULT_BLOCK_GATHER,
                         interpret: bool = False):
     """The raw kernel call (dispatch through
     `ops.attention.paged_decode_attention`, which picks impl and asks
     the tuner for `block_gather`).
 
-    q, new_k, new_v: [S, h, d] — lane S's pending token's query and
-    its key/value (it attends to itself).
-    k_pool / v_pool: [num_blocks, block_size, h, d] — the paged pool
-    (block 0 = the null block; any float dtype, or int8 with scales).
-    k_scale / v_scale: [num_blocks, block_size] f32 per-token-slot
+    q, new_k, new_v: [S, h*d] — lane S's pending token's query and its
+    key/value (it attends to itself), heads merged like the pool's rows.
+    kv_pool: [n_layers, 2, num_blocks, block_size, h*d] — the whole
+    paged pool (block 0 = the null block; any float dtype, or int8 with
+    scales); `layer` (static) picks the layer in the index maps.
+    kv_scale: [n_layers, 2, num_blocks, block_size] f32 per-token-slot
     dequant scales (required iff the pool is quantized).
     block_tables: [S, max_blocks] int32; ctx_len: [S] int32 valid
     lengths (cached position p lives at table[p // bs], slot p % bs).
-    Returns [S, h, d] float32.
+    Returns [S, h*d] float32.
     """
-    s, h, d = q.shape
-    nb, bs, _, _ = k_pool.shape
+    s, hd = q.shape
+    bs = kv_pool.shape[3]
+    h = hd // head_dim
     mb = block_tables.shape[1]
     g = max(1, int(block_gather))
-    quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("pass both k_scale and v_scale, or neither")
+    quantized = kv_scale is not None
     # pad the table up to a multiple of g with null blocks — their
     # positions sit past every ctx_len, so the mask kills them
     if mb % g:
@@ -192,30 +247,34 @@ def paged_decode_pallas(q, new_k, new_v, k_pool, v_pool, block_tables,
     block_tables = block_tables.astype(jnp.int32)
     ctx_len = jnp.asarray(ctx_len, jnp.int32)
 
-    lane = pl.BlockSpec((1, h, d), lambda si, j, tbl, cl: (si, 0, 0))
+    # a [1, hd] block of an [S, hd] array breaks Mosaic's (8, 128)
+    # block rule; of the [S, 1, hd] view it is the last two dims whole
+    lane = pl.BlockSpec((None, 1, hd), lambda si, j, tbl, cl: (si, 0, 0))
 
-    def _pool_spec(i):
+    def _fixed(shape):
+        return pl.BlockSpec(shape, lambda si, j, tbl, cl: (0, 0))
+
+    def _pool_spec(which, i, rows):
+        # K (which=0) or V (1) block table[lane, j*g + i] of `layer`;
+        # `rows` is the block's own shape: (bs, hd) of the pool,
+        # (1, bs) of the scales' [..., num_blocks, 1, bs] view
         return pl.BlockSpec(
-            (1, bs, h, d),
-            partial(lambda si, j, tbl, cl, i: (tbl[si, j * g + i],
-                                               0, 0, 0), i=i))
+            (None, None, None) + rows,
+            lambda si, j, tbl, cl: (layer, which, tbl[si, j * g + i],
+                                    0, 0))
 
-    def _scale_spec(i):
-        # over the [num_blocks, 1, bs] view below: a (1, bs) block of a
-        # (num_blocks, bs) array breaks Mosaic's (8, 128) block rule,
-        # while here it is the array's own last two dims
-        return pl.BlockSpec(
-            (None, 1, bs),
-            partial(lambda si, j, tbl, cl, i: (tbl[si, j * g + i],
-                                               0, 0), i=i))
-
-    in_specs = ([lane, lane, lane]
-                + [_pool_spec(i) for i in range(g)] * 2)
-    args = [q, new_k, new_v] + [k_pool] * g + [v_pool] * g
+    e = (jnp.arange(hd)[:, None] // head_dim
+         == jnp.arange(h)[None, :]).astype(jnp.bfloat16)
+    in_specs = ([lane, lane, lane, _fixed((hd, h)), _fixed((h, hd))]
+                + [_pool_spec(w, i, (bs, hd))
+                   for w in (0, 1) for i in range(g)])
+    args = ([q[:, None], new_k[:, None], new_v[:, None], e, e.T]
+            + [kv_pool] * (2 * g))
     if quantized:
-        in_specs += [_scale_spec(i) for i in range(g)] * 2
-        args += [k_scale.astype(jnp.float32).reshape(nb, 1, bs)] * g \
-            + [v_scale.astype(jnp.float32).reshape(nb, 1, bs)] * g
+        in_specs += [_pool_spec(w, i, (1, bs))
+                     for w in (0, 1) for i in range(g)]
+        args += [kv_scale.astype(jnp.float32).reshape(
+            *kv_scale.shape[:3], 1, bs)] * (2 * g)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -223,18 +282,18 @@ def paged_decode_pallas(q, new_k, new_v, k_pool, v_pool, block_tables,
         in_specs=in_specs,
         out_specs=lane,
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
+            pltpu.VMEM((1, h), jnp.float32),
         ],
     )
     return pl.pallas_call(
         partial(_kernel, g=g, bs=bs, num_j=num_j, quantized=quantized,
-                scale=1.0 / (d ** 0.5)),
+                scale=1.0 / (head_dim ** 0.5)),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s, 1, hd), jnp.float32),
         interpret=interpret,
-    )(block_tables, ctx_len, *args)
+    )(block_tables, ctx_len, *args)[:, 0]
 
 
 # ----------------------------------------------------------------------
@@ -252,37 +311,31 @@ def _bench_paged_decode(bs, lanes, h, d, dtype, cfg, iters: int = 8):
     mb = max(4, 512 // bs)                 # a serving-shaped table
     nb = lanes * mb + 1
     rng = np.random.default_rng(0)
+    shape = (1, 2, nb, bs, h * d)
     if jnp.dtype(dtype) == jnp.int8:
-        k_pool = jnp.asarray(rng.integers(-127, 128, (nb, bs, h, d)),
-                             jnp.int8)
-        v_pool = jnp.asarray(rng.integers(-127, 128, (nb, bs, h, d)),
-                             jnp.int8)
-        k_scale = jnp.asarray(rng.uniform(0.005, 0.02, (nb, bs)),
-                              jnp.float32)
-        v_scale = jnp.asarray(rng.uniform(0.005, 0.02, (nb, bs)),
-                              jnp.float32)
+        kv_pool = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+        kv_scale = jnp.asarray(rng.uniform(0.005, 0.02, shape[:4]),
+                               jnp.float32)
     else:
-        k_pool = jnp.asarray(rng.normal(size=(nb, bs, h, d)), dtype)
-        v_pool = jnp.asarray(rng.normal(size=(nb, bs, h, d)), dtype)
-        k_scale = v_scale = None
+        kv_pool = jnp.asarray(rng.normal(size=shape), dtype)
+        kv_scale = None
     tables = jnp.asarray(
         1 + rng.permutation(nb - 1)[:lanes * mb].reshape(lanes, mb),
         jnp.int32)
     ctx = jnp.full(lanes, mb * bs - 1, jnp.int32)
-    q0 = jnp.asarray(rng.normal(size=(lanes, h, d)), jnp.float32)
-    nk = jnp.asarray(rng.normal(size=(lanes, h, d)), jnp.float32)
-    nv = jnp.asarray(rng.normal(size=(lanes, h, d)), jnp.float32)
+    q0 = jnp.asarray(rng.normal(size=(lanes, h * d)), jnp.float32)
+    nk = jnp.asarray(rng.normal(size=(lanes, h * d)), jnp.float32)
+    nv = jnp.asarray(rng.normal(size=(lanes, h * d)), jnp.float32)
 
     @jax.jit
     def many(q):
         def body(c, _):
             o = paged_decode_pallas(
-                c, nk, nv, k_pool, v_pool, tables, ctx,
-                k_scale=k_scale, v_scale=v_scale,
-                block_gather=cfg["block_gather"])
+                c, nk, nv, kv_pool, tables, ctx, layer=0, head_dim=d,
+                kv_scale=kv_scale, block_gather=cfg["block_gather"])
             return o, None
         c, _ = jax.lax.scan(body, q, None, length=iters)
-        return c[0, 0, 0]
+        return c[0, 0]
 
     float(many(q0))                        # compile + warm
     dt = float("inf")
@@ -304,7 +357,7 @@ def tuned_paged_block_gather(bs, lanes, h, d, dtype,
     from analytics_zoo_tpu.ops import tuning
     shape = {"bs": bs, "lanes": lanes, "d": d}
     cands = paged_decode_candidates(bs, mb if mb is not None else 8,
-                                    h, d)
+                                    h, d, jnp.dtype(dtype).itemsize)
     cfg = tuning.get_config(
         "paged_decode", shape, dtype,
         default={"block_gather": DEFAULT_BLOCK_GATHER},
@@ -323,7 +376,8 @@ def tune_paged_decode(bs, lanes, h, d, dtype=jnp.float32,
     shape = {"bs": bs, "lanes": lanes, "d": d}
     cfg = tuning.tune(
         "paged_decode", shape, dtype,
-        paged_decode_candidates(bs, mb if mb is not None else 8, h, d),
+        paged_decode_candidates(bs, mb if mb is not None else 8, h, d,
+                                jnp.dtype(dtype).itemsize),
         lambda c: _bench_paged_decode(bs, lanes, h, d, dtype, c),
         force=force)
     return int(cfg["block_gather"])

@@ -22,11 +22,12 @@ Layout rules (`TP_PARAM_RULES`, applied through
   crosses a shard boundary.
 * embeddings and LayerNorm params fall through to the replicated
   default (they are small and read every step).
-* the `PagedKVCache` pool ``[L, 2, tokens, heads, head_dim]`` shards
-  on the HEAD dim; the int8 scale vectors ``[L, 2, tokens]`` are
-  per-token (their amax spans the head dim, and max is exact under
-  any reduction order) and stay replicated, as do sampled tokens and
-  logits, pinned by `out_shardings` on every compiled step.
+* the `PagedKVCache` pool ``[L, 2, tokens, heads * head_dim]`` shards
+  by HEADS: a head shard is a contiguous slice of the merged axis
+  (`kv_cache.KV_TP_SPEC`); the int8 scale vectors ``[L, 2, tokens]``
+  are per-token (their amax spans the head dim, and max is exact
+  under any reduction order) and stay replicated, as do sampled tokens
+  and logits, pinned by `out_shardings` on every compiled step.
 
 A dim that the axis does not divide (e.g. a vocab head with
 ``vocab % tp != 0``) silently stays replicated — the rule table
@@ -47,6 +48,7 @@ from analytics_zoo_tpu.parallel.sharding import (
     mesh_axis_size,
     shard_map_compat,
 )
+from analytics_zoo_tpu.serving.generation.kv_cache import KV_TP_SPEC
 
 #: param-path substring -> sharding rule (pinned-dim form of
 #: `logical_to_sharding`).  Column sharding only: ":1" pins a kernel's
@@ -65,9 +67,6 @@ TP_PARAM_RULES = {
     "lm_head/bias": "tp:0",
 }
 
-#: the pool's head dim in `PagedKVCache.kv` [L, 2, tokens, h, d]
-_KV_HEAD_SPEC = P(None, None, None, "tp", None)
-
 
 class TensorParallelPlacement:
     """Device placement for one tensor-parallel generation engine.
@@ -81,7 +80,7 @@ class TensorParallelPlacement:
     def __init__(self, mesh: Mesh, degree: int):
         self.mesh = mesh
         self.degree = int(degree)
-        self.kv_sharding = NamedSharding(mesh, _KV_HEAD_SPEC)
+        self.kv_sharding = NamedSharding(mesh, KV_TP_SPEC)
         self.replicated = NamedSharding(mesh, P())
 
     @classmethod
@@ -113,7 +112,7 @@ class TensorParallelPlacement:
             raise ValueError(
                 f"model.n_head {model.n_head} is not divisible by "
                 f"tensor_parallel={degree}; the KV pool shards on the "
-                "head dim")
+                "merged heads * head_dim axis by whole heads")
         return cls(mesh, degree)
 
     # -- placement -----------------------------------------------------
@@ -126,7 +125,7 @@ class TensorParallelPlacement:
             infer_param_shardings(params, self.mesh, TP_PARAM_RULES))
 
     def put_kv(self, kv: jax.Array) -> jax.Array:
-        """Shard the KV pool on its head dim."""
+        """Shard the KV pool by heads (`kv_cache.KV_TP_SPEC`)."""
         return jax.device_put(kv, self.kv_sharding)
 
     def put_replicated(self, x: Any) -> Any:
@@ -164,13 +163,12 @@ class TensorParallelPlacement:
         single-device engine's bit-for-bit)."""
         gather = shard_map_compat(
             lambda x: jax.lax.all_gather(x, "tp", axis=3, tiled=True),
-            mesh=self.mesh, in_specs=_KV_HEAD_SPEC,
-            out_specs=P(None, None, None, None, None))
+            mesh=self.mesh, in_specs=KV_TP_SPEC, out_specs=P())
         return gather(kv)
 
     def per_device_kv_bytes(self, cache) -> int:
         """Resident pool bytes per device: the value tensor splits
-        1/degree ways on the head dim, the per-token scale vectors
+        1/degree ways by heads, the per-token scale vectors
         replicate (docs/distributed-serving.md's residency math)."""
         scale = cache.kv_scale
         return (cache.kv.nbytes // self.degree

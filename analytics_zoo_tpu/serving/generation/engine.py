@@ -82,8 +82,9 @@ from analytics_zoo_tpu.observability import (
 )
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
-    dequantize_kv_tokens,
-    quantize_kv_tokens,
+    block_view,
+    gather_kv,
+    write_kv,
 )
 from analytics_zoo_tpu.resilience.faults import (
     FaultInjected,
@@ -475,7 +476,7 @@ class GenerationEngine:
     def _build_steps(self) -> None:
         model = self.model
         bs = self.cache.block_size
-        nb = self.cache.num_blocks
+        n_head = self.cache.n_head
         max_pos = model.max_position_len
         quantized = self._quantized
         paged = self.decode_attention == "paged"
@@ -484,25 +485,29 @@ class GenerationEngine:
         # warns, so only donate off-CPU
         donate = ((1, 2) if jax.devices()[0].platform != "cpu" else ())
 
-        def write_kv(kv, kv_scale, dest, new_k, new_v):
-            # new_k/new_v [L, n, h, d] at token destinations dest [n];
-            # int8 mode quantizes on block write (per-token-slot
-            # symmetric scales — kv_cache.quantize_kv_tokens), so a
-            # dequantized pool never exists and appends never touch
-            # already-written slots
-            if quantized:
-                qk, sk = quantize_kv_tokens(new_k)
-                qv, sv = quantize_kv_tokens(new_v)
-                kv = kv.at[:, 0, dest].set(qk)
-                kv = kv.at[:, 1, dest].set(qv)
-                kv_scale = kv_scale.at[:, 0, dest].set(sk)
-                kv_scale = kv_scale.at[:, 1, dest].set(sv)
-            else:
-                kv = kv.at[:, 0, dest].set(
-                    new_k.astype(kv.dtype))
-                kv = kv.at[:, 1, dest].set(
-                    new_v.astype(kv.dtype))
-            return kv, kv_scale
+        def paged_apply(params, kv, kv_scale, tokens, pos, block_tables,
+                        ctx_len):
+            # the pool goes to the model whole, as its block view (a
+            # bitcast — kv_cache.block_view), with each lane's block
+            # table: the attention op gathers pool blocks by table
+            # index itself (ops/pallas/paged_attention.py), so neither
+            # a [S, C, h, d] context nor a per-layer slice of the pool
+            # is ever materialized
+            return model.apply(
+                {"params": params}, tokens, pos,
+                kv_pool=block_view(kv, bs),
+                kv_scale=block_view(kv_scale, bs) if quantized else None,
+                block_tables=block_tables, ctx_len=ctx_len)
+
+        def concat_apply(params, kv, kv_scale, tokens, pos, tok_idx,
+                         ctx_len):
+            # the context gathered out of the pool by token slot
+            # (kv_cache.gather_kv) and attended by the concat read
+            # path: the parity oracle, and the chunk step's read
+            ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
+            return model.apply(
+                {"params": params}, tokens, pos,
+                ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
 
         def prefill(params, kv, kv_scale, tokens, length, block_table,
                     temperature, top_k, rng):
@@ -532,32 +537,16 @@ class GenerationEngine:
             S, MB = block_tables.shape
             pos = jnp.minimum(ctx_len, max_pos - 1)
             if paged:
-                # the block table rides into the attention op; the
-                # kernel gathers pool blocks by table index itself
-                # (ops/pallas/paged_attention.py) — no [S, C, h, d]
-                # context tensor is ever materialized
-                kvp = kv.reshape(kv.shape[0], 2, nb, bs,
-                                 *kv.shape[-2:])
-                scl = (kv_scale.reshape(kv.shape[0], 2, nb, bs)
-                       if quantized else None)
-                logits, new_k, new_v = model.apply(
-                    {"params": params}, tokens[:, None], pos[:, None],
-                    kv_pool=kvp, kv_scale=scl,
-                    block_tables=block_tables, ctx_len=ctx_len)
+                logits, new_k, new_v = paged_apply(
+                    params, kv, kv_scale, tokens[:, None], pos[:, None],
+                    block_tables, ctx_len)
             else:
                 tok_idx = (block_tables[:, :, None] * bs
                            + jnp.arange(bs)[None, None, :]
                            ).reshape(S, -1)
-                ctx_k = kv[:, 0][:, tok_idx]    # [L, S, C, h, d]
-                ctx_v = kv[:, 1][:, tok_idx]
-                if quantized:
-                    ctx_k = dequantize_kv_tokens(
-                        ctx_k, kv_scale[:, 0][:, tok_idx])
-                    ctx_v = dequantize_kv_tokens(
-                        ctx_v, kv_scale[:, 1][:, tok_idx])
-                logits, new_k, new_v = model.apply(
-                    {"params": params}, tokens[:, None], pos[:, None],
-                    ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
+                logits, new_k, new_v = concat_apply(
+                    params, kv, kv_scale, tokens[:, None], pos[:, None],
+                    tok_idx, ctx_len)
             dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
                 + ctx_len % bs
             dest = jnp.where(active, dest, 0)   # dead lanes → null block
@@ -584,18 +573,10 @@ class GenerationEngine:
             rel = jnp.arange(B)
             pos = jnp.minimum(start + rel, max_pos - 1)
             tok_idx = (block_table[:, None] * bs
-                       + jnp.arange(bs)[None, :]).reshape(-1)
-            ctx_k = kv[:, 0][:, tok_idx][:, None]  # [L, 1, T, h, d]
-            ctx_v = kv[:, 1][:, tok_idx][:, None]
-            if quantized:
-                ctx_k = dequantize_kv_tokens(
-                    ctx_k, kv_scale[:, 0][:, tok_idx][:, None])
-                ctx_v = dequantize_kv_tokens(
-                    ctx_v, kv_scale[:, 1][:, tok_idx][:, None])
-            logits, new_k, new_v = model.apply(
-                {"params": params}, tokens, pos[None],
-                ctx_k=ctx_k, ctx_v=ctx_v,
-                ctx_len=jnp.reshape(start, (1,)).astype(jnp.int32))
+                       + jnp.arange(bs)[None, :]).reshape(1, -1)
+            logits, new_k, new_v = concat_apply(
+                params, kv, kv_scale, tokens, pos[None], tok_idx,
+                jnp.reshape(start, (1,)).astype(jnp.int32))
             dest = block_table[(start + rel) // bs] * bs \
                 + (start + rel) % bs
             dest = jnp.where(rel < length, dest, 0)
@@ -623,28 +604,15 @@ class GenerationEngine:
             rel = jnp.arange(W)
             pos = jnp.minimum(start[:, None] + rel[None], max_pos - 1)
             if paged:
-                kvp = kv.reshape(kv.shape[0], 2, nb, bs,
-                                 *kv.shape[-2:])
-                scl = (kv_scale.reshape(kv.shape[0], 2, nb, bs)
-                       if quantized else None)
-                logits, new_k, new_v = model.apply(
-                    {"params": params}, tokens, pos,
-                    kv_pool=kvp, kv_scale=scl,
-                    block_tables=block_tables, ctx_len=start)
+                logits, new_k, new_v = paged_apply(
+                    params, kv, kv_scale, tokens, pos, block_tables,
+                    start)
             else:
                 tok_idx = (block_tables[:, :, None] * bs
                            + jnp.arange(bs)[None, None, :]
                            ).reshape(S, -1)
-                ctx_k = kv[:, 0][:, tok_idx]
-                ctx_v = kv[:, 1][:, tok_idx]
-                if quantized:
-                    ctx_k = dequantize_kv_tokens(
-                        ctx_k, kv_scale[:, 0][:, tok_idx])
-                    ctx_v = dequantize_kv_tokens(
-                        ctx_v, kv_scale[:, 1][:, tok_idx])
-                logits, new_k, new_v = model.apply(
-                    {"params": params}, tokens, pos,
-                    ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=start)
+                logits, new_k, new_v = concat_apply(
+                    params, kv, kv_scale, tokens, pos, tok_idx, start)
             abs_pos = start[:, None] + rel[None]        # [S, W]
             dest = block_tables[jnp.arange(S)[:, None],
                                 abs_pos // bs] * bs + abs_pos % bs
@@ -675,7 +643,7 @@ class GenerationEngine:
 
         def restore_block(kv, kv_scale, dst, rows, srows):
             # host-tier restore: land one host slab's token slots
-            # (rows [L, 2, bs, h, d] in pool dtype, srows [L, 2, bs]
+            # (rows [L, 2, bs, h*d] in pool dtype, srows [L, 2, bs]
             # scales — a 1-element placeholder unquantized) into pool
             # block `dst`.  A separate single-shape program, warmed in
             # warmup(), never touching the decode step.
@@ -835,11 +803,9 @@ class GenerationEngine:
             if self.host_tier is not None \
                     and self._restore_block_jit is not None:
                 # the host-restore program (dst=null block: harmless)
-                bs = self.cache.block_size
-                kvs = self.cache.kv.shape
-                rows = jnp.zeros((kvs[0], 2, bs) + kvs[3:],
-                                 self.cache.kv.dtype)
-                srows = (jnp.zeros((kvs[0], 2, bs), jnp.float32)
+                slab = self.cache.slab_shape
+                rows = jnp.zeros(slab, self.cache.kv.dtype)
+                srows = (jnp.zeros(slab[:3], jnp.float32)
                          if self._quantized
                          else jnp.zeros((1,), jnp.float32))
                 kv, scl = self._restore_block_jit(

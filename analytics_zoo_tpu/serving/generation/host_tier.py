@@ -80,7 +80,7 @@ def reset_dma() -> None:
 
 class _HostEntry:
     """One spilled block: the full token-id prefix it terminates, its
-    KV rows ``[L, 2, block_size, heads, head_dim]`` (pool dtype — int8
+    KV rows ``[L, 2, block_size, heads * head_dim]`` (pool dtype — int8
     values when the pool is quantized), the matching dequant scales
     ``[L, 2, block_size]`` (None unquantized), and — while a restore
     is staged — the in-flight device copies."""
@@ -111,9 +111,9 @@ class HostKVTier:
         self._entries: "OrderedDict[Tuple[int, ...], _HostEntry]" = \
             OrderedDict()
         self._bytes = 0
-        #: (n_layers, block_size, heads, head_dim, dtype, quantized) —
-        #: bound by the first engine; a mismatched slab is refused so
-        #: a heterogeneous fleet cannot adopt garbage
+        #: (`PagedKVCache.slab_shape`, dtype, quantized) — bound by
+        #: the first engine; a mismatched slab is refused so a
+        #: heterogeneous fleet cannot adopt garbage
         self._geometry: Optional[tuple] = None
         if registry is None:
             from analytics_zoo_tpu.observability import get_registry
@@ -155,9 +155,8 @@ class HostKVTier:
         """Pin the slab geometry to `cache`'s pool.  A tier re-bound
         to an incompatible pool drops its entries (advisory: losing
         them only costs recomputes)."""
-        geo = (int(cache.kv.shape[0]), int(cache.block_size),
-               int(cache.kv.shape[3]), int(cache.kv.shape[4]),
-               str(cache.kv.dtype), cache.kv_scale is not None)
+        geo = (cache.slab_shape, str(cache.kv.dtype),
+               cache.kv_scale is not None)
         if self._geometry is not None and self._geometry != geo:
             self.clear()
         self._geometry = geo
@@ -166,12 +165,12 @@ class HostKVTier:
               ) -> bool:
         if self._geometry is None:
             return True
-        L, bs, h, d, dt, quant = self._geometry
-        if tuple(kv.shape) != (L, 2, bs, h, d) or str(kv.dtype) != dt:
+        slab, dt, quant = self._geometry
+        if tuple(kv.shape) != slab or str(kv.dtype) != dt:
             return False
         if quant != (scale is not None):
             return False
-        return scale is None or tuple(scale.shape) == (L, 2, bs)
+        return scale is None or tuple(scale.shape) == slab[:3]
 
     # ------------------------------------------------------------------
 
@@ -242,7 +241,7 @@ class HostKVTier:
         classifier calls this on every submit."""
         if self._geometry is None or not self._entries:
             return 0
-        bs = self._geometry[1]
+        bs = self._geometry[0][2]       # slab_shape's block_size
         usable = (len(tokens) - 1) // bs
         j = 0
         while j < usable:
@@ -262,7 +261,7 @@ class HostKVTier:
         staged (already-staged entries count)."""
         if self._geometry is None or not self._entries:
             return 0
-        bs = self._geometry[1]
+        bs = self._geometry[0][2]       # slab_shape's block_size
         usable = (len(tokens) - 1) // bs
         staged = 0
         j = n_matched // bs

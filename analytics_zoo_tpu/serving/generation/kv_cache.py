@@ -8,14 +8,26 @@ Fragmentation drops from per-sequence worst-case to one partial block
 per sequence, so many more sequences fit in the same HBM.
 
 The device side is ONE jax array per cache,
-[n_layers, 2, num_blocks * block_size, heads, head_dim] (k=0/v=1 on
-axis 1), flat in the token dimension so reads/writes are plain
-gathers/scatters on `block_id * block_size + offset` — no kernel
-needed, XLA lowers them to dynamic-(gather|scatter) and the decode
-step stays a single compiled program.  Block 0 is reserved as the NULL
-block: inactive slots' table entries (and padding writes) all point at
-it, so dead lanes scribble harmlessly instead of branching — that is
-what keeps the decode step's shapes static.
+[n_layers, 2, num_blocks * block_size, heads * head_dim] (k=0/v=1 on
+axis 1): one ROW per token slot, heads and head_dim merged.  This is
+the one form every reader and writer shares, chosen so that no step
+program ever relays the pool out.  The chip stores an array in
+(sublane, 128-lane) tiles over its two minor dims — (16, 128) for
+bf16 — and Mosaic wants its operands row-major in those tiles.  A
+minor pair of (heads, head_dim) = (12, 64) fits neither, so the old
+[..., heads, head_dim] pool was padded and transposed on the way into
+every program and back out (three whole-pool copies a decode step);
+a merged row of 768 is six full lanes and a block of 16 rows one
+sublane tile, so `block_view` is a bitcast and a paged-kernel DMA
+lands on whole tiles.  The write is `write_kv`: ONE scatter of rows
+whose window is a single row, which XLA performs in place on the
+donated pool (a scatter whose window spans the layer axis made it
+move that axis inward around the write: two more whole-pool copies).
+A head shard under tensor parallelism is a contiguous slice of the
+merged axis (`KV_TP_SPEC`).  Block 0 is reserved as the NULL block:
+inactive slots' table entries (and padding writes) all point at it,
+so dead lanes scribble harmlessly instead of branching — that is what
+keeps the decode step's shapes static.
 
 Allocation is host-side (the free list is python state; the device
 never sees it) — the allocator hands block ids to the scheduler, which
@@ -41,9 +53,13 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 #: block id 0 is never allocated; see module docstring
 NULL_BLOCK = 0
+#: the pool under tensor parallelism: a head shard is a contiguous
+#: slice of the merged heads * head_dim axis
+KV_TP_SPEC = P(None, None, None, "tp")
 
 
 def quantize_kv_tokens(x) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -64,6 +80,62 @@ def quantize_kv_tokens(x) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def dequantize_kv_tokens(q, scale):
     """Inverse of `quantize_kv_tokens` (tests and the XLA read path)."""
     return q.astype(jnp.float32) * scale[..., None, None]
+
+
+def block_view(x, block_size: int):
+    """The pool [L, 2, slots, h*d] (or its scale vectors [L, 2, slots])
+    with the slot axis split into [num_blocks, block_size] — what the
+    paged kernel's BlockSpecs index by block-table entry.  A bitcast
+    wherever `block_size` rows fill whole tiles (16 for bf16, 8 for
+    f32), so the decode program never holds a second pool."""
+    return x.reshape(*x.shape[:2], -1, block_size, *x.shape[3:])
+
+
+def _row_index(n_layers: int, slots):
+    """(layer, k/v, slot) index arrays that broadcast to
+    [L, 2, *slots.shape]: a gather or scatter through them moves single
+    ROWS of the pool, which XLA does on the pool as it lies (indexing
+    `kv[:, :, slots]` instead makes the window span the layer and k/v
+    axes, and XLA relays the whole pool out to bring them inward)."""
+    ones = (1,) * slots.ndim
+    return (jnp.arange(n_layers).reshape(n_layers, 1, *ones),
+            jnp.arange(2).reshape(1, 2, *ones), slots[None, None])
+
+
+def gather_kv(kv, kv_scale, tok_idx, n_head: int):
+    """Token slots `tok_idx` [...] of every layer as (ctx_k, ctx_v)
+    [L, ..., heads, head_dim] — the concat-oracle, chunk-prefill and
+    verify read paths.  Heads are split out of what was GATHERED,
+    never of the pool; an int8 pool dequantizes here by `kv_scale`
+    [L, 2, slots]."""
+    idx = _row_index(kv.shape[0], tok_idx)
+    rows = kv[idx]
+    rows = rows.reshape(*rows.shape[:-1], n_head, -1)
+    if kv.dtype == jnp.int8:
+        rows = dequantize_kv_tokens(rows, kv_scale[idx])
+    return rows[:, 0], rows[:, 1]
+
+
+def write_kv(kv, kv_scale, dest, new_k, new_v):
+    """Write new_k / new_v [L, n, heads, head_dim] into token slots
+    `dest` [n] of every layer — the one pool write of the prefill,
+    chunk, decode and verify programs.  ONE scatter of rows: index
+    (layer, k/v, slot), window a single merged row, so XLA updates the
+    donated pool in place whatever `n` is (a `kv.at[:, 0, dest]` window
+    spans the layer axis and costs two whole-pool relayouts).  An int8
+    pool quantizes on write (per-token-slot symmetric scales —
+    `quantize_kv_tokens`), so a dequantized pool never exists and
+    appends never touch already-written slots; otherwise `kv_scale`
+    passes through.  Duplicate slots only ever name the null block
+    (dead lanes, padding), where any winner is harmless."""
+    L, n = new_k.shape[:2]
+    rows = jnp.stack([new_k, new_v], axis=1)       # [L, 2, n, h, d]
+    idx = _row_index(L, dest)
+    if kv.dtype == jnp.int8:
+        rows, scales = quantize_kv_tokens(rows)
+        kv_scale = kv_scale.at[idx].set(scales)
+    kv = kv.at[idx].set(rows.reshape(L, 2, n, -1).astype(kv.dtype))
+    return kv, kv_scale
 
 
 class BlockAllocator:
@@ -176,7 +248,7 @@ class PagedKVCache:
         self.logical_dtype = jnp.dtype(dtype)
         store = jnp.int8 if quantization == "int8" else dtype
         self.kv = jnp.zeros(
-            (n_layers, 2, num_blocks * block_size, n_head, head_dim),
+            (n_layers, 2, num_blocks * block_size, n_head * head_dim),
             store)
         #: per-token-slot dequant scales (int8 mode only) — functional
         #: state like `kv`: the jitted steps take and return it
@@ -189,6 +261,22 @@ class PagedKVCache:
     def blocks_for(self, n_tokens: int) -> int:
         """Blocks needed to hold n_tokens."""
         return -(-n_tokens // self.block_size)
+
+    @property
+    def slab_shape(self) -> Tuple[int, int, int, int]:
+        """One block's rows across every layer, [L, 2, block_size,
+        h*d] — the unit the host tier spills and restores (its scales
+        are `slab_shape[:3]`)."""
+        return (self.n_layers, 2, self.block_size,
+                self.n_head * self.head_dim)
+
+    def read_block(self, blk: int):
+        """Block `blk`'s slab and its scales (None unquantized), still
+        on the device — what the prefix cache spills."""
+        rows = slice(blk * self.block_size, (blk + 1) * self.block_size)
+        return (self.kv[:, :, rows],
+                None if self.kv_scale is None
+                else self.kv_scale[:, :, rows])
 
     @property
     def physical_nbytes(self) -> int:

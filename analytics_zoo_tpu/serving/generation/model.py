@@ -38,11 +38,15 @@ class CausalLM(nn.Module):
     Prefill: pass `token_mask` [batch, t] (1 = real token) and no ctx —
     full causal attention over the (bucket-padded) prompt.
     Paged decode (t == 1): pass `kv_pool` [n_block, 2, num_blocks,
-    block_size, heads, head_dim] (the engine's pool, block-major view),
-    `block_tables` [batch, max_blocks], `ctx_len` [batch] — and
-    `kv_scale` [n_block, 2, num_blocks, block_size] when the pool is
-    int8 — each new token attends over [its block table ; itself]
-    through `ops.attention.paged_decode_attention`.
+    block_size, heads * head_dim] (the engine's pool in the form it is
+    stored in, heads merged into each token's row — the block view of
+    kv_cache.py, a bitcast), `block_tables` [batch, max_blocks],
+    `ctx_len` [batch] — and `kv_scale` [n_block, 2, num_blocks,
+    block_size] when the pool is int8 — each new token attends over
+    [its block table ; itself] through
+    `ops.attention.paged_decode_attention`, which is handed the pool
+    WHOLE and this block's index: slicing `kv_pool[i]` here would make
+    XLA materialize a copy of every layer's K and V each step.
     Paged verify (t > 1, same args): speculative decoding's scoring
     pass — each lane's pending token plus its drafted tokens attend
     causally over [its block table ; themselves] through
@@ -102,23 +106,14 @@ class CausalLM(nn.Module):
             new_v.append(v.astype(jnp.float32))
             if kv_pool is not None and t == 1:
                 a = paged_decode_attention(
-                    q[:, 0], k[:, 0], v[:, 0],
-                    kv_pool[i, 0], kv_pool[i, 1], block_tables,
-                    ctx_len,
-                    k_scale=(None if kv_scale is None
-                             else kv_scale[i, 0]),
-                    v_scale=(None if kv_scale is None
-                             else kv_scale[i, 1]),
+                    q[:, 0], k[:, 0], v[:, 0], kv_pool, block_tables,
+                    ctx_len, layer=i, kv_scale=kv_scale,
                     impl=self.paged_attention_impl or "auto",
                     compute_dtype=self.compute_dtype)[:, None]
             elif kv_pool is not None:
                 a = paged_verify_attention(
-                    q, k, v, kv_pool[i, 0], kv_pool[i, 1],
-                    block_tables, ctx_len,
-                    k_scale=(None if kv_scale is None
-                             else kv_scale[i, 0]),
-                    v_scale=(None if kv_scale is None
-                             else kv_scale[i, 1]),
+                    q, k, v, kv_pool, block_tables, ctx_len, layer=i,
+                    kv_scale=kv_scale,
                     impl=self.paged_attention_impl or "auto",
                     compute_dtype=self.compute_dtype)
             elif ctx_k is not None:

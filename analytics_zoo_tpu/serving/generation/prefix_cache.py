@@ -260,16 +260,11 @@ class PrefixCache:
         tier = self.host_tier
         if tier is None or tier.capacity_bytes <= 0:
             return
-        bs = self.block_size
-        blk = victim.block
         try:
             t0 = now()
-            kv_np = np.asarray(
-                self.cache.kv[:, :, blk * bs:(blk + 1) * bs])
-            scale_np = (np.asarray(
-                self.cache.kv_scale[:, :, blk * bs:(blk + 1) * bs])
-                if self.cache.kv_scale is not None else None)
-            tier.put(self._key_for(victim), kv_np, scale_np,
+            kv, scale = self.cache.read_block(victim.block)
+            tier.put(self._key_for(victim), np.asarray(kv),
+                     None if scale is None else np.asarray(scale),
                      dur_s=now() - t0,
                      lane=getattr(self.owner, "spool_name", "engine"))
         except Exception:

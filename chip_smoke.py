@@ -452,12 +452,13 @@ def tp_phase(devices, *, model_kw=GPT2_SMALL, engine_kw=ENGINE,
             raise RuntimeError(
                 f"the tp decode step compiled {compiles} times, not once")
         kv = engine.cache.kv
-        shard_heads = {s.data.shape[3] for s in kv.addressable_shards}
+        # heads are contiguous slices of the pool's merged last axis
+        shard_cols = {s.data.shape[3] for s in kv.addressable_shards}
         kv_devices = {str(s.device) for s in kv.addressable_shards}
-        if shard_heads != {kv.shape[3] // n} or len(kv_devices) != n:
+        if shard_cols != {kv.shape[3] // n} or len(kv_devices) != n:
             raise RuntimeError(
                 f"KV pool is not head-sharded over {n} devices: shard "
-                f"heads {shard_heads}, devices {sorted(kv_devices)}")
+                f"columns {shard_cols}, devices {sorted(kv_devices)}")
         # paths joined as `parallel/sharding.py` joins them to match
         # a rule ("block_0_qkv/kernel" contains "qkv/kernel")
         named = [("/".join(str(k.key) for k in path), leaf) for path, leaf
@@ -526,16 +527,18 @@ def kernel_presence(*, batch=32, seq=128, hidden=768, heads=12,
     def auto_and_xla(op, **kw):
         return partial(op, impl="auto", **kw), partial(op, impl="xla", **kw)
 
-    lane, pool = (lanes, heads, hd), (nb, block, heads, hd)
+    # the pool whole, as the engine's decode step hands it over
+    lane, pool = (lanes, heads, hd), (2, 2, nb, block, heads * hd)
     tables = jnp.asarray(
         1 + rng.permutation(nb - 1).reshape(lanes, table), jnp.int32)
     ctx = jnp.asarray(rng.integers(1, table * block, lanes), jnp.int32)
     # name: (auto form, XLA form, arguments, Pallas calls expected)
     cases = {
         "paged_decode": (
-            *auto_and_xla(paged_decode_attention, compute_dtype=bf16),
+            *auto_and_xla(paged_decode_attention, layer=1,
+                          compute_dtype=bf16),
             (rand(lane, bf16), rand(lane, bf16), rand(lane, bf16),
-             rand(pool, bf16), rand(pool, bf16), tables, ctx), 1),
+             rand(pool, bf16), tables, ctx), 1),
         # the forward AND the backward kernel
         "layer_norm_fwd_bwd": (
             ln_grads("auto"), ln_grads("xla"),

@@ -93,11 +93,84 @@ def test_block_allocator_invariants():
 
 
 def test_paged_cache_shapes():
+    """One row of heads * head_dim columns per token slot; the block
+    view splits the slot axis and nothing else, and a block's slab is
+    what the host tier moves."""
+    from analytics_zoo_tpu.serving.generation.kv_cache import block_view
     c = PagedKVCache(n_layers=2, num_blocks=5, block_size=4, n_head=2,
                      head_dim=8)
-    assert c.kv.shape == (2, 2, 20, 2, 8)
+    assert c.kv.shape == (2, 2, 20, 16)
+    assert block_view(c.kv, 4).shape == (2, 2, 5, 4, 16)
+    assert c.slab_shape == (2, 2, 4, 16)
+    rows, scale = c.read_block(3)
+    assert rows.shape == c.slab_shape and scale is None
     assert c.blocks_for(1) == 1 and c.blocks_for(4) == 1
     assert c.blocks_for(5) == 2
+    q = PagedKVCache(n_layers=2, num_blocks=5, block_size=4, n_head=2,
+                     head_dim=8, quantization="int8")
+    assert q.kv_scale.shape == (2, 2, 20)
+    assert block_view(q.kv_scale, 4).shape == (2, 2, 5, 4)
+    assert q.read_block(3)[1].shape == q.slab_shape[:3]
+
+
+@pytest.mark.parametrize("quantization", [None, "int8"])
+@pytest.mark.parametrize("n_rows", [1, 7, 32])
+def test_write_kv_rows_match_the_layerwise_scatter(quantization, n_rows):
+    """`write_kv`'s ONE scatter of rows (index (layer, k/v, slot),
+    window a merged row) against the write it replaced — a
+    `kv.at[:, 0, dest]` / `kv.at[:, 1, dest]` scatter of [h, d] slabs
+    over a [..., heads, head_dim] pool — on a seeded pool: every slot
+    but the null block's (where the duplicates land: dead lanes and
+    padding, any winner) holds the same values, int8 scales included,
+    and `gather_kv` reads back what was written."""
+    from analytics_zoo_tpu.serving.generation.kv_cache import (
+        gather_kv, quantize_kv_tokens, write_kv)
+    L, nb, bs, h, d = 3, 6, 4, 2, 8
+    rng = np.random.default_rng(5 + n_rows)
+    c = PagedKVCache(n_layers=L, num_blocks=nb, block_size=bs, n_head=h,
+                     head_dim=d, quantization=quantization)
+    int8 = quantization == "int8"
+    if int8:
+        kv = jnp.asarray(rng.integers(-127, 128, c.kv.shape), jnp.int8)
+        scale = jnp.asarray(rng.uniform(0.01, 0.1, c.kv_scale.shape),
+                            jnp.float32)
+    else:
+        kv = jnp.asarray(rng.normal(size=c.kv.shape), jnp.float32)
+        scale = jnp.zeros((1,), jnp.float32)    # the engine's placeholder
+    # distinct live slots, then padding that all names the null block
+    live = bs + rng.permutation((nb - 1) * bs)[:max(1, n_rows - 3)]
+    dest = jnp.asarray(np.concatenate(
+        [live, np.zeros(n_rows - len(live), np.int64)]), jnp.int32)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(L, n_rows, h, d)),
+                                jnp.float32) for _ in range(2))
+    got_kv, got_scale = write_kv(kv, scale, dest, new_k, new_v)
+
+    old = kv.reshape(L, 2, nb * bs, h, d)
+    want_scale = scale
+    if int8:
+        (qk, sk), (qv, sv) = (quantize_kv_tokens(x)
+                              for x in (new_k, new_v))
+        old = old.at[:, 0, dest].set(qk).at[:, 1, dest].set(qv)
+        want_scale = scale.at[:, 0, dest].set(sk).at[:, 1, dest].set(sv)
+    else:
+        old = old.at[:, 0, dest].set(new_k).at[:, 1, dest].set(new_v)
+    assert got_kv.shape == kv.shape and got_kv.dtype == kv.dtype
+    np.testing.assert_array_equal(
+        np.asarray(got_kv)[:, :, bs:],
+        np.asarray(old.reshape(kv.shape))[:, :, bs:])
+    if int8:
+        np.testing.assert_array_equal(np.asarray(got_scale)[:, :, bs:],
+                                      np.asarray(want_scale)[:, :, bs:])
+    else:
+        assert got_scale is scale or np.array_equal(got_scale, scale)
+    # the read side of the same layout: heads split out of the gather
+    ctx_k, ctx_v = gather_kv(got_kv, got_scale, dest[:len(live)], h)
+    want_k, want_v = new_k[:, :len(live)], new_v[:, :len(live)]
+    tol = 0.05 if int8 else 0.0
+    np.testing.assert_allclose(np.asarray(ctx_k), np.asarray(want_k),
+                               atol=tol)
+    np.testing.assert_allclose(np.asarray(ctx_v), np.asarray(want_v),
+                               atol=tol)
 
 
 # ----------------------------------------------------------------------

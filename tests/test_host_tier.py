@@ -75,7 +75,7 @@ def _tier_engine(lm, **kw):
 
 def test_tier_lru_bounded_bytes_and_dedupe():
     tier = HostKVTier(300, registry=MetricsRegistry())
-    kv = np.zeros((1, 2, 4, 1, 4), np.float32)      # 128 bytes
+    kv = np.zeros((1, 2, 4, 4), np.float32)      # 128 bytes
     assert tier.put((1, 2, 3, 4), kv, None)
     assert tier.put((1, 2, 3, 4, 5, 6, 7, 8), kv, None)
     assert len(tier) == 2 and tier.bytes_used == 256
@@ -106,7 +106,7 @@ def test_tier_geometry_guard_refuses_mismatched_slabs():
                          n_head=1, head_dim=4)
     tier = HostKVTier(1 << 16, registry=MetricsRegistry())
     tier.bind_geometry(cache)
-    good = np.zeros((1, 2, 4, 1, 4), np.asarray(cache.kv).dtype)
+    good = np.zeros((1, 2, 4, 4), np.asarray(cache.kv).dtype)
     assert tier.put((1, 2, 3, 4), good, None)
     # wrong block size / unexpected scales: refused, tier unchanged
     assert not tier.put((9,), np.zeros((1, 2, 8, 1, 4), good.dtype),
@@ -127,7 +127,7 @@ def test_match_tokens_is_read_only_and_capped():
     tier = HostKVTier(1 << 16, registry=MetricsRegistry())
     tier.bind_geometry(cache)
     toks = list(range(12))
-    kv = np.zeros((1, 2, 4, 1, 4), np.asarray(cache.kv).dtype)
+    kv = np.zeros((1, 2, 4, 4), np.asarray(cache.kv).dtype)
     tier.put(tuple(toks[:4]), kv, None)
     tier.put(tuple(toks[:8]), kv, None)
     order_before = list(tier._entries)

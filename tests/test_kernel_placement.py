@@ -59,23 +59,23 @@ def _paged_case():
     nb = s * mb + 1
     q, nk, nv = (rng.normal(size=(s, h, d)).astype(np.float32)
                  for _ in range(3))
-    kp, vp = (rng.normal(size=(nb, bs, h, d)).astype(np.float32)
-              for _ in range(2))
+    # the whole pool in its block view, two layers; layer 1 is read
+    kv = rng.normal(size=(2, 2, nb, bs, h * d)).astype(np.float32)
     tables = (1 + rng.permutation(nb - 1)).reshape(s, mb).astype(np.int32)
     ctx = np.asarray([3, 17, 31, 9], np.int32)
     lane = NamedSharding(mesh, P(None, "tp", None))
-    pool = NamedSharding(mesh, P(None, None, "tp", None))
+    pool = NamedSharding(mesh, P(None, None, None, None, "tp"))
     rep = NamedSharding(mesh, P())
     args = (jax.device_put(q, lane), jax.device_put(nk, lane),
-            jax.device_put(nv, lane), jax.device_put(kp, pool),
-            jax.device_put(vp, pool), jax.device_put(tables, rep),
-            jax.device_put(ctx, rep))
+            jax.device_put(nv, lane), jax.device_put(kv, pool),
+            jax.device_put(tables, rep), jax.device_put(ctx, rep))
 
     def run(impl):
         def fn(*a):
             with declare_mesh(mesh):
                 return paged_decode_attention(
-                    *a, impl=impl, block_gather=2, interpret=True)
+                    *a, layer=1, impl=impl, block_gather=2,
+                    interpret=True)
         out = jax.jit(fn)(*args)
         if impl == "pallas":
             # the kernel ran per device on its own heads: the result

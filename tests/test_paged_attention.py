@@ -35,16 +35,22 @@ VOCAB = 61
 H, D = 4, 16
 
 
-def _scene(bs, mb, s=4, h=H, d=D, seed=0, quantized=False):
-    """One decode scene: a pool, per-lane tables and RAGGED ctx_lens —
-    lane 0 is freshly preempted (null table, ctx 0), lane 1 holds a
-    partial first block, the last lane is block-aligned full; the rest
-    land mid-block.  Tables beyond each lane's blocks stay null and
-    pool contents are garbage there — the mask must hide all of it."""
+L, LAYER = 3, 1
+
+
+def _scene(bs, mb, s=4, h=H, d=D, seed=0, quantized=False,
+           dtype=np.float32):
+    """One decode scene: a whole pool in the engine's block view
+    [L, 2, nb, bs, h*d] (the layers other than LAYER hold garbage of
+    their own — a kernel that read the wrong layer would see it),
+    per-lane tables and RAGGED ctx_lens — lane 0 is freshly preempted
+    (null table, ctx 0: a dead lane), lane 1 holds a partial first
+    block, the last lane is block-aligned full; the rest end inside a
+    block.  Tables beyond each lane's blocks stay null and pool
+    contents are garbage there — the mask must hide all of it."""
     rng = np.random.default_rng(seed)
     nb = s * mb + 1
-    kf = rng.normal(size=(nb, bs, h, d)).astype(np.float32)
-    vf = rng.normal(size=(nb, bs, h, d)).astype(np.float32)
+    pool = jnp.asarray(rng.normal(size=(L, 2, nb, bs, h, d)), dtype)
     tables = np.zeros((s, mb), np.int32)
     perm = 1 + rng.permutation(nb - 1)
     ctx = np.zeros(s, np.int32)
@@ -58,35 +64,31 @@ def _scene(bs, mb, s=4, h=H, d=D, seed=0, quantized=False):
     q = rng.normal(size=(s, h, d)).astype(np.float32)
     nk = rng.normal(size=(s, h, d)).astype(np.float32)
     nv = rng.normal(size=(s, h, d)).astype(np.float32)
-    scene = dict(q=q, new_k=nk, new_v=nv, tables=tables, ctx=ctx,
-                 k_pool=kf, v_pool=vf, k_scale=None, v_scale=None)
+    scale = None
     if quantized:
-        qk, sk = quantize_kv_tokens(jnp.asarray(kf))
-        qv, sv = quantize_kv_tokens(jnp.asarray(vf))
-        scene.update(k_pool=np.asarray(qk), v_pool=np.asarray(qv),
-                     k_scale=np.asarray(sk), v_scale=np.asarray(sv))
-    return scene
+        pool, scale = quantize_kv_tokens(pool)
+    return dict(q=q, new_k=nk, new_v=nv, tables=tables, ctx=ctx,
+                kv_pool=pool.reshape(L, 2, nb, bs, h * d),
+                kv_scale=scale)
 
 
 def _concat_reference(sc):
     """The pre-paged decode path, computed independently: host-side
-    gather (dequantizing first when the pool is int8) + the
-    dot_product_attention KV-cache read path."""
+    gather of LAYER's token rows (dequantizing first when the pool is
+    int8) + the dot_product_attention KV-cache read path."""
     s, h, d = sc["q"].shape
-    bs = sc["k_pool"].shape[1]
-    flat_k = sc["k_pool"].reshape(-1, h, d)
-    flat_v = sc["v_pool"].reshape(-1, h, d)
-    if sc["k_scale"] is not None:
-        flat_k = flat_k.astype(np.float32) \
-            * sc["k_scale"].reshape(-1)[:, None, None]
-        flat_v = flat_v.astype(np.float32) \
-            * sc["v_scale"].reshape(-1)[:, None, None]
+    bs = sc["kv_pool"].shape[3]
+    flat = np.asarray(sc["kv_pool"][LAYER].astype(jnp.float32)
+                      ).reshape(2, -1, h, d)
+    if sc["kv_scale"] is not None:
+        flat = flat * np.asarray(sc["kv_scale"][LAYER]).reshape(
+            2, -1, 1, 1)
     tok = (sc["tables"][:, :, None] * bs
            + np.arange(bs)[None, None, :]).reshape(s, -1)
     out = dot_product_attention(
         jnp.asarray(sc["q"])[:, None], jnp.asarray(sc["new_k"])[:, None],
         jnp.asarray(sc["new_v"])[:, None], compute_dtype=jnp.float32,
-        ctx_k=jnp.asarray(flat_k[tok]), ctx_v=jnp.asarray(flat_v[tok]),
+        ctx_k=jnp.asarray(flat[0][tok]), ctx_v=jnp.asarray(flat[1][tok]),
         ctx_len=jnp.asarray(sc["ctx"]))
     return np.asarray(out[:, 0])
 
@@ -94,13 +96,9 @@ def _concat_reference(sc):
 def _paged(sc, impl, block_gather=None):
     return np.asarray(paged_decode_attention(
         jnp.asarray(sc["q"]), jnp.asarray(sc["new_k"]),
-        jnp.asarray(sc["new_v"]), jnp.asarray(sc["k_pool"]),
-        jnp.asarray(sc["v_pool"]), jnp.asarray(sc["tables"]),
-        jnp.asarray(sc["ctx"]),
-        k_scale=(None if sc["k_scale"] is None
-                 else jnp.asarray(sc["k_scale"])),
-        v_scale=(None if sc["v_scale"] is None
-                 else jnp.asarray(sc["v_scale"])),
+        jnp.asarray(sc["new_v"]), sc["kv_pool"],
+        jnp.asarray(sc["tables"]), jnp.asarray(sc["ctx"]),
+        layer=LAYER, kv_scale=sc["kv_scale"],
         impl=impl, block_gather=block_gather,
         interpret=(True if impl == "pallas" else None)))
 
@@ -133,6 +131,34 @@ def test_pallas_parity_across_block_sizes_and_gather_configs():
             np.testing.assert_allclose(
                 out, ref, atol=2e-5, rtol=2e-5,
                 err_msg=f"bs={bs} cfg={cfg}")
+
+
+#: (heads, head_dim): GPT-2 small's 12 x 64 = 768 merged columns, six
+#: whole lane tiles; and a toy width whose 4 x 16 = 64 is no multiple
+#: of 128, which only has to be correct
+WIDTHS = {"768": (12, 64), "toy64": (4, 16)}
+
+
+@pytest.mark.parametrize("block_gather", [1, 2, 4])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_pallas_on_the_merged_layout(width, pool, block_gather):
+    """The kernel (interpret mode) on the pool as it is stored — whole,
+    [L, 2, nb, bs, h*d], the layer an index — against the XLA fallback
+    and, through it, the concat oracle: bf16 and int8 pools, every
+    gather width, a context that ends inside a block, a dead lane."""
+    h, d = WIDTHS[width]
+    sc = _scene(bs=16, mb=4, s=4, h=h, d=d, seed=31 + block_gather,
+                quantized=pool == "int8",
+                dtype=jnp.bfloat16 if pool == "bf16" else np.float32)
+    assert sc["kv_pool"].shape[-1] == h * d
+    assert sc["ctx"][0] == 0 and 0 < sc["ctx"][2] % 16   # dead, ragged
+    ref = _paged(sc, "xla")
+    np.testing.assert_array_equal(ref, _concat_reference(sc))
+    out = _paged(sc, "pallas", block_gather=block_gather)
+    np.testing.assert_allclose(out, ref, atol=3e-5, rtol=3e-5)
+    # the dead lane is pure self-attention, whatever the pool holds
+    np.testing.assert_allclose(out[0], sc["new_v"][0], atol=1e-6)
 
 
 def test_mid_preemption_lane_is_inert():
@@ -197,11 +223,7 @@ def test_int8_attention_close_to_f32_reference():
     """End-to-end quantization quality: int8 pool attention vs the
     same attention over the unquantized f32 pool."""
     sc32 = _scene(bs=8, mb=4, seed=17)
-    scq = dict(sc32)
-    qk, sk = quantize_kv_tokens(jnp.asarray(sc32["k_pool"]))
-    qv, sv = quantize_kv_tokens(jnp.asarray(sc32["v_pool"]))
-    scq.update(k_pool=np.asarray(qk), v_pool=np.asarray(qv),
-               k_scale=np.asarray(sk), v_scale=np.asarray(sv))
+    scq = _scene(bs=8, mb=4, seed=17, quantized=True)
     out32 = _paged(sc32, "xla")
     outq = _paged(scq, "xla")
     # |values| ~ N(0,1): per-element quant noise ~ amax/254 ~ 1.5e-2;

@@ -58,25 +58,25 @@ def topo():
 
 def _paged(pool_dtype, block_gather, lanes=8, d=64, bs=16):
     """The engine's decode attention, by default at the smoke's serve
-    shapes: 8 bf16 lanes, 12 heads x 64, block 16, tables for a 1024
-    context, the full lanes*blocks+1-block pool."""
+    shapes: 8 bf16 lanes, 12 heads x 64 merged into rows of 768,
+    block 16, tables for a 1024 context, the full lanes*blocks+1-block
+    pool handed over whole (two layers of it, the second one read)."""
     def build(place):
         from analytics_zoo_tpu.ops.pallas.paged_attention import (
             paged_decode_pallas)
         h, mb = 12, 1024 // bs
         nb = lanes * mb + 1
-        lane = place((lanes, h, d), jnp.bfloat16)
-        pool = place((nb, bs, h, d), pool_dtype)
-        args = [lane, lane, lane, pool, pool,
+        lane = place((lanes, h * d), jnp.bfloat16)
+        args = [lane, lane, lane, place((2, 2, nb, bs, h * d), pool_dtype),
                 place((lanes, mb), jnp.int32), place((lanes,), jnp.int32)]
         if pool_dtype == jnp.int8:
-            args += [place((nb, bs), jnp.float32)] * 2
+            args += [place((2, 2, nb, bs), jnp.float32)]
 
-        def fn(q, nk, nv, kp, vp, tbl, cl, *scales):
-            ks, vs = scales or (None, None)
+        def fn(q, nk, nv, pool, tbl, cl, scale=None):
             return paged_decode_pallas(
-                q, nk, nv, kp, vp, tbl, cl, k_scale=ks, v_scale=vs,
-                block_gather=block_gather, interpret=False)
+                q, nk, nv, pool, tbl, cl, layer=1, head_dim=d,
+                kv_scale=scale, block_gather=block_gather,
+                interpret=False)
         return fn, args
     return build
 
@@ -130,7 +130,8 @@ def _paged_tp(place):
     itself (`ops.attention.paged_decode_attention`, impl pinned since
     `auto` asks the backend, which is the CPU here) inside a program
     GSPMD partitions over a 4-device mesh, pool and lanes head-sharded
-    as `serving/distributed/tp.py` shards them.  Mosaic refuses a
+    as `serving/distributed/tp.py` shards them (a head shard is a
+    contiguous slice of the pool's merged axis).  Mosaic refuses a
     kernel GSPMD would have to partition, so this compiles only while
     the dispatcher carries the kernel in a shard_map."""
     from analytics_zoo_tpu.ops.attention import paged_decode_attention
@@ -138,16 +139,16 @@ def _paged_tp(place):
     s, h, d, bs, mb = 8, 12, 64, 16, 64
     nb = s * mb + 1
     lane = place((s, h, d), jnp.bfloat16, P(None, "tp", None))
-    pool = place((nb, bs, h, d), jnp.bfloat16,
-                 P(None, None, "tp", None))
+    pool = place((2, 2, nb, bs, h * d), jnp.bfloat16,
+                 P(None, None, None, None, "tp"))
     mesh = lane.sharding.mesh
 
-    def fn(q, nk, nv, kp, vp, tbl, cl):
+    def fn(q, nk, nv, kv, tbl, cl):
         with declare_mesh(mesh):
             return paged_decode_attention(
-                q, nk, nv, kp, vp, tbl, cl, impl="pallas",
+                q, nk, nv, kv, tbl, cl, layer=1, impl="pallas",
                 block_gather=8, interpret=False)
-    return fn, [lane, lane, lane, pool, pool,
+    return fn, [lane, lane, lane, pool,
                 place((s, mb), jnp.int32), place((s,), jnp.int32)]
 
 
@@ -211,3 +212,72 @@ def test_tuning_table_block_gathers_compile_for_v5e(topo):
         build = _paged(jnp.dtype(dtype), row["config"]["block_gather"],
                        lanes=dim["lanes"], d=dim["d"], bs=dim["bs"])
         _assert_kernel_compiles(build, _one_chip(topo), key)
+
+
+def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
+    """The engine's own `decode` and 1024-bucket `prefill` programs at
+    `gpt2_small_serve`'s widths and full pool (32 lanes x 64 blocks of
+    16 + the null block; 2 layers are enough, a relayout scales with
+    the pool and not with the depth), compiled for the described v5e:
+    no `copy` whose result has the pool's shape, and temporaries under
+    a quarter of the pool.  The pool relaid out whole was 75% of
+    serving's device time until PR 29 (PERF.md section 6); the cure
+    engages on every step or not at all, so this compile is its
+    guard."""
+    import re
+
+    from analytics_zoo_tpu.serving.generation import (
+        CausalLM, GenerationEngine)
+    model = CausalLM(vocab=50257, hidden_size=768, n_head=12, n_block=2,
+                     intermediate_size=3072, max_position_len=1024,
+                     compute_dtype=jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+            jnp.arange(8)[None])["params"]))
+    # the engine asks the backend whether to donate the pool, and the
+    # dispatchers whether to take their Pallas form: here it is a TPU
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = GenerationEngine(
+        model, params, max_slots=32, block_size=16, max_context=1024,
+        prefill_buckets=[128, 256, 512, 1024], cache_dtype=jnp.bfloat16)
+    place = _one_chip(topo)
+
+    def like(x):
+        return place(x.shape, x.dtype)
+    s, mb = 32, eng.scheduler.max_blocks_per_seq
+    state = (jax.tree_util.tree_map(like, eng.params),
+             like(eng.cache.kv), like(eng._kv_scale))
+    programs = {
+        "decode": (eng._decode_jit.fn, (
+            place((s,), jnp.int32), place((s, mb), jnp.int32),
+            place((s,), jnp.int32), place((s,), jnp.bool_),
+            place((s,), jnp.float32), place((s,), jnp.int32))),
+        "prefill": (eng._prefill_jit.fn, (
+            place((1, 1024), jnp.int32), place((), jnp.int32),
+            place((mb,), jnp.int32), place((1,), jnp.float32),
+            place((1,), jnp.int32))),
+    }
+    pool = eng.cache.kv
+    pool_bytes = pool.size * pool.dtype.itemsize
+    l, _, slots, hd = pool.shape
+    shapes = "|".join(
+        re.escape("bf16[" + ",".join(map(str, dims)) + "]")
+        for dims in ((l, 2, slots, hd), (l, 2, slots // 16, 16, hd)))
+    pool_copy = re.compile(rf"= (?:{shapes})\S* copy\(")
+    # the prefill keeps f32 logits of all 1024 positions to use one row
+    # (PERF.md section 5): 206 MB that are not the pool's
+    allowance = {"decode": 0, "prefill": 1024 * model.vocab * 4}
+    for name, (fn, args) in programs.items():
+        compiled = fn.lower(*state, *args, like(eng._rng)).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text, name
+        copies = [ln.strip()[:200] for ln in text.splitlines()
+                  if pool_copy.search(ln)]
+        assert not copies, f"{name} copies the whole pool: {copies}"
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < pool_bytes // 4 + allowance[name], (
+            f"{name}: {temp} bytes of temporaries beside a pool of "
+            f"{pool_bytes}")
