@@ -35,7 +35,8 @@ from jax.sharding import PartitionSpec as P
 def dot_product_attention(q, k, v, mask=None, causal: bool = False,
                           dropout_rate: float = 0.0, dropout_rng=None,
                           compute_dtype=jnp.bfloat16,
-                          ctx_k=None, ctx_v=None, ctx_len=None):
+                          ctx_k=None, ctx_v=None, ctx_len=None,
+                          window: Optional[int] = None):
     """q, k, v: [batch, time, heads, head_dim] (BTHD).  `mask` is an
     additive float mask broadcastable to [batch, heads, q_time, k_time].
     Returns [batch, time, heads, head_dim].
@@ -50,8 +51,25 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     masked out: decoding with time=1 is O(ctx) instead of the O(ctx^2)
     full recompute.  `mask`/`causal` are ignored on this path (causal
     semantics are implied); dropout is unsupported (decode is
-    inference-only)."""
+    inference-only).
+
+    Grouped queries and windows: k/v (and ctx_k/ctx_v) may carry FEWER
+    heads than q — query head i then reads KV head i // (q heads / KV
+    heads) — and `window` w hides every key more than w - 1 positions
+    behind its query (key j is visible to query p iff j <= p and
+    p - j < w).  Either one takes `_grouped_attention`, the same
+    mathematics with the KV heads kept as an axis of their own; plain
+    multi-head attention without a window runs the code below as it
+    always has."""
     b, t, h, d = q.shape
+    if k.shape[2] != h or window is not None:
+        if dropout_rate > 0.0:
+            raise ValueError("dropout is not supported with grouped "
+                             "query heads or a window")
+        return _grouped_attention(
+            q, k, v, mask=mask, causal=causal,
+            compute_dtype=compute_dtype, ctx_k=ctx_k, ctx_v=ctx_v,
+            ctx_len=ctx_len, window=window)
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q = q.astype(compute_dtype)
     k = k.astype(compute_dtype)
@@ -95,6 +113,52 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     return out.astype(jnp.float32)
 
 
+def _grouped_attention(q, k, v, *, mask, causal, compute_dtype, ctx_k,
+                       ctx_v, ctx_len, window):
+    """`dot_product_attention` for g KV heads under h = g * r query
+    heads and/or a window: q [b, t, h, d], k/v [b, t, g, d] (and
+    ctx_k/ctx_v [b, c, g, d]).  Scores are [b, g, r, q, k] — a K or V
+    head is read once for its r queries, never repeated in memory."""
+    b, t, h, d = q.shape
+    g = k.shape[2]
+    if h % g:
+        raise ValueError(f"{h} query heads do not divide over {g} KV "
+                         f"heads")
+    r = h // g
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    q = q.astype(compute_dtype).reshape(b, t, g, r, d)
+    keys, vals = k.astype(compute_dtype), v.astype(compute_dtype)
+    if ctx_k is not None:
+        c = ctx_k.shape[1]
+        ctx_len = jnp.asarray(ctx_len, jnp.int32)
+        keys = jnp.concatenate([ctx_k.astype(compute_dtype), keys], 1)
+        vals = jnp.concatenate([ctx_v.astype(compute_dtype), vals], 1)
+        col = jnp.arange(c + t)[None, :]
+        k_pos = jnp.where(col < c, col, ctx_len[:, None] + (col - c))
+        q_pos = ctx_len[:, None] + jnp.arange(t)[None]
+        valid = ((k_pos[:, None, :] <= q_pos[:, :, None])
+                 & ((col >= c) | (col < ctx_len[:, None]))[:, None, :])
+    else:
+        k_pos = q_pos = jnp.arange(t)[None]
+        valid = (k_pos[:, None, :] <= q_pos[:, :, None]) if causal \
+            else jnp.ones((1, t, t), bool)
+    if window is not None:
+        valid = valid & (q_pos[:, :, None] - k_pos[:, None, :]
+                         < int(window))
+    scores = (jnp.einsum("bqgrd,bkgd->bgrqk", q, keys)
+              .astype(jnp.float32) * scale)
+    scores = jnp.where(valid[:, None, None], scores, -1e9)
+    if mask is not None and ctx_k is None:
+        mask = jnp.asarray(mask)
+        scores = scores + (mask[:, :, None] if mask.shape[1] == 1 else
+                           mask.reshape(mask.shape[0], g, r,
+                                        *mask.shape[2:]))
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(compute_dtype),
+                     vals)
+    return out.reshape(b, t, h, d).astype(jnp.float32)
+
+
 def _paged_context(kv_pool, kv_scale, layer, which, block_tables, h):
     """Every lane's table blocks of `layer`'s K (which=0) or V (1),
     gathered out of the pool's block view as a [S, C, h, d] context and
@@ -115,7 +179,8 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
                            impl: str = "auto",
                            block_gather: Optional[int] = None,
                            compute_dtype=jnp.float32,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None):
     """Decode-step attention of one new token per lane over its paged
     KV cache — the generation engine's hot path (docs/kernels.md,
     docs/generation.md).
@@ -135,6 +200,15 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
     null-table padding and mid-preemption lanes cost nothing).
     Returns [S, heads, head_dim] float32.
 
+    Grouped queries: new_k / new_v (and the pool's rows) may carry g KV
+    heads under q's h = g * q_per_kv query heads; query head i reads
+    KV head i // q_per_kv, and one staged K/V block serves all the
+    q_per_kv queries of a KV head.  `window` w (a window layer): the
+    token at position ctx_len sees itself and the w - 1 cached
+    positions before it; the kernel walks only the table's blocks that
+    reach into them, so what a window layer READS stops growing with
+    the context (the blocks behind the window stay allocated).
+
     impl: "auto" (Pallas on TPU, XLA elsewhere) | "pallas" | "xla".
     The XLA fallback gathers the context and runs the exact
     `dot_product_attention` KV-cache read path (concat-attend) — the
@@ -144,6 +218,7 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
     kernel's gather width; `interpret=True` runs the kernel on the CPU
     interpreter (tests)."""
     s, h, d = q.shape
+    g = new_k.shape[1]                 # KV heads (= h without grouping)
     bs = kv_pool.shape[3]
     if impl == "auto":
         try:
@@ -156,10 +231,10 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
             q[:, None], new_k[:, None], new_v[:, None],
             compute_dtype=compute_dtype,
             ctx_k=_paged_context(kv_pool, kv_scale, layer, 0,
-                                 block_tables, h),
+                                 block_tables, g),
             ctx_v=_paged_context(kv_pool, kv_scale, layer, 1,
-                                 block_tables, h),
-            ctx_len=ctx_len)
+                                 block_tables, g),
+            ctx_len=ctx_len, window=window)
         return out[:, 0]
     if impl != "pallas":
         raise ValueError(f"unknown paged_decode_attention impl "
@@ -172,18 +247,19 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
         interpret = jax.devices()[0].platform == "cpu"
     if block_gather is None:
         block_gather = tuned_paged_block_gather(
-            bs, s, h, d, kv_pool.dtype, mb=block_tables.shape[1])
+            bs, s, g, d, kv_pool.dtype, mb=block_tables.shape[1])
 
     def kernel(q, new_k, new_v, kv_pool, block_tables, ctx_len,
                kv_scale=None):
         return paged_decode_pallas(
             q, new_k, new_v, kv_pool, block_tables, ctx_len,
             layer=layer, head_dim=d, kv_scale=kv_scale,
-            block_gather=block_gather, interpret=interpret)
+            block_gather=block_gather, interpret=interpret,
+            q_per_kv=h // g, window=window)
 
     # the kernel's rows are the pool's: heads merged into h*d columns
-    args = (q.reshape(s, h * d), new_k.reshape(s, h * d),
-            new_v.reshape(s, h * d), kv_pool, block_tables, ctx_len)
+    args = (q.reshape(s, h * d), new_k.reshape(s, g * d),
+            new_v.reshape(s, g * d), kv_pool, block_tables, ctx_len)
     if kv_scale is not None:
         args += (kv_scale,)
     from analytics_zoo_tpu.parallel.sharding import (
@@ -197,7 +273,7 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
         # (serving/distributed/tp.py) with the tables, lengths and
         # per-token scales whole
         n_tp = mesh_axis_size("tp", mesh)
-        tp = "tp" if n_tp > 1 and h % n_tp == 0 else None
+        tp = "tp" if n_tp > 1 and g % n_tp == 0 else None
         lane, pool = P(None, tp), P(None, None, None, None, tp)
         kernel = shard_map_compat(
             kernel, mesh=mesh,
@@ -209,7 +285,8 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
 def paged_verify_attention(q, new_k, new_v, kv_pool, block_tables,
                            ctx_len, *, layer: int, kv_scale=None,
                            impl: str = "auto",
-                           compute_dtype=jnp.float32):
+                           compute_dtype=jnp.float32,
+                           window: Optional[int] = None):
     """Verify-step attention of q_len>1 new tokens per lane over its
     paged KV cache — speculative decoding's scoring pass
     (serving/generation/speculation.py; docs/generation.md).
@@ -220,7 +297,8 @@ def paged_verify_attention(q, new_k, new_v, kv_pool, block_tables,
     [cached context ; themselves], exactly the chunk-prefill read
     semantics (`dot_product_attention`'s ctx path).
     kv_pool / layer / block_tables / ctx_len / kv_scale: as in
-    `paged_decode_attention`.  Returns [S, T, heads, head_dim] float32.
+    `paged_decode_attention`, grouped KV heads and `window` too.
+    Returns [S, T, heads, head_dim] float32.
 
     impl: "auto" | "pallas" | "xla" — all three currently run the XLA
     gather path (the decode fallback generalized to T queries); a
@@ -230,11 +308,11 @@ def paged_verify_attention(q, new_k, new_v, kv_pool, block_tables,
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown paged_verify_attention impl "
                          f"{impl!r}; use 'auto', 'pallas' or 'xla'")
-    h = q.shape[2]
+    g = new_k.shape[2]
     return dot_product_attention(
         q, new_k, new_v, compute_dtype=compute_dtype,
         ctx_k=_paged_context(kv_pool, kv_scale, layer, 0, block_tables,
-                             h),
+                             g),
         ctx_v=_paged_context(kv_pool, kv_scale, layer, 1, block_tables,
-                             h),
-        ctx_len=ctx_len)
+                             g),
+        ctx_len=ctx_len, window=window)

@@ -156,3 +156,28 @@ class LayerNorm(nn.Module):
         bias = self.param("bias", nn.initializers.zeros_init(), (d,))
         return layer_norm(x, scale, bias, eps=self.epsilon,
                           impl=self.impl, out_dtype=self.dtype)
+
+
+def rms_norm(x, scale, *, eps: float = 1e-5, out_dtype=None):
+    """RMSNorm over the last axis of `x` [..., d]: x * rsqrt(mean(x^2)
+    + eps) * scale, the statistics and the product in float32.  Plain
+    XLA on every backend (a fused kernel would land here, as the
+    LayerNorm one did)."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                           + eps) * scale.astype(jnp.float32)
+    return y.astype(x.dtype if out_dtype is None else out_dtype)
+
+
+class RMSNorm(nn.Module):
+    """`rms_norm` with its learned "scale" [d] (ones; held in
+    `param_dtype`); `dtype` is what the result is handed on in."""
+    epsilon: float = 1e-5
+    dtype: Optional[Any] = None
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones_init(),
+                           (x.shape[-1],), self.param_dtype)
+        return rms_norm(x, scale, eps=self.epsilon, out_dtype=self.dtype)

@@ -130,8 +130,18 @@ def _rows(xs):
     return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=0)
 
 
+def _first_block(cl, window: Optional[int], bs: int):
+    """The first table entry a lane at context `cl` reads: 0 without a
+    window, else the block that holds position cl - window + 1 (the
+    oldest cached position the pending token at position cl sees)."""
+    if window is None:
+        return 0
+    return jnp.maximum(cl - (window - 1), 0) // bs
+
+
 def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
-            g: int, bs: int, num_j: int, quantized: bool, scale: float):
+            g: int, bs: int, num_j: int, quantized: bool, scale: float,
+            window: Optional[int] = None):
     # scalar prefetch: tbl_ref [S, MB] block tables, cl_ref [S] ctx
     # lengths.  q/nk/nv_ref: [1, h*d] lane rows; e_ref [h*d, h] and
     # et_ref [h, h*d] the head indicators (fetched once: their block
@@ -170,16 +180,22 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
         o_scr[...] = nv_ref[...].astype(jnp.float32)
 
     cl = cl_ref[s_idx]
+    # the rows of this grid step start at position `base`: group j of
+    # the table, counted from the first block the window reaches into
+    base = j * n if window is None \
+        else _first_block(cl, window, bs) * bs + j * n
 
     # block groups entirely past the lane's context are all-masked:
     # skip their compute (the DMAs still stream by, cheaply — the
     # shapes stay static, which is the zero-recompile contract)
-    @pl.when(j * n < cl)
+    @pl.when(base < cl)
     def _compute():
         kt = _rows([k[...].astype(jnp.float32) for k in ks])  # [n, hd]
         vt = _rows([v[...].astype(jnp.float32) for v in vs])
-        pos = j * n + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
         valid = pos < cl                                 # [n, 1]
+        if window is not None:
+            valid = valid & (pos > cl - window)
         s = heads(kt * qv) * scale                       # [n, h]
         if quantized:
             # the scales arrive as [1, bs] rows and scale ROWS here:
@@ -212,10 +228,104 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
                       ).astype(o_ref.dtype)
 
 
+#: lanes the running max / denominator of the grouped kernel are kept
+#: over (every lane holds the same number: a [rows, 1] scratch is no
+#: whole tile)
+_STAT_LANES = 128
+
+
+def _split3(x):
+    """x f32 [r, k] as its three bf16 pieces stacked along the rows."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
+def _dot_f32(x, w, contract_w: int):
+    """x [r, k] f32 times a K or V tile `w` as it lies in the pool,
+    contracting w's axis `contract_w` (1: x @ w.T, 0: x @ w), to f32.
+    A bf16 tile takes ONE bf16 MXU pass over x's three bf16 pieces and
+    is exact to f32, like `_dot_exact`; any other pool dtype (the f32
+    pools of the CPU tests) multiplies in f32."""
+    dims = (((1,), (contract_w,)), ((), ()))
+    if w.dtype == jnp.bfloat16:
+        r = x.shape[0]
+        y = jax.lax.dot_general(_split3(x), w, dims,
+                                preferred_element_type=jnp.float32)
+        return y[:r] + y[r:2 * r] + y[2 * r:]
+    return jax.lax.dot_general(x, w.astype(jnp.float32), dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _kernel_gqa(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
+                bs: int, num_j: int, kv_heads: int, r: int, d: int,
+                window: Optional[int], scale: float):
+    # grouped queries: q_ref [h, d], the h = kv_heads * r query heads of
+    # one lane as rows (KV head c's queries are rows c*r .. c*r + r - 1);
+    # nk_ref / nv_ref [h, d] the new token's key / value of each query
+    # head's KV head.  rest: g gathered K blocks [bs, kv_heads * d], g V
+    # blocks, then o_ref [h, d] and the o [h, d] / m / l [h, 128] VMEM
+    # scratch carried across the block axis.  A KV head's columns are a
+    # static slice of the staged tile — whole lanes at d = 128 — and its
+    # r queries meet them in one MXU product: one read of a K/V block
+    # serves r queries.
+    rest = list(rest)
+    ks = [rest.pop(0) for _ in range(g)]
+    vs = [rest.pop(0) for _ in range(g)]
+    o_ref, o_scr, m_scr, l_scr = rest
+    s_idx = pl.program_id(0)
+    j = pl.program_id(1)
+    n = g * bs
+    cl = cl_ref[s_idx]
+    base = _first_block(cl, window, bs) * bs + j * n
+
+    @pl.when(j == 0)
+    def _init():
+        # the new token attends to itself: see `_kernel`
+        qv = q_ref[...].astype(jnp.float32)
+        s_self = (qv * nk_ref[...].astype(jnp.float32)).sum(
+            axis=1, keepdims=True) * scale               # [h, 1]
+        m_scr[...] = jnp.broadcast_to(s_self, m_scr.shape)
+        l_scr[...] = jnp.ones_like(l_scr)
+        o_scr[...] = nv_ref[...].astype(jnp.float32)
+
+    @pl.when(base < cl)
+    def _compute():
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+        valid = pos < cl                                 # [1, n]
+        if window is not None:
+            valid = valid & (pos > cl - window)
+        for c in range(kv_heads):
+            rows = slice(c * r, (c + 1) * r)
+            cols = slice(c * d, (c + 1) * d)
+            kc = _rows([k[:, cols] for k in ks])         # [n, d]
+            vc = _rows([v[:, cols] for v in vs])
+            s = _dot_f32(q_ref[rows, :].astype(jnp.float32), kc, 1) \
+                * scale                                  # [r, n]
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_scr[rows, 0:1]                    # [r, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[rows, 0:1] * alpha + p.sum(axis=1,
+                                                     keepdims=True)
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (r, _STAT_LANES))
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (r, _STAT_LANES))
+            o_scr[rows, :] = o_scr[rows, :] * alpha + _dot_f32(p, vc, 0)
+
+    @pl.when(j == num_j - 1)
+    def _finalize():
+        o_ref[...] = (o_scr[...] / l_scr[:, 0:1]).astype(o_ref.dtype)
+
+
 def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
                         *, layer: int, head_dim: int, kv_scale=None,
                         block_gather: int = DEFAULT_BLOCK_GATHER,
-                        interpret: bool = False):
+                        interpret: bool = False, q_per_kv: int = 1,
+                        window: Optional[int] = None):
     """The raw kernel call (dispatch through
     `ops.attention.paged_decode_attention`, which picks impl and asks
     the tuner for `block_gather`).
@@ -230,6 +340,16 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     block_tables: [S, max_blocks] int32; ctx_len: [S] int32 valid
     lengths (cached position p lives at table[p // bs], slot p % bs).
     Returns [S, h*d] float32.
+
+    q_per_kv > 1 (grouped queries): q is [S, h*d] over h = kv_heads *
+    q_per_kv query heads while new_k / new_v and the pool's rows are
+    [.., kv_heads * d]; query head i reads KV head i // q_per_kv
+    (`_kernel_gqa`: one staged K/V tile, q_per_kv query rows against
+    each KV head's columns).  `window` w: the pending token sees itself
+    and the w - 1 cached positions before it; the grid walks
+    ceil((w - 1) / bs) + 1 table entries from the block of position
+    ctx_len - w + 1 on, whatever the context's length, and masks the
+    rest inside.  Without either, the kernel is the one it was.
     """
     s, hd = q.shape
     bs = kv_pool.shape[3]
@@ -237,6 +357,15 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     mb = block_tables.shape[1]
     g = max(1, int(block_gather))
     quantized = kv_scale is not None
+    reach = None
+    if window is not None:
+        window = int(window)
+        if window < 1:
+            raise ValueError(f"window must be at least 1, got {window}")
+        # the w - 1 cached positions in sight touch at most `reach`
+        # blocks: gather no more than that at a time
+        reach = -(-(window - 1) // bs) + 1
+        g = min(g, reach)
     # pad the table up to a multiple of g with null blocks — their
     # positions sit past every ctx_len, so the mask kills them
     if mb % g:
@@ -244,8 +373,56 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
         block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
         mb += pad
     num_j = mb // g
+    if reach is not None:
+        num_j = min(num_j, -(-reach // g))
     block_tables = block_tables.astype(jnp.int32)
     ctx_len = jnp.asarray(ctx_len, jnp.int32)
+
+    def _entry(si, j, i, tbl, cl):
+        # the table entry grid step j's i-th block reads: counted from
+        # the window's first block, held inside the table (an entry
+        # past the context is masked whatever it names)
+        if window is None:
+            return tbl[si, j * g + i]
+        first = _first_block(cl[si], window, bs)
+        return tbl[si, jnp.minimum(first + j * g + i, mb - 1)]
+
+    if q_per_kv > 1:
+        if quantized:
+            raise NotImplementedError(
+                "the grouped-query paged kernel reads no int8 pool yet")
+        if h % q_per_kv:
+            raise ValueError(f"{h} query heads do not divide by "
+                             f"q_per_kv {q_per_kv}")
+        kv_heads, d = h // q_per_kv, head_dim
+        heads = pl.BlockSpec((None, h, d),
+                             lambda si, j, tbl, cl: (si, 0, 0))
+
+        def per_query_head(x):     # [S, kv_heads*d] -> [S, h, d]
+            return jnp.repeat(x.reshape(s, kv_heads, d), q_per_kv, axis=1)
+
+        pool = [pl.BlockSpec(
+            (None, None, None, bs, kv_heads * d),
+            lambda si, j, tbl, cl, w=w, i=i: (
+                layer, w, _entry(si, j, i, tbl, cl), 0, 0))
+            for w in (0, 1) for i in range(g)]
+        out = pl.pallas_call(
+            partial(_kernel_gqa, g=g, bs=bs, num_j=num_j,
+                    kv_heads=kv_heads, r=q_per_kv, d=d, window=window,
+                    scale=1.0 / (head_dim ** 0.5)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(s, num_j),
+                in_specs=[heads, heads, heads] + pool, out_specs=heads,
+                scratch_shapes=[
+                    pltpu.VMEM((h, d), jnp.float32),
+                    pltpu.VMEM((h, _STAT_LANES), jnp.float32),
+                    pltpu.VMEM((h, _STAT_LANES), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
+            interpret=interpret,
+        )(block_tables, ctx_len, q.reshape(s, h, d),
+          per_query_head(new_k), per_query_head(new_v),
+          *[kv_pool] * (2 * g))
+        return out.reshape(s, hd)
 
     # a [1, hd] block of an [S, hd] array breaks Mosaic's (8, 128)
     # block rule; of the [S, 1, hd] view it is the last two dims whole
@@ -260,8 +437,8 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
         # (1, bs) of the scales' [..., num_blocks, 1, bs] view
         return pl.BlockSpec(
             (None, None, None) + rows,
-            lambda si, j, tbl, cl: (layer, which, tbl[si, j * g + i],
-                                    0, 0))
+            lambda si, j, tbl, cl: (layer, which,
+                                    _entry(si, j, i, tbl, cl), 0, 0))
 
     e = (jnp.arange(hd)[:, None] // head_dim
          == jnp.arange(h)[None, :]).astype(jnp.bfloat16)
@@ -289,7 +466,7 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     )
     return pl.pallas_call(
         partial(_kernel, g=g, bs=bs, num_j=num_j, quantized=quantized,
-                scale=1.0 / (head_dim ** 0.5)),
+                scale=1.0 / (head_dim ** 0.5), window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, hd), jnp.float32),
         interpret=interpret,

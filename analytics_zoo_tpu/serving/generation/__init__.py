@@ -22,6 +22,10 @@ subsystem:
 * `CausalLM` — a GPT-style decoder on
   `ops.attention.dot_product_attention`'s KV-cache read path
   (model.py), with greedy/temperature/top-k sampling (sampling.py).
+* `DecoderLM` — a decoder described by configuration (decoder.py):
+  RMSNorm, rotary positions, grouped query heads, window and full
+  attention layers mixed, dense and expert (`ExpertLayer`) FFNs, under
+  the same call contract, so the one engine serves both.
 * `Speculator` / `ngram_draft` — draft-free speculative decoding:
   n-gram prompt-lookup proposals verified k-at-a-time by one compiled
   step, accepted-prefix emission, free-list rollback (speculation.py;
@@ -45,6 +49,10 @@ from analytics_zoo_tpu.serving.generation.kv_cache import (  # noqa: F401
     dequantize_kv_tokens,
     quantize_kv_tokens,
 )
+from analytics_zoo_tpu.serving.generation.decoder import (  # noqa: F401
+    DecoderLM,
+    ExpertLayer,
+)
 from analytics_zoo_tpu.serving.generation.model import (  # noqa: F401
     CausalLM,
 )
@@ -64,7 +72,8 @@ from analytics_zoo_tpu.serving.generation.speculation import (  # noqa: F401,E50
     ngram_draft,
 )
 
-__all__ = ["BlockAllocator", "CausalLM", "GenerationEngine",
+__all__ = ["BlockAllocator", "CausalLM", "DecoderLM", "ExpertLayer",
+           "GenerationEngine",
            "GenerationStream", "PagedKVCache", "PrefixCache",
            "QueueFull", "RequestTooLarge", "Sequence", "SlotScheduler",
            "SpecState", "Speculator", "dequantize_kv_tokens",

@@ -80,10 +80,12 @@ from analytics_zoo_tpu.observability import (
     step_clock,
     tracing,
 )
+from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
     block_view,
     gather_kv,
+    pool_geometry,
     write_kv,
 )
 from analytics_zoo_tpu.resilience.faults import (
@@ -187,11 +189,19 @@ class GenerationEngine:
         #: analytic FLOPs model for MFU accounting — the dispatch
         #: ledger combines these with the fenced walls below; None when
         #: the model doesn't carry the CausalLM dims (a stand-in model
-        #: in tests), which simply zeroes the MFU gauges
+        #: in tests) or says its blocks are not CausalLM's (a model
+        #: with a `kv_geometry` of its own: grouped heads, experts —
+        #: counting it as 4d^2 + 2d*ff a block would put wrong MFU
+        #: gauges on /dispatch), which simply zeroes the MFU gauges
         try:
-            self._flops = profiling.CausalLMFlops.from_model(model)
+            self._flops = (None if hasattr(model, "kv_geometry") else
+                           profiling.CausalLMFlops.from_model(model))
         except (AttributeError, TypeError):
             self._flops = None
+        #: features a model cannot serve are refused here, by name,
+        #: before anything is placed (decoder.py says which and why)
+        refused = set(getattr(model, "unsupported_features",
+                              lambda: ())())
         #: tensor-parallel decode (serving/distributed/tp.py) — "auto"
         #: reads OrcaContext.decode_tensor_parallel; 0 (the default)
         #: keeps the legacy single-device placement bitwise untouched
@@ -200,6 +210,10 @@ class GenerationEngine:
                 as _Ctx
             tensor_parallel = _Ctx.decode_tensor_parallel
         self.tensor_parallel = int(tensor_parallel or 0)
+        if self.tensor_parallel > 1 and "tensor_parallel" in refused:
+            raise NotImplementedError(
+                f"{type(model).__name__} cannot be served with "
+                f"tensor_parallel={self.tensor_parallel}")
         if self.tensor_parallel > 1:
             from analytics_zoo_tpu.serving.distributed.tp import (
                 TensorParallelPlacement)
@@ -226,6 +240,10 @@ class GenerationEngine:
             kv_quantization = OrcaContext.kv_cache_quantization
         self.kv_quantization = kv_quantization
         self._quantized = kv_quantization == "int8"
+        if self._quantized and "kv_quantization" in refused:
+            raise NotImplementedError(
+                f"{type(model).__name__} cannot be served from an int8 "
+                f"KV pool")
         #: radix-tree prompt-prefix reuse (prefix_cache.py) — "auto"
         #: reads OrcaContext.prefix_caching; off (the default) keeps
         #: the engine bitwise-identical to the pre-cache behavior
@@ -255,10 +273,10 @@ class GenerationEngine:
         if num_blocks is None:
             # comfortable default: every lane can hold a full context
             num_blocks = max_slots * (-(-max_context // block_size)) + 1
+        n_layers, kv_heads, head_dim = pool_geometry(model)
         self.cache = PagedKVCache(
-            model.n_block, num_blocks, block_size, model.n_head,
-            model.hidden_size // model.n_head, dtype=cache_dtype,
-            quantization=kv_quantization)
+            n_layers, num_blocks, block_size, kv_heads, head_dim,
+            dtype=cache_dtype, quantization=kv_quantization)
         #: functional scale state fed to the jitted steps alongside
         #: `cache.kv` — a 1-element placeholder when quantization is
         #: off (the steps return it untouched)
@@ -387,6 +405,35 @@ class GenerationEngine:
         reg.gauge("generation_preemptions",
                   fn=lambda: self.scheduler.n_preemptions,
                   help="sequences preempted under cache pressure")
+        #: expert layers (a model with `moe_counts_shape`): tokens each
+        #: held expert computed, by layer and global expert id (the
+        #: registry has no labels: they ride in the name), where the
+        #: router's assignments went, and the ones lost on the way
+        self._moe_shape = getattr(model, "moe_counts_shape", None)
+        if self._moe_shape is not None:
+            first, held = model.held
+            self._c_moe_tokens = [
+                [reg.counter(
+                    f"generation_moe_expert_tokens_total_layer{layer}"
+                    f"_expert{first + e}",
+                    help="tokens this expert computed")
+                 for e in range(held)] for layer in model.moe_layers]
+            self._c_moe_held = reg.counter(
+                "generation_moe_assignments_total_held",
+                help="router assignments to experts held here")
+            self._c_moe_elsewhere = reg.counter(
+                "generation_moe_assignments_total_elsewhere",
+                help="router assignments to experts other chips hold")
+            self._c_moe_dropped = reg.counter(
+                "generation_moe_dropped_total",
+                help="assignments to held experts that no expert "
+                     "computed (must read 0)")
+            self._c_moe_loads = {
+                program: reg.counter(
+                    f"generation_moe_expert_loads_total_{program}",
+                    help="(layer, held expert) pairs a dispatch had a "
+                         "token for: expert weights it had to read")
+                for program in ("prefill", "decode")}
         self._c_cow = (reg.counter(
             "prefix_cache_cow_copies_total",
             help="shared blocks copy-on-write un-shared before a "
@@ -485,29 +532,42 @@ class GenerationEngine:
         # warns, so only donate off-CPU
         donate = ((1, 2) if jax.devices()[0].platform != "cpu" else ())
 
+        counted = self._moe_shape is not None
+
+        def apply(params, *args, token_mask=None, **kw):
+            # model.apply and, beside its outputs, the expert layers'
+            # counts where the model has any (decoder.py sows them;
+            # `token_mask` tells it which tokens are real).  A model
+            # without them is called exactly as it always was.
+            if not counted:
+                return model.apply({"params": params}, *args, **kw), ()
+            out, state = model.apply(
+                {"params": params}, *args, token_mask=token_mask,
+                mutable=[MOE_COUNTS], **kw)
+            return out, (state[MOE_COUNTS]["tokens"],)
+
         def paged_apply(params, kv, kv_scale, tokens, pos, block_tables,
-                        ctx_len):
+                        ctx_len, real=None):
             # the pool goes to the model whole, as its block view (a
             # bitcast — kv_cache.block_view), with each lane's block
             # table: the attention op gathers pool blocks by table
             # index itself (ops/pallas/paged_attention.py), so neither
             # a [S, C, h, d] context nor a per-layer slice of the pool
             # is ever materialized
-            return model.apply(
-                {"params": params}, tokens, pos,
+            return apply(
+                params, tokens, pos, token_mask=real,
                 kv_pool=block_view(kv, bs),
                 kv_scale=block_view(kv_scale, bs) if quantized else None,
                 block_tables=block_tables, ctx_len=ctx_len)
 
         def concat_apply(params, kv, kv_scale, tokens, pos, tok_idx,
-                         ctx_len):
+                         ctx_len, real=None):
             # the context gathered out of the pool by token slot
             # (kv_cache.gather_kv) and attended by the concat read
             # path: the parity oracle, and the chunk step's read
             ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
-            return model.apply(
-                {"params": params}, tokens, pos,
-                ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
+            return apply(params, tokens, pos, token_mask=real,
+                         ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
 
         def prefill(params, kv, kv_scale, tokens, length, block_table,
                     temperature, top_k, rng):
@@ -517,9 +577,8 @@ class GenerationEngine:
             B = tokens.shape[1]
             pos = jnp.minimum(jnp.arange(B), max_pos - 1)
             token_mask = (jnp.arange(B) < length)[None]
-            logits, new_k, new_v = model.apply(
-                {"params": params}, tokens, pos[None],
-                token_mask=token_mask)
+            (logits, new_k, new_v), counts = apply(
+                params, tokens, pos[None], token_mask=token_mask)
             dest = block_table[jnp.arange(B) // bs] * bs \
                 + jnp.arange(B) % bs
             dest = jnp.where(jnp.arange(B) < length, dest, 0)
@@ -527,7 +586,7 @@ class GenerationEngine:
                                     new_k[:, 0], new_v[:, 0])
             last = logits[0, length - 1]
             nxt = sample_tokens(last[None], rng, temperature, top_k)[0]
-            return kv, kv_scale, nxt, last
+            return (kv, kv_scale, nxt, last) + counts
 
         def decode(params, kv, kv_scale, tokens, block_tables, ctx_len,
                    active, temperature, top_k, rng):
@@ -537,16 +596,16 @@ class GenerationEngine:
             S, MB = block_tables.shape
             pos = jnp.minimum(ctx_len, max_pos - 1)
             if paged:
-                logits, new_k, new_v = paged_apply(
+                (logits, new_k, new_v), counts = paged_apply(
                     params, kv, kv_scale, tokens[:, None], pos[:, None],
-                    block_tables, ctx_len)
+                    block_tables, ctx_len, active[:, None])
             else:
                 tok_idx = (block_tables[:, :, None] * bs
                            + jnp.arange(bs)[None, None, :]
                            ).reshape(S, -1)
-                logits, new_k, new_v = concat_apply(
+                (logits, new_k, new_v), counts = concat_apply(
                     params, kv, kv_scale, tokens[:, None], pos[:, None],
-                    tok_idx, ctx_len)
+                    tok_idx, ctx_len, active[:, None])
             dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
                 + ctx_len % bs
             dest = jnp.where(active, dest, 0)   # dead lanes → null block
@@ -554,7 +613,7 @@ class GenerationEngine:
                                     new_k[:, :, 0], new_v[:, :, 0])
             last = jnp.where(active[:, None], logits[:, 0], 0.0)
             nxt = sample_tokens(last, rng, temperature, top_k)
-            return kv, kv_scale, nxt, last
+            return (kv, kv_scale, nxt, last) + counts
 
         def chunk_prefill(params, kv, kv_scale, tokens, start, length,
                           block_table, temperature, top_k, rng):
@@ -574,9 +633,10 @@ class GenerationEngine:
             pos = jnp.minimum(start + rel, max_pos - 1)
             tok_idx = (block_table[:, None] * bs
                        + jnp.arange(bs)[None, :]).reshape(1, -1)
-            logits, new_k, new_v = concat_apply(
+            (logits, new_k, new_v), counts = concat_apply(
                 params, kv, kv_scale, tokens, pos[None], tok_idx,
-                jnp.reshape(start, (1,)).astype(jnp.int32))
+                jnp.reshape(start, (1,)).astype(jnp.int32),
+                (rel < length)[None])
             dest = block_table[(start + rel) // bs] * bs \
                 + (start + rel) % bs
             dest = jnp.where(rel < length, dest, 0)
@@ -584,7 +644,7 @@ class GenerationEngine:
                                     new_k[:, 0], new_v[:, 0])
             last = logits[0, length - 1]
             nxt = sample_tokens(last[None], rng, temperature, top_k)[0]
-            return kv, kv_scale, nxt, last
+            return (kv, kv_scale, nxt, last) + counts
 
         def spec_verify(params, kv, kv_scale, tokens, block_tables,
                         start, length, active):
@@ -603,16 +663,18 @@ class GenerationEngine:
             S, W = tokens.shape
             rel = jnp.arange(W)
             pos = jnp.minimum(start[:, None] + rel[None], max_pos - 1)
+            real = (rel[None] < length[:, None]) & active[:, None]
             if paged:
-                logits, new_k, new_v = paged_apply(
+                (logits, new_k, new_v), counts = paged_apply(
                     params, kv, kv_scale, tokens, pos, block_tables,
-                    start)
+                    start, real)
             else:
                 tok_idx = (block_tables[:, :, None] * bs
                            + jnp.arange(bs)[None, None, :]
                            ).reshape(S, -1)
-                logits, new_k, new_v = concat_apply(
-                    params, kv, kv_scale, tokens, pos, tok_idx, start)
+                (logits, new_k, new_v), counts = concat_apply(
+                    params, kv, kv_scale, tokens, pos, tok_idx, start,
+                    real)
             abs_pos = start[:, None] + rel[None]        # [S, W]
             dest = block_tables[jnp.arange(S)[:, None],
                                 abs_pos // bs] * bs + abs_pos % bs
@@ -624,7 +686,7 @@ class GenerationEngine:
                 new_k.reshape(L, S * W, *new_k.shape[-2:]),
                 new_v.reshape(L, S * W, *new_v.shape[-2:]))
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return kv, kv_scale, greedy
+            return (kv, kv_scale, greedy) + counts
 
         def copy_block(kv, kv_scale, src, dst):
             # copy-on-write: duplicate one pool block's token slots
@@ -782,13 +844,13 @@ class GenerationEngine:
                 if self._use_chunks:
                     if b not in chunk_buckets:
                         continue
-                    kv, scl, _, _ = self._chunk_jit(
+                    kv, scl, *_ = self._chunk_jit(
                         self.params, self.cache.kv, self._kv_scale,
                         jnp.zeros((1, b), jnp.int32), jnp.int32(0),
                         jnp.int32(1), jnp.zeros(MB, jnp.int32),
                         one, onek, self._rng)
                 else:
-                    kv, scl, _, _ = self._prefill_jit(
+                    kv, scl, *_ = self._prefill_jit(
                         self.params, self.cache.kv, self._kv_scale,
                         jnp.zeros((1, b), jnp.int32), jnp.int32(1),
                         jnp.zeros(MB, jnp.int32), one, onek, self._rng)
@@ -814,7 +876,7 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
                 self._goodput_warm.add("host_restore")
             S = self.max_slots
-            kv, scl, _, _ = self._decode_jit(
+            kv, scl, *_ = self._decode_jit(
                 self.params, self.cache.kv, self._kv_scale,
                 jnp.zeros(S, jnp.int32),
                 jnp.zeros((S, MB), jnp.int32), jnp.zeros(S, jnp.int32),
@@ -825,7 +887,7 @@ class GenerationEngine:
                 # every verify k-bucket compiles here too (inactive
                 # grid: all writes land in the null block)
                 for b in self.speculation.buckets:
-                    kv, scl, _ = self._spec_jit(
+                    kv, scl, *_ = self._spec_jit(
                         self.params, self.cache.kv, self._kv_scale,
                         jnp.zeros((S, 1 + b), jnp.int32),
                         jnp.zeros((S, MB), jnp.int32),
@@ -980,6 +1042,25 @@ class GenerationEngine:
         if reason:
             self._finish(seq, reason)
 
+    def _account_moe(self, fetched, program: str) -> None:
+        """Add one dispatch's expert counts ([expert layers, held + 2]:
+        decoder.ExpertLayer) to the registry; `fetched` is empty for a
+        model without expert layers.  `program`: "prefill" (chunks
+        too) or "decode" (verify rounds too)."""
+        if not fetched:
+            return
+        counts = np.asarray(fetched[0])
+        held = counts.shape[1] - 2
+        self._c_moe_loads[program].inc(int((counts[:, :held] > 0).sum()))
+        for row, counters in zip(counts, self._c_moe_tokens):
+            for n, counter in zip(row[:held], counters):
+                if n:
+                    counter.inc(int(n))
+        to_held = int(counts[:, held].sum())
+        self._c_moe_held.inc(to_held)
+        self._c_moe_elsewhere.inc(int(counts[:, held + 1].sum()) - to_held)
+        self._c_moe_dropped.inc(to_held - int(counts[:, :held].sum()))
+
     def _end_step(self, rec) -> None:
         """Close a step record: the goodput commit (counters, the
         timeline ring, the memory sampler) is accounting like the rest,
@@ -1002,7 +1083,7 @@ class GenerationEngine:
             t0 = now()
             rec.cold = ("prefill", bucket) not in self._goodput_warm
             with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _ = self._prefill_jit(
+                kv, scl, nxt, _, *moe = self._prefill_jit(
                     self.params, self.cache.kv, self._kv_scale,
                     jnp.asarray(tokens), jnp.int32(L),
                     jnp.asarray(table),
@@ -1011,7 +1092,9 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
+                moe = jax.device_get(moe)
             with rec.phase("generation.account"):
+                self._account_moe(moe, "prefill")
                 self._goodput_warm.add(("prefill", bucket))
                 dur = now() - t0
                 self._h_prefill.record(dur, L)
@@ -1082,7 +1165,7 @@ class GenerationEngine:
             t0 = now()
             rec.cold = ("chunk", bucket) not in self._goodput_warm
             with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _ = self._chunk_jit(
+                kv, scl, nxt, _, *moe = self._chunk_jit(
                     self.params, self.cache.kv, self._kv_scale,
                     jnp.asarray(tokens), jnp.int32(start),
                     jnp.int32(real), jnp.asarray(table),
@@ -1091,7 +1174,9 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
+                moe = jax.device_get(moe)
             with rec.phase("generation.account"):
+                self._account_moe(moe, "prefill")
                 self._goodput_warm.add(("chunk", bucket))
                 dur = now() - t0
                 self._h_prefill.record(dur, real)
@@ -1295,7 +1380,7 @@ class GenerationEngine:
             t0 = now()
             rec.cold = ("spec", W - 1) not in self._goodput_warm
             with rec.phase("generation.dispatch"):
-                kv, scl, greedy = self._spec_jit(
+                kv, scl, greedy, *moe = self._spec_jit(
                     self.params, self.cache.kv, self._kv_scale,
                     jnp.asarray(tokens), jnp.asarray(tables),
                     jnp.asarray(start), jnp.asarray(length),
@@ -1303,11 +1388,13 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 greedy = np.asarray(greedy)  # token fetch = device fence
+                moe = jax.device_get(moe)
             # accounting for every lane, then emission for every lane:
             # each request's log keeps its order (decode round, token,
             # finish), and a trace shows two spans, not two a lane
             accepted = []
             with rec.phase("generation.account"):
+                self._account_moe(moe, "decode")
                 self._goodput_warm.add(("spec", W - 1))
                 dur = now() - t0
                 self._h_decode.record(dur, len(drafted) + len(riders))
@@ -1418,7 +1505,7 @@ class GenerationEngine:
             t0 = now()
             rec.cold = "decode" not in self._goodput_warm
             with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _ = self._decode_jit(
+                kv, scl, nxt, _, *moe = self._decode_jit(
                     self.params, self.cache.kv, self._kv_scale,
                     jnp.asarray(tokens), jnp.asarray(tables),
                     jnp.asarray(ctx_len), jnp.asarray(active),
@@ -1427,10 +1514,12 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = np.asarray(nxt)     # token fetch = device fence
+                moe = jax.device_get(moe)
             # accounting for every lane, then emission for every lane:
             # each request's log keeps its order (decode round, token,
             # finish), and a trace shows two spans, not two a lane
             with rec.phase("generation.account"):
+                self._account_moe(moe, "decode")
                 self._goodput_warm.add("decode")
                 dur = now() - t0
                 self._h_decode.record(dur, len(lanes))

@@ -82,6 +82,19 @@ def dequantize_kv_tokens(q, scale):
     return q.astype(jnp.float32) * scale[..., None, None]
 
 
+def pool_geometry(model) -> Tuple[int, int, int]:
+    """(layers, KV heads, head dim) of the pool `model` needs: what
+    the model says of itself (`kv_geometry()`: grouped heads, a head
+    that is not hidden / heads), else the plain multi-head reading of
+    `CausalLM`'s fields.  The one place the pool's geometry is read
+    from a model."""
+    geometry = getattr(model, "kv_geometry", None)
+    if geometry is not None:
+        return tuple(int(n) for n in geometry())
+    return (int(model.n_block), int(model.n_head),
+            int(model.hidden_size) // int(model.n_head))
+
+
 def block_view(x, block_size: int):
     """The pool [L, 2, slots, h*d] (or its scale vectors [L, 2, slots])
     with the slot axis split into [num_blocks, block_size] — what the

@@ -81,6 +81,47 @@ def _paged(pool_dtype, block_gather, lanes=8, d=64, bs=16):
     return build
 
 
+def _paged_gqa(block_gather, window):
+    """The grouped-query kernel at `kexaone_236b_ep8_serve`'s decode
+    shapes: 64 lanes, 64 query heads over 8 KV heads of 128 (pool rows
+    of 1024), block 16, tables for a 1024 context, a bf16 pool; with
+    the 128-position window of a window layer or without."""
+    def build(place):
+        from analytics_zoo_tpu.ops.pallas.paged_attention import (
+            paged_decode_pallas)
+        lanes, h, g, d, bs = 64, 64, 8, 128, 16
+        mb = 1024 // bs
+        nb = lanes * mb + 1
+        args = [place((lanes, h * d), jnp.bfloat16),
+                place((lanes, g * d), jnp.bfloat16),
+                place((lanes, g * d), jnp.bfloat16),
+                place((2, 2, nb, bs, g * d), jnp.bfloat16),
+                place((lanes, mb), jnp.int32), place((lanes,), jnp.int32)]
+
+        def fn(q, nk, nv, pool, tbl, cl):
+            return paged_decode_pallas(
+                q, nk, nv, pool, tbl, cl, layer=1, head_dim=d,
+                block_gather=block_gather, interpret=False,
+                q_per_kv=h // g, window=window)
+        return fn, args
+    return build
+
+
+def _paged_window(place):
+    """The multi-head kernel with a window (no model serves it yet; it
+    shares the index map and the mask with the grouped one)."""
+    from analytics_zoo_tpu.ops.pallas.paged_attention import (
+        paged_decode_pallas)
+    lanes, h, d, bs, mb = 8, 12, 64, 16, 64
+    lane = place((lanes, h * d), jnp.bfloat16)
+    return (lambda q, nk, nv, pool, tbl, cl: paged_decode_pallas(
+                q, nk, nv, pool, tbl, cl, layer=1, head_dim=d,
+                block_gather=8, interpret=False, window=128),
+            [lane, lane, lane,
+             place((2, 2, lanes * mb + 1, bs, h * d), jnp.bfloat16),
+             place((lanes, mb), jnp.int32), place((lanes,), jnp.int32)])
+
+
 def _layer_norm(dtype):
     """BERT-base's LayerNorm (batch 32 x seq 128 rows, hidden 768),
     forward and backward."""
@@ -157,6 +198,11 @@ CASES = {
     # 8 = what ops/tuning/default_tables.json names for this key
     "paged_bf16_g8": _paged(jnp.bfloat16, 8),
     "paged_int8_g8": _paged(jnp.int8, 8),
+    # 8 = the table's row for lanes=64, d=128 (the expert-layer cell)
+    "paged_gqa_bf16_g8_full": _paged_gqa(8, None),
+    "paged_gqa_bf16_g8_window128": _paged_gqa(8, 128),
+    "paged_gqa_bf16_g1_window128": _paged_gqa(1, 128),
+    "paged_bf16_g8_window128": _paged_window,
     "layer_norm_fwd_bwd_bf16": _layer_norm(jnp.bfloat16),
     "layer_norm_fwd_bwd_f32": _layer_norm(jnp.float32),
     "bias_gelu_fwd_bf16": _bias_gelu,
