@@ -318,10 +318,16 @@ def _callsite() -> str:
 
 class LedgeredFunction:
     """The jit-wrap hook: forwards calls to the wrapped (jitted)
-    callable, derives each call's abstract signature, and records
-    compile events for signatures the family has not dispatched
-    before.  Forwards ``_cache_size`` so the engines'
-    ``decode_compile_count`` pin keeps reading the REAL jit cache."""
+    callable, counts them, and records a compile event for every
+    signature the family has not dispatched before.  The abstract
+    signature is derived only for a call that compiled — the wrapped
+    function's own ``_cache_size()`` grew across it, or the family has
+    no signature yet — never on a warm call: that walk over every
+    argument leaf (150 for a decode step) would describe again what the
+    jit cache already knows.  A callable without ``_cache_size`` (not a
+    jit) is described on every call, as there is nothing cheaper to ask.
+    Forwards ``_cache_size`` so the engines' ``decode_compile_count``
+    pin keeps reading the REAL jit cache."""
 
     def __init__(self, family: str, fn: Callable,
                  argnames: Optional[Sequence[str]] = None):
@@ -329,24 +335,31 @@ class LedgeredFunction:
         self.fn = fn
         self.argnames = tuple(argnames) if argnames else None
         self._fam = _family(family)
+        #: argument bytes of the variant this wrapper compiled last:
+        #: what a warm call adds to the family's `bytes_total`
+        self._arg_bytes = 0
         inner = getattr(fn, "_cache_size", None)
         if inner is not None:
             self._cache_size = inner
 
     def __call__(self, *args):
         fam = self._fam
-        sig = abstract_signature(args, self.argnames)
-        with _lock:
-            known = sig in fam.signatures
+        size = getattr(self, "_cache_size", None)
+        before = size() if size is not None else -1
         t0 = now()
         out = self.fn(*args)
         dur = now() - t0
-        if not known:
-            _record_compile(fam, sig, dur, _callsite())
+        if size is None or size() != before or not fam.signatures:
+            sig = abstract_signature(args, self.argnames)
+            with _lock:
+                known = sig in fam.signatures
+            if not known:
+                _record_compile(fam, sig, dur, _callsite())
+            self._arg_bytes = fam.signatures[sig]
         reg = get_registry()
         with _lock:
             fam.calls += 1
-            fam.bytes_total += fam.signatures.get(sig, 0)
+            fam.bytes_total += self._arg_bytes
         reg.counter(
             "dispatch_calls_total",
             help="ledgered jit dispatches, all families").inc()
@@ -407,8 +420,8 @@ def instrument(family: str, fn: Callable,
                ) -> LedgeredFunction:
     """Register `fn` (a jitted callable) under a dispatch-ledger
     family.  The wrapper is transparent to the zero-recompile pin
-    (``_cache_size`` forwards) and adds one signature derivation per
-    call."""
+    (``_cache_size`` forwards) and derives a signature only for a
+    call that compiled."""
     return LedgeredFunction(family, fn, argnames)
 
 

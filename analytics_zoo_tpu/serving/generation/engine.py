@@ -1,9 +1,10 @@
 """Continuous-batching decode engine.
 
 The hot loop is ONE jitted decode step over a fixed `max_slots`-lane
-grid — tokens [S], block tables [S, max_blocks], context lengths [S],
-an active-lane mask [S] and per-lane sampling params.  Sequences join
-and leave between steps by mutating those host arrays, never the
+grid — a row a lane (pending token, position, active flag, sampling
+params, block table) and the sampling key, resident on the device and
+advanced by the step itself (lane_state.py).  Sequences join and leave
+between steps as dirty rows of that state, never as a change to the
 compiled program: steady-state serving triggers ZERO recompiles after
 `warmup()` (asserted in tests via the jit cache size).  Prefill runs
 per-sequence over power-of-two length buckets, so any prompt length
@@ -80,6 +81,7 @@ from analytics_zoo_tpu.observability import (
     step_clock,
     tracing,
 )
+from analytics_zoo_tpu.serving.generation import lane_state
 from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
@@ -354,22 +356,10 @@ class GenerationEngine:
         #: registry label ("model@version") stamped on this engine's
         #: request-log records; None outside a ModelRegistry
         self.model_label: Optional[str] = None
-        self._rng = jax.random.PRNGKey(seed)
-        if self._tp is not None:
-            # commit the key to the mesh once; splits stay on-mesh, so
-            # no step ever mixes single-device and mesh-committed args
-            self._rng = self._tp.put_replicated(self._rng)
-        else:
-            # same invariant off-mesh: when the params are committed
-            # to one chip of a multi-chip host (a pinned replica),
-            # commit the key there too — jax.random.split of an
-            # UNcommitted key executes on the default device, so the
-            # loop thread's key would drift off the replica's chip and
-            # fork a second pjit cache entry, breaking zero-recompile
-            leaf = jax.tree_util.tree_leaves(self.params)[0]
-            if getattr(leaf, "committed", False):
-                self._rng = jax.device_put(
-                    self._rng, next(iter(leaf.devices())))
+        #: lane rows + sampling key, resident where the steps run
+        self._lanes = lane_state.LaneState(
+            self.scheduler, seed,
+            lane_state.placement(self.params, self._tp), reg)
         self._lock = threading.RLock()
         self._wake = threading.Event()
         self._stop = threading.Event()
@@ -531,6 +521,9 @@ class GenerationEngine:
         # vectors) in place; the CPU backend ignores donation and
         # warns, so only donate off-CPU
         donate = ((1, 2) if jax.devices()[0].platform != "cpu" else ())
+        # ... and the lane state with them, where a step takes it
+        donate_lanes = donate and donate + (3,)
+        width = self._lanes.width
 
         counted = self._moe_shape is not None
 
@@ -569,11 +562,15 @@ class GenerationEngine:
             return apply(params, tokens, pos, token_mask=real,
                          ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
 
-        def prefill(params, kv, kv_scale, tokens, length, block_table,
-                    temperature, top_k, rng):
-            # tokens [1, B] (bucket-padded), length scalar, block_table
-            # [max_blocks]; writes KV for the `length` real tokens and
-            # samples the first new token from the last real position
+        def prefill(params, kv, kv_scale, lanes, request):
+            # request = [slot | the lane's row | tokens, bucket-padded]
+            # (lane_state.split_request): writes KV for the row's
+            # `length` real tokens, samples the first new token from
+            # the last real position with the state's key, and leaves
+            # the row — that token pending — in the lane's place
+            slot, row, tokens = lane_state.split_request(request, width)
+            _, length, _, temperature, top_k, block_table = \
+                lane_state.fields(row)
             B = tokens.shape[1]
             pos = jnp.minimum(jnp.arange(B), max_pos - 1)
             token_mask = (jnp.arange(B) < length)[None]
@@ -585,14 +582,23 @@ class GenerationEngine:
             kv, kv_scale = write_kv(kv, kv_scale, dest,
                                     new_k[:, 0], new_v[:, 0])
             last = logits[0, length - 1]
-            nxt = sample_tokens(last[None], rng, temperature, top_k)[0]
-            return (kv, kv_scale, nxt, last) + counts
+            rng, sub = jax.random.split(lanes["rng"])
+            nxt = sample_tokens(last[None], sub, temperature[None],
+                                top_k[None])[0]
+            rows = lane_state.admitted(lanes["rows"], slot, row, nxt)
+            return (kv, kv_scale, nxt, last,
+                    {"rows": rows, "rng": rng}) + counts
 
-        def decode(params, kv, kv_scale, tokens, block_tables, ctx_len,
-                   active, temperature, top_k, rng):
-            # ONE static-shape step for all lanes: tokens [S] (each
-            # lane's pending token), ctx_len [S] (= its position),
-            # block_tables [S, max_blocks], active [S] lane mask
+        def decode(params, kv, kv_scale, lanes, patch):
+            # ONE static-shape step for all lanes, over the resident
+            # rows once `patch` (the rows the host changed, or none)
+            # is applied: tokens [S] (each lane's pending token),
+            # ctx_len [S] (= its position), block_tables [S,
+            # max_blocks], active [S] lane mask.  Hands the rows back
+            # advanced and the key split, for the next round
+            rows = lane_state.patched(lanes["rows"], patch)
+            tokens, ctx_len, active, temperature, top_k, block_tables \
+                = lane_state.fields(rows)
             S, MB = block_tables.shape
             pos = jnp.minimum(ctx_len, max_pos - 1)
             if paged:
@@ -612,8 +618,11 @@ class GenerationEngine:
             kv, kv_scale = write_kv(kv, kv_scale, dest,
                                     new_k[:, :, 0], new_v[:, :, 0])
             last = jnp.where(active[:, None], logits[:, 0], 0.0)
-            nxt = sample_tokens(last, rng, temperature, top_k)
-            return (kv, kv_scale, nxt, last) + counts
+            rng, sub = jax.random.split(lanes["rng"])
+            nxt = sample_tokens(last, sub, temperature, top_k)
+            return (kv, kv_scale, nxt, last,
+                    {"rows": lane_state.advanced(rows, nxt),
+                     "rng": rng}) + counts
 
         def chunk_prefill(params, kv, kv_scale, tokens, start, length,
                           block_table, temperature, top_k, rng):
@@ -627,7 +636,8 @@ class GenerationEngine:
             # ops.attention's ctx path) plus itself causally, writes
             # its KV into block slots, and samples from its last real
             # position — only the FINAL chunk's sample is consumed by
-            # the host.
+            # the host.  `rng` is the lane state's key: split here as
+            # the other steps split it, its successor handed back.
             B = tokens.shape[1]
             rel = jnp.arange(B)
             pos = jnp.minimum(start + rel, max_pos - 1)
@@ -643,8 +653,9 @@ class GenerationEngine:
             kv, kv_scale = write_kv(kv, kv_scale, dest,
                                     new_k[:, 0], new_v[:, 0])
             last = logits[0, length - 1]
-            nxt = sample_tokens(last[None], rng, temperature, top_k)[0]
-            return (kv, kv_scale, nxt, last) + counts
+            rng, sub = jax.random.split(rng)
+            nxt = sample_tokens(last[None], sub, temperature, top_k)[0]
+            return (kv, kv_scale, nxt, last, rng) + counts
 
         def spec_verify(params, kv, kv_scale, tokens, block_tables,
                         start, length, active):
@@ -725,15 +736,11 @@ class GenerationEngine:
         # compile-event differ so a recompile post-mortem names the
         # guilty leaf as e.g. `tokens: int32[4] -> int32[5]`.
         _ledger = profiling.instrument
-        _names_prefill = ("params", "kv", "kv_scale", "tokens",
-                          "length", "block_table", "temperature",
-                          "top_k", "rng")
+        _names_prefill = ("params", "kv", "kv_scale", "lanes", "request")
         _names_chunk = ("params", "kv", "kv_scale", "tokens", "start",
                         "length", "block_table", "temperature",
                         "top_k", "rng")
-        _names_decode = ("params", "kv", "kv_scale", "tokens",
-                         "block_tables", "ctx_len", "active",
-                         "temperature", "top_k", "rng")
+        _names_decode = ("params", "kv", "kv_scale", "lanes", "patch")
         _names_spec = ("params", "kv", "kv_scale", "tokens",
                        "block_tables", "start", "length", "active")
         if self._tp is not None:
@@ -742,11 +749,11 @@ class GenerationEngine:
             # tokens/logits replicated) so every step's outputs feed
             # the next step in the same layout (zero-recompile holds)
             self._prefill_jit = _ledger(
-                "prefill", self._tp.jit_step(prefill, donate, 4),
+                "prefill", self._tp.jit_step(prefill, donate_lanes, 5),
                 argnames=_names_prefill)
             self._chunk_jit = _ledger(
                 "chunk_prefill",
-                self._tp.jit_step(chunk_prefill, donate, 4),
+                self._tp.jit_step(chunk_prefill, donate, 5),
                 argnames=_names_chunk)
             self._copy_block_jit = _ledger(
                 "copy_block",
@@ -755,7 +762,7 @@ class GenerationEngine:
                 argnames=("kv", "kv_scale", "src", "dst"))
             self._restore_block_jit = None   # host tier off under TP
             self._decode_jit = _ledger(
-                "decode", self._tp.jit_step(decode, donate, 4),
+                "decode", self._tp.jit_step(decode, donate_lanes, 5),
                 argnames=_names_decode)
             self._spec_jit = _ledger(
                 "spec_verify",
@@ -763,7 +770,7 @@ class GenerationEngine:
                 argnames=_names_spec)
         else:
             self._prefill_jit = _ledger(
-                "prefill", jax.jit(prefill, donate_argnums=donate),
+                "prefill", jax.jit(prefill, donate_argnums=donate_lanes),
                 argnames=_names_prefill)
             self._chunk_jit = _ledger(
                 "chunk_prefill",
@@ -780,7 +787,7 @@ class GenerationEngine:
                         donate_argnums=((0, 1) if donate else ())),
                 argnames=("kv", "kv_scale", "dst", "rows", "srows"))
             self._decode_jit = _ledger(
-                "decode", jax.jit(decode, donate_argnums=donate),
+                "decode", jax.jit(decode, donate_argnums=donate_lanes),
                 argnames=_names_decode)
             self._spec_jit = _ledger(
                 "spec_verify",
@@ -832,11 +839,14 @@ class GenerationEngine:
         """Compile the decode step and every prefill bucket — of the
         chunk-prefill program when prefix caching / chunked prefill is
         on, of the legacy whole-prompt program otherwise — on dummy
-        inputs (all writes land in the null block)."""
+        inputs (all writes land in the null block, no lane is active;
+        the key is put back as it was and every row resent after)."""
         with self._lock:
             MB = self.scheduler.max_blocks_per_seq
             one = jnp.zeros(1, jnp.float32)
             onek = jnp.zeros(1, jnp.int32)
+            lanes = self._lanes
+            key = lanes.key()
             chunk_buckets = [
                 b for b in self.scheduler.prefill_buckets
                 if not self.chunked_prefill or b <= self._chunk_cap]
@@ -844,16 +854,16 @@ class GenerationEngine:
                 if self._use_chunks:
                     if b not in chunk_buckets:
                         continue
-                    kv, scl, *_ = self._chunk_jit(
-                        self.params, self.cache.kv, self._kv_scale,
-                        jnp.zeros((1, b), jnp.int32), jnp.int32(0),
-                        jnp.int32(1), jnp.zeros(MB, jnp.int32),
-                        one, onek, self._rng)
+                    kv, scl, _, _, lanes.state["rng"], *_ = \
+                        self._chunk_jit(
+                            self.params, self.cache.kv, self._kv_scale,
+                            jnp.zeros((1, b), jnp.int32), jnp.int32(0),
+                            jnp.int32(1), jnp.zeros(MB, jnp.int32),
+                            one, onek, lanes.state["rng"])
                 else:
-                    kv, scl, *_ = self._prefill_jit(
+                    kv, scl, _, _, lanes.state, *_ = self._prefill_jit(
                         self.params, self.cache.kv, self._kv_scale,
-                        jnp.zeros((1, b), jnp.int32), jnp.int32(1),
-                        jnp.zeros(MB, jnp.int32), one, onek, self._rng)
+                        lanes.state, lanes.warm_request(b))
                 self._store_kv_state(kv, scl)
             if self.prefix_cache is not None:
                 # the COW copy program (src=dst=null block: harmless)
@@ -876,13 +886,11 @@ class GenerationEngine:
                 self._store_kv_state(kv, scl)
                 self._goodput_warm.add("host_restore")
             S = self.max_slots
-            kv, scl, *_ = self._decode_jit(
+            kv, scl, _, _, lanes.state, *_ = self._decode_jit(
                 self.params, self.cache.kv, self._kv_scale,
-                jnp.zeros(S, jnp.int32),
-                jnp.zeros((S, MB), jnp.int32), jnp.zeros(S, jnp.int32),
-                jnp.zeros(S, bool), jnp.zeros(S, jnp.float32),
-                jnp.zeros(S, jnp.int32), self._rng)
+                lanes.state, lanes.idle_patch())
             self._store_kv_state(kv, scl)
+            lanes.restore(key)
             if self.speculation is not None:
                 # every verify k-bucket compiles here too (inactive
                 # grid: all writes land in the null block)
@@ -1011,10 +1019,6 @@ class GenerationEngine:
     # the loop
     # ------------------------------------------------------------------
 
-    def _next_rng(self):
-        self._rng, sub = jax.random.split(self._rng)
-        return sub
-
     def _finish(self, seq: Sequence, reason: str) -> None:
         if (self.prefix_cache is not None and seq.slot is not None
                 and reason in ("length", "eos")):
@@ -1070,29 +1074,26 @@ class GenerationEngine:
 
     def _prefill_seq(self, seq: Sequence) -> None:
         rec = self._clock_prefill.begin(force_fence=True)
-        with tracing.phase("generation.prefill"):
+        lanes, slot = self._lanes, seq.slot
+        with tracing.phase("generation.prefill"), lanes.guard():
             with rec.phase("generation.stage", "host_input"):
                 ctx = seq.prompt + seq.generated
                 L = len(ctx)
                 bucket = self.scheduler.bucket_for(L)
-                MB = self.scheduler.max_blocks_per_seq
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, :L] = ctx
-                table = np.zeros(MB, np.int32)
-                table[:len(seq.block_table)] = seq.block_table
+                # one upload: the prompt and the lane's row, which the
+                # program leaves in the resident state itself
+                request, row = lanes.prefill_request(seq, ctx, bucket)
             t0 = now()
             rec.cold = ("prefill", bucket) not in self._goodput_warm
             with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _, *moe = self._prefill_jit(
+                kv, scl, nxt, _, lanes.state, *moe = self._prefill_jit(
                     self.params, self.cache.kv, self._kv_scale,
-                    jnp.asarray(tokens), jnp.int32(L),
-                    jnp.asarray(table),
-                    jnp.full(1, seq.temperature, jnp.float32),
-                    jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
+                    lanes.state, request)
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
                 moe = jax.device_get(moe)
+                lanes.landed(slot, row, nxt)
             with rec.phase("generation.account"):
                 self._account_moe(moe, "prefill")
                 self._goodput_warm.add(("prefill", bucket))
@@ -1164,13 +1165,14 @@ class GenerationEngine:
                 table[:len(seq.block_table)] = seq.block_table
             t0 = now()
             rec.cold = ("chunk", bucket) not in self._goodput_warm
-            with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _, *moe = self._chunk_jit(
+            with rec.phase("generation.dispatch"), self._lanes.guard():
+                state = self._lanes.state
+                kv, scl, nxt, _, state["rng"], *moe = self._chunk_jit(
                     self.params, self.cache.kv, self._kv_scale,
                     jnp.asarray(tokens), jnp.int32(start),
                     jnp.int32(real), jnp.asarray(table),
                     jnp.full(1, seq.temperature, jnp.float32),
-                    jnp.full(1, seq.top_k, jnp.int32), self._next_rng())
+                    jnp.full(1, seq.top_k, jnp.int32), state["rng"])
                 self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
@@ -1202,6 +1204,7 @@ class GenerationEngine:
                         seq.block_table = self.prefix_cache.commit(
                             seq.prompt, seq.block_table)
                     seq.status = "running"
+                    self.scheduler.touched.add(seq.slot)
                     self._emit(seq, nxt)
             self._end_step(rec)
 
@@ -1449,6 +1452,9 @@ class GenerationEngine:
                                           dur * (len(draft) - m) / k1)
                     done.add(seq)
             with rec.phase("generation.emit"):
+                # advanced outside the decode step: their rows go up
+                # again (sync() keeps them out of this round's decode)
+                self.scheduler.touched.update(s.slot for s in done)
                 for seq in riders:
                     self._emit(seq, int(greedy[seq.slot, 0]))
                 for (seq, _st, _draft), m in zip(drafted, accepted):
@@ -1478,40 +1484,27 @@ class GenerationEngine:
         with tracing.phase(
                 f"generation.decode[l={len(lanes)},"
                 f"w={min(len(self.scheduler.waiting), 99)}]"):
-            with rec.phase("generation.stage", "host_input"):
-                S = self.max_slots
-                MB = self.scheduler.max_blocks_per_seq
-                tokens = np.zeros(S, np.int32)
-                tables = np.zeros((S, MB), np.int32)
-                ctx_len = np.zeros(S, np.int32)
-                active = np.zeros(S, bool)
-                temp = np.zeros(S, np.float32)
-                top_k = np.zeros(S, np.int32)
-                for i, seq in lanes.items():
-                    tokens[i] = seq.generated[-1] if seq.generated \
-                        else seq.prompt[-1]
-                    tables[i, :len(seq.block_table)] = seq.block_table
-                    ctx_len[i] = seq.context_len - 1  # pending position
-                    active[i] = True
-                    temp[i] = seq.temperature
-                    top_k[i] = seq.top_k
-            # fault-injection site: "poison_request" raises
-            # PoisonedRequestError BEFORE dispatch (no KV/state change
-            # happened, so surviving lanes replay this round
-            # untouched); "stall" wedges the loop for the watchdog
-            fault_point("generation.decode",
-                        request_ids=[s.request_id
-                                     for s in lanes.values()])
-            t0 = now()
-            rec.cold = "decode" not in self._goodput_warm
-            with rec.phase("generation.dispatch"):
-                kv, scl, nxt, _, *moe = self._decode_jit(
-                    self.params, self.cache.kv, self._kv_scale,
-                    jnp.asarray(tokens), jnp.asarray(tables),
-                    jnp.asarray(ctx_len), jnp.asarray(active),
-                    jnp.asarray(temp), jnp.asarray(top_k),
-                    self._next_rng())
-                self._store_kv_state(kv, scl)
+            with self._lanes.guard():
+                with rec.phase("generation.stage", "host_input"):
+                    # the rows the scheduler changed since the last
+                    # round, as one upload — or none (lane_state.py)
+                    patch = self._lanes.sync(skip)
+                # fault-injection site: "poison_request" raises
+                # PoisonedRequestError BEFORE dispatch (no KV change
+                # happened; the guard has every lane's row resent, so
+                # surviving lanes replay this round untouched);
+                # "stall" wedges the loop for the watchdog
+                fault_point("generation.decode",
+                            request_ids=[s.request_id
+                                         for s in lanes.values()])
+                t0 = now()
+                rec.cold = "decode" not in self._goodput_warm
+                with rec.phase("generation.dispatch"):
+                    kv, scl, nxt, _, self._lanes.state, *moe = \
+                        self._decode_jit(
+                            self.params, self.cache.kv, self._kv_scale,
+                            self._lanes.state, patch)
+                    self._store_kv_state(kv, scl)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = np.asarray(nxt)     # token fetch = device fence
                 moe = jax.device_get(moe)
@@ -1523,8 +1516,9 @@ class GenerationEngine:
                 self._goodput_warm.add("decode")
                 dur = now() - t0
                 self._h_decode.record(dur, len(lanes))
-                ctx_mean = (float(np.sum(ctx_len[active])) / len(lanes)
+                ctx_mean = (self._lanes.ctx_sum() / len(lanes)
                             if lanes else 0.0)
+                self._lanes.advance(nxt)
                 profiling.record_work(
                     "decode", dur, tokens=len(lanes),
                     flops=(self._flops.decode(len(lanes), ctx_mean)
@@ -1703,6 +1697,7 @@ class GenerationEngine:
                 flight_recorder.dump("generation_step_error", exc=e,
                                      extra={"request_ids": affected})
                 with self._lock:
+                    self._lanes.invalidate()
                     for seq in list(self.scheduler.slotted()):
                         self._finish(seq, f"error: {e}")
 

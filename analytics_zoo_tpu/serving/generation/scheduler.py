@@ -33,6 +33,12 @@ writes land strictly past committed prompt blocks); it is the safety
 net that keeps a future fork/beam path from corrupting shared state,
 and it is unit-tested via explicitly shared blocks.
 
+What the device is told: the decode step reads each lane's row from a
+state resident on the device (lane_state.py).  Every method here that
+changes who holds a lane or what its block table says adds the lane to
+`touched`; the engine uploads exactly those rows before the next
+decode step and clears the set.
+
 Invariant the engine relies on: a RUNNING sequence has KV written for
 exactly `context_len - 1` tokens — the newest sampled token is pending,
 and the next decode step feeds it, writes its KV, and samples its
@@ -142,6 +148,9 @@ class SlotScheduler:
         self.waiting: Deque[Sequence] = deque()
         self.n_preemptions = 0
         self._admit_counter = 0
+        #: lanes whose holder or block table changed since the engine
+        #: last told the device (lane_state.py reads and clears it)
+        self.touched: set = set()
 
     # ------------------------------------------------------------------
 
@@ -230,6 +239,7 @@ class SlotScheduler:
                           context_len=victim.context_len)
         self.cache.allocator.free(victim.block_table)
         victim.block_table = []
+        self.touched.add(victim.slot)
         self.slots[victim.slot] = None
         victim.slot = None
         victim.status = "waiting"
@@ -256,6 +266,7 @@ class SlotScheduler:
                 got = self._alloc_with_evict(1)
                 if got is not None:
                     seq.block_table.extend(got)
+                    self.touched.add(seq.slot)
                     continue
                 victim = self._preempt_newest()
                 if victim is None or victim is seq:
@@ -282,6 +293,8 @@ class SlotScheduler:
                 return False
             added.extend(got)
             seq.block_table.extend(got)
+        if added:
+            self.touched.add(seq.slot)
         return True
 
     def rollback_speculation(self, seq: Sequence) -> None:
@@ -300,6 +313,7 @@ class SlotScheduler:
             extra = seq.block_table[keep:]
             del seq.block_table[keep:]
             self.cache.allocator.free(extra)
+            self.touched.add(seq.slot)
 
     def resolve_write_conflicts(self) \
             -> List[Tuple[Sequence, int, int, int]]:
@@ -335,6 +349,7 @@ class SlotScheduler:
                     continue
             dst = got[0]
             seq.block_table[idx] = dst
+            self.touched.add(seq.slot)
             self.cache.allocator.free([src])
             flight_recorder.record("sched_cow", uid=seq.uid,
                                    slot=seq.slot, src=src, dst=dst)
@@ -410,6 +425,7 @@ class SlotScheduler:
             seq._admit_order = self._admit_counter
             self._admit_counter += 1
             self.slots[seq.slot] = seq
+            self.touched.add(seq.slot)
             if not self.chunk_mode:
                 budget -= bucket
             admitted.append(seq)
@@ -439,6 +455,7 @@ class SlotScheduler:
             self.cache.allocator.free(seq.block_table)
             seq.block_table = []
         if seq.slot is not None:
+            self.touched.add(seq.slot)
             self.slots[seq.slot] = None
             seq.slot = None
         seq.status = "finished"

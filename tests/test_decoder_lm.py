@@ -35,6 +35,7 @@ from analytics_zoo_tpu.serving.generation import (  # noqa: E402
     DecoderLM,
     ExpertLayer,
     GenerationEngine,
+    lane_state,
 )
 
 TOL = 5e-5
@@ -121,15 +122,19 @@ def capture(eng):
     prefill, decode = eng._prefill_jit, eng._decode_jit
 
     def on_prefill(*args):
+        _, row, tokens = lane_state.split_request(
+            np.asarray(args[4]), eng._lanes.width)
         out = prefill(*args)
-        length = int(args[4])
-        prompt = tuple(int(t) for t in np.asarray(args[3])[0, :length])
+        length = int(row[lane_state.CTX])
+        prompt = tuple(int(t) for t in tokens[0, :length])
         got[(prompt, length - 1)] = np.asarray(out[3])
         return out
 
     def on_decode(*args):
+        ctx = np.asarray(lane_state.patched(args[3]["rows"], args[4])
+                         )[:, lane_state.CTX]
         out = decode(*args)
-        ctx, last = np.asarray(args[5]), np.asarray(out[3])
+        last = np.asarray(out[3])
         for seq in eng.scheduler.running():
             got[(tuple(seq.prompt), int(ctx[seq.slot]))] = last[seq.slot]
         return out

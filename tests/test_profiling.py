@@ -158,6 +158,108 @@ def test_weak_scalar_value_change_is_not_a_compile():
 
 
 # ----------------------------------------------------------------------
+# a signature is derived for a call that compiled, and for no other
+# ----------------------------------------------------------------------
+
+@pytest.fixture
+def derivations(monkeypatch):
+    """How often the wrapper walked its arguments' leaves."""
+    calls = []
+    real = profiling.abstract_signature
+
+    def counting(args, argnames=None):
+        calls.append(len(args))
+        return real(args, argnames)
+
+    monkeypatch.setattr(profiling, "abstract_signature", counting)
+    return calls
+
+
+def tree(n, width=4):
+    return {f"w{i}": jnp.zeros((width,), jnp.float32) for i in range(n)}
+
+
+@pytest.mark.parametrize("warm_calls", [1, 7])
+def test_signature_derived_once_a_compiled_variant(derivations,
+                                                   warm_calls):
+    """A warm call of a jitted family costs two reads of the jit cache's
+    size and two counters, however many leaves its arguments have; each
+    variant that compiles is described once."""
+    fn = profiling.instrument(
+        "decode", jax.jit(lambda params, x: x + params["w0"].sum()),
+        argnames=("params", "x"))
+    params = tree(150)
+    for _ in range(1 + warm_calls):
+        fn(params, jnp.zeros((4,), jnp.float32))
+    assert len(derivations) == 1
+    fn(params, jnp.zeros((5,), jnp.float32))     # a second variant
+    for _ in range(warm_calls):
+        fn(params, jnp.zeros((5,), jnp.float32))
+        fn(params, jnp.zeros((4,), jnp.float32))  # and the first, warm
+    assert len(derivations) == 2
+    events = profiling.compile_events()
+    assert [e["n"] for e in events] == [1, 2]
+    assert events[1]["diff"] == [{"path": "x", "old": "float32[4]",
+                                  "new": "float32[5]"}]
+    snap = profiling.ledger_snapshot()["families"]["decode"]
+    assert snap["calls"] == 2 + 3 * warm_calls
+    assert snap["compile_count"] == 2 and snap["signatures"] == 2
+    assert fn._cache_size() == 2
+
+
+@pytest.mark.parametrize("counter", [
+    "dispatch_calls_total", "dispatch_decode_calls_total",
+    "compile_events_total", "compile_seconds_total"])
+def test_ledger_counters_read_as_before(derivations, counter):
+    """Every call counts; only the compiling ones add compile events
+    and compile seconds (what the benchmark's `window_compile_s.*`
+    reads: 0 over a window of warm calls)."""
+    reg = get_registry()
+    fn = profiling.instrument("decode", jax.jit(lambda x: x * 2),
+                              argnames=("x",))
+    fn(jnp.zeros((3,), jnp.int32))
+    before = reg.counter(counter).value
+    for _ in range(5):
+        fn(jnp.zeros((3,), jnp.int32))
+    grew = reg.counter(counter).value - before
+    assert grew == (5 if "calls" in counter else 0)
+    fn(jnp.zeros((6,), jnp.int32))
+    assert reg.counter(counter).value - before - grew > 0
+
+
+@pytest.mark.parametrize("second", ["same_shapes", "other_shapes"])
+def test_second_wrapper_of_a_family(derivations, second):
+    """Two engines in one process wrap one family each: the second's
+    first call compiles ITS jit, so it is described; the family logs a
+    compile event only for a signature it has not seen."""
+    one = profiling.instrument("decode", jax.jit(lambda x: x + 1),
+                               argnames=("x",))
+    two = profiling.instrument("decode", jax.jit(lambda x: x + 2),
+                               argnames=("x",))
+    one(jnp.zeros((4,), jnp.int32))
+    two(jnp.zeros((4 if second == "same_shapes" else 8,), jnp.int32))
+    two(jnp.zeros((4 if second == "same_shapes" else 8,), jnp.int32))
+    assert len(derivations) == 2
+    assert len(profiling.compile_events()) == (
+        1 if second == "same_shapes" else 2)
+    snap = profiling.ledger_snapshot()["families"]["decode"]
+    assert snap["calls"] == 3
+    assert snap["bytes_total"] == (48 if second == "same_shapes" else 80)
+    assert one._cache_size() == 1 and two._cache_size() == 1
+
+
+def test_a_callable_without_a_jit_cache_is_described_every_call(
+        derivations):
+    """Nothing cheaper to ask than the arguments themselves."""
+    fn = profiling.instrument("decode", lambda x: x, argnames=("x",))
+    assert not hasattr(fn, "_cache_size")
+    for n in (4, 4, 5):
+        fn(np.zeros((n,), np.int32))
+    assert len(derivations) == 3
+    assert len(profiling.compile_events()) == 2
+
+
+# ----------------------------------------------------------------------
 # MFU accounting
 # ----------------------------------------------------------------------
 

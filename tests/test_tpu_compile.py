@@ -293,18 +293,14 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
 
     def like(x):
         return place(x.shape, x.dtype)
-    s, mb = 32, eng.scheduler.max_blocks_per_seq
+    s, width = 32, eng._lanes.width
     state = (jax.tree_util.tree_map(like, eng.params),
-             like(eng.cache.kv), like(eng._kv_scale))
+             like(eng.cache.kv), like(eng._kv_scale),
+             jax.tree_util.tree_map(like, eng._lanes.state))
     programs = {
-        "decode": (eng._decode_jit.fn, (
-            place((s,), jnp.int32), place((s, mb), jnp.int32),
-            place((s,), jnp.int32), place((s,), jnp.bool_),
-            place((s,), jnp.float32), place((s,), jnp.int32))),
-        "prefill": (eng._prefill_jit.fn, (
-            place((1, 1024), jnp.int32), place((), jnp.int32),
-            place((mb,), jnp.int32), place((1,), jnp.float32),
-            place((1,), jnp.int32))),
+        "decode": (eng._decode_jit.fn, place((s, 1 + width), jnp.int32)),
+        "prefill": (eng._prefill_jit.fn,
+                    place((1 + width + 1024,), jnp.int32)),
     }
     pool = eng.cache.kv
     pool_bytes = pool.size * pool.dtype.itemsize
@@ -316,8 +312,8 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
     # the prefill keeps f32 logits of all 1024 positions to use one row
     # (PERF.md section 5): 206 MB that are not the pool's
     allowance = {"decode": 0, "prefill": 1024 * model.vocab * 4}
-    for name, (fn, args) in programs.items():
-        compiled = fn.lower(*state, *args, like(eng._rng)).compile()
+    for name, (fn, upload) in programs.items():
+        compiled = fn.lower(*state, upload).compile()
         text = compiled.as_text()
         assert "tpu_custom_call" in text, name
         copies = [ln.strip()[:200] for ln in text.splitlines()

@@ -24,7 +24,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from analytics_zoo_tpu.observability import goodput, request_log, tracing
-from analytics_zoo_tpu.serving.generation import CausalLM, GenerationEngine
+from analytics_zoo_tpu.serving.generation import (
+    CausalLM, GenerationEngine, lane_state)
 from benchmarks.harness import span_metrics
 from benchmarks.harness.trace_reduce import Trace
 from benchmarks.harness.tracing import Tracer
@@ -103,16 +104,16 @@ def serve(engine, temperature=0.0):
     """The three requests queued, then the loop started: one admission,
     three prefills, and decode rounds of 3, 3, 3, 2, 2, 1, 1 lanes.
     Returns the tokens and the lanes of every decode dispatch, counted
-    from the `active` argument the jitted program was given."""
+    from the lane rows the jitted program was given, its patch applied."""
     lanes, real = [], engine._decode_jit
 
-    def counted(params, kv, scale, tokens, tables, ctx_len, active, *rest):
-        lanes.append(int(np.asarray(active).sum()))
-        return real(params, kv, scale, tokens, tables, ctx_len, active,
-                    *rest)
+    def counted(params, kv, scale, state, patch):
+        rows = lane_state.patched(state["rows"], patch)
+        lanes.append(int(np.asarray(rows[:, lane_state.ACTIVE]).sum()))
+        return real(params, kv, scale, state, patch)
 
     engine._decode_jit = counted
-    engine._rng = jax.random.PRNGKey(7)
+    engine._lanes.restore(np.asarray(jax.random.PRNGKey(7)))
     try:
         streams = [engine.submit(p, max_new_tokens=n,
                                  temperature=temperature)
@@ -177,6 +178,30 @@ def test_engine_spans_land_in_a_profiler_trace(engine):
     assert 0 < span_metrics.prefill_time_share(ctx) < 100
     # no device plane on the CPU: no share of its idle time
     assert span_metrics.serve_idle(ctx) is None
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.9])
+def test_a_decode_round_launches_one_program(engine, temperature):
+    """Greedy or sampled, with a patch of lane rows or without: the
+    only program a decode round runs is `decode` — the key is split
+    inside it (no `_threefry_split`, no `_unstack`), and the lane rows
+    are advanced by it.  The name is what the benchmark's readers find
+    the step by."""
+    streams = [engine.submit(p, max_new_tokens=12, temperature=temperature)
+               for p, _ in PROMPTS]
+    engine.step()                   # the prefills and a first decode
+    with session() as tracer:
+        for _ in range(6):          # lanes cross block boundaries in it
+            engine.step()
+        trace = reduced(tracer)
+    engine.run_until_idle()
+    assert [len(s.tokens()) for s in streams] == [12, 12, 12]
+    rounds = [n for n, _, _ in azt(trace, "generation.decode")]
+    assert rounds == ["azt:generation.decode[l=3,w=0]"] * 6
+    programs = {n for n, _, _ in
+                span_metrics.host_events(trace, "PjitFunction(")}
+    assert programs == {"PjitFunction(decode)"}
+    assert engine.decode_compile_count == 1
 
 
 def test_sampled_tokens_do_not_depend_on_a_session(engine):
