@@ -57,7 +57,6 @@ class OrcaContextMeta(type):
     _observability_dir = None
     _kernel_tuning_mode = "off"
     _kernel_tuning_cache_dir = None
-    _kv_cache_quantization = None
     _goodput_sample_every = 16
     _watchdog_deadline_s = None
     _nonfinite_watchdog = False
@@ -70,15 +69,7 @@ class OrcaContextMeta(type):
     _fault_plan = None
     _background_checkpointing = False
     _slo_shed_attainment = None
-    _prefix_caching = False
-    _chunked_prefill = False
-    _speculative_decoding = False
-    _speculative_k = 4
-    _kv_host_tier_bytes = 0
-    _router_phase_aware = False
     _host_input_prefetch = 2
-    _decode_tensor_parallel = 0
-    _serving_replicas = 0
     _telemetry_spool_interval_s = 1.0
     _telemetry_spool_max_bytes = 1024 * 1024
     _tenant_quotas = None
@@ -358,8 +349,7 @@ class OrcaContextMeta(type):
         `block_until_ready` fence so its wall time decomposes exactly
         into compile / host-input / device-compute / blocked-collective
         / overhead buckets.  Default 16 (≈6% of steps pay one fence);
-        1 fences every step (full accounting — what the bench's
-        buckets-sum-to-wall assertion runs)."""
+        1 fences every step (full accounting)."""
         return cls._goodput_sample_every
 
     @goodput_sample_every.setter
@@ -477,8 +467,7 @@ class OrcaContextMeta(type):
         to its e2e within this fraction (an absolute 0.1 ms floor
         covers sub-millisecond e2e).  Violations flip the ledger's
         `additive_ok` flag and tick
-        `blame_additivity_violations_total`; the bench overload gate
-        hard-fails on any violation at the default 5%."""
+        `blame_additivity_violations_total` (default 5%)."""
         return cls._blame_tolerance
 
     @blame_tolerance.setter
@@ -591,186 +580,13 @@ class OrcaContextMeta(type):
         cls._slo_shed_attainment = value
 
     @property
-    def prefix_caching(cls):
-        """Radix-tree prompt-prefix reuse in the generation engine
-        (serving/generation/prefix_cache.py; docs/generation.md).
-        False (default) keeps the engine bitwise-identical to the
-        pre-cache behavior: every request prefills its full prompt and
-        owns its KV blocks exclusively.  True: on admission the
-        scheduler looks up the longest cached whole-block prompt
-        prefix, shares those blocks (copy-on-write guarded, refcounted
-        in `BlockAllocator`), prefills only the tail, and commits full
-        prompt blocks back to the radix tree; unreferenced cached
-        blocks are LRU-evicted under pool pressure before any running
-        lane is preempted.  Read at engine construction (pass
-        `GenerationEngine(prefix_caching=...)` to override per
-        engine)."""
-        return cls._prefix_caching
-
-    @prefix_caching.setter
-    def prefix_caching(cls, value):
-        cls._prefix_caching = bool(value)
-
-    @property
-    def chunked_prefill(cls):
-        """Chunked prefill in the generation engine (default False).
-        When True, a long prompt's prefill is split across scheduling
-        rounds in `prefill_token_budget`-bounded chunks, with a decode
-        step for every running lane BETWEEN chunks — a 32k-token
-        prompt no longer stalls every active lane for its whole
-        prefill (the TTFT/TPOT histograms and SLO attainment gauge are
-        the regression gate).  Read at engine construction
-        (`GenerationEngine(chunked_prefill=...)` overrides).  The
-        decode program is untouched either way: the one-static-shape
-        zero-recompile contract holds with chunking armed (asserted in
-        tests and bench)."""
-        return cls._chunked_prefill
-
-    @chunked_prefill.setter
-    def chunked_prefill(cls, value):
-        cls._chunked_prefill = bool(value)
-
-    @property
-    def speculative_decoding(cls):
-        """Draft-free speculative decoding in the generation engine
-        (serving/generation/speculation.py; docs/generation.md).
-        False (default) keeps the decode loop bitwise untouched: one
-        token per jitted step per lane.  True: greedy lanes propose up
-        to `speculative_k` continuation tokens per round via n-gram
-        prompt lookup over their own token history, ONE verify step
-        scores them all (the chunk-step ctx-read shape), and the
-        longest prefix matching the model's greedy argmax is accepted
-        — plus the bonus token the verify logits yield for free.
-        Accepted tokens equal what single-step greedy would emit, so
-        output streams are identical either way; rejected drafts
-        rewind through the refcounted block allocator at free-list
-        cost.  Read at engine construction
-        (`GenerationEngine(speculative_decoding=...)` overrides)."""
-        return cls._speculative_decoding
-
-    @speculative_decoding.setter
-    def speculative_decoding(cls, value):
-        cls._speculative_decoding = bool(value)
-
-    @property
-    def speculative_k(cls):
-        """Max drafted tokens per lane per speculative-decoding round
-        (default 4; used only while `speculative_decoding` is on).
-        Verify programs compile per pow2 draft-length bucket, so k
-        adds O(log k) compiled families next to the single decode
-        family — the zero-recompile contract holds with speculation
-        armed.  Read at engine construction
-        (`GenerationEngine(speculative_k=...)` overrides)."""
-        return cls._speculative_k
-
-    @speculative_k.setter
-    def speculative_k(cls, value):
-        value = int(value)
-        if value < 1:
-            raise ValueError(
-                f"speculative_k must be >= 1, got {value}")
-        cls._speculative_k = value
-
-    @property
-    def kv_host_tier_bytes(cls):
-        """Host-RAM KV offload tier capacity in bytes for the
-        generation engine's prefix cache
-        (serving/generation/host_tier.py; docs/generation.md "Host
-        tier").  0 (default) = no tier: evicted prefix blocks are
-        dropped, bitwise the pre-tier behavior.  N > 0: radix-tree
-        evictions of refcount-1 blocks spill the block's KV rows (and
-        int8 scales) into a bounded-bytes host LRU, and a later radix
-        miss extending into a host-resident prefix restores the block
-        via a staged async `device_put` instead of recomputing its
-        prefill.  The tier is ADVISORY — a full/corrupt/lost entry
-        only costs a recompute, never correctness.  Effective only
-        with `prefix_caching` on; read at engine construction
-        (`GenerationEngine(kv_host_tier=...)` overrides, accepting a
-        byte count or a shared `HostKVTier` instance)."""
-        return cls._kv_host_tier_bytes
-
-    @kv_host_tier_bytes.setter
-    def kv_host_tier_bytes(cls, value):
-        value = int(value)
-        if value < 0:
-            raise ValueError(
-                "kv_host_tier_bytes must be >= 0 (0 = off)")
-        cls._kv_host_tier_bytes = value
-
-    @property
-    def router_phase_aware(cls):
-        """Prefill/decode phase-aware routing in the `ReplicaRouter`
-        (serving/distributed/router.py; docs/distributed-serving.md
-        "Phase-aware routing").  False (default) keeps pure
-        least-loaded admission.  True (with >= 2 replicas): the first
-        replica is tagged "prefill" and the rest "decode"; each
-        submit is classified by its prefix-match fraction — a
-        prefill-heavy request (long prompt, little cached) prefers the
-        prefill replica, which commits its blocks through the shared
-        host tier (`kv_host_tier_bytes`), and decode-heavy requests
-        prefer decode replicas, which adopt those blocks on lookup —
-        one replica's prefill work becomes every replica's prefix
-        hit.  Scoring stays load-first: a phase mismatch is a
-        penalty, not a hard pin, so a saturated preferred replica
-        never starves traffic.  Read at router construction."""
-        return cls._router_phase_aware
-
-    @router_phase_aware.setter
-    def router_phase_aware(cls, value):
-        cls._router_phase_aware = bool(value)
-
-    @property
-    def decode_tensor_parallel(cls):
-        """Tensor-parallel degree for the generation decode path
-        (serving/distributed/tp.py; docs/distributed-serving.md).
-        0 (default) keeps the legacy single-device engine bitwise
-        untouched.  N > 1 shards the `CausalLM` param tree
-        column-wise and the `PagedKVCache` pool on the head dim over
-        the mesh's ``tp`` axis, which `init_orca_context(mesh_shape=
-        {"tp": N})` must provide.  Block tables and every other host
-        input stay replicated, so the one-static-shape jitted decode
-        contract still holds (`decode_compile_count == 1`) and greedy
-        output is token-identical to the single-device engine.  Read
-        at engine construction
-        (`GenerationEngine(tensor_parallel=...)` overrides)."""
-        return cls._decode_tensor_parallel
-
-    @decode_tensor_parallel.setter
-    def decode_tensor_parallel(cls, value):
-        value = int(value)
-        if value < 0:
-            raise ValueError(
-                "decode_tensor_parallel must be >= 0 (0 = off)")
-        cls._decode_tensor_parallel = value
-
-    @property
-    def serving_replicas(cls):
-        """Generation-engine replica count for the `ReplicaRouter`
-        (serving/distributed/router.py; docs/distributed-serving.md).
-        0 (default) = no router: `ServingServer` talks to one engine,
-        bitwise the pre-router behavior.  N >= 1:
-        `ReplicaRouter.build(model, params)` constructs N engines
-        (each with its own `MetricsRegistry`) and admits via
-        least-loaded scoring off their live queue-depth/KV-occupancy
-        gauges.  Independent of `decode_tensor_parallel` — replicas
-        may themselves be tensor-parallel."""
-        return cls._serving_replicas
-
-    @serving_replicas.setter
-    def serving_replicas(cls, value):
-        value = int(value)
-        if value < 0:
-            raise ValueError("serving_replicas must be >= 0 (0 = off)")
-        cls._serving_replicas = value
-
-    @property
     def host_input_prefetch(cls):
         """Host-input double-buffering depth for the SPMD host-
         streaming train/eval loops (orca/learn/spmd.py).  With depth
         N >= 1 the engine keeps N batches staged ahead and assembles +
         `device_put`s the NEXT batch while the CURRENT step runs on
         the device, so the goodput ``host_input`` bucket shrinks
-        toward zero (bench's prefetch window asserts it).  0 disables
+        toward zero.  0 disables
         prefetching: each batch is assembled synchronously before its
         step (the comparison baseline).  Default 2."""
         return cls._host_input_prefetch
@@ -811,30 +627,6 @@ class OrcaContextMeta(type):
     @kernel_tuning_cache_dir.setter
     def kernel_tuning_cache_dir(cls, value):
         cls._kernel_tuning_cache_dir = None if value is None else str(value)
-
-    @property
-    def kv_cache_quantization(cls):
-        """KV-cache residency policy for the generation engine
-        (serving/generation, docs/generation.md): None (default) keeps
-        the block pool at the engine's `cache_dtype` (f32/bf16/f16);
-        "int8" stores blocks as int8 with per-token-slot symmetric
-        scales — ~1.9x block-pool residency vs f16 at equal pool
-        bytes, dequantized on read inside the paged-attention kernel.
-        Read at engine construction (an existing engine's pool dtype
-        never changes under it)."""
-        return cls._kv_cache_quantization
-
-    @kv_cache_quantization.setter
-    def kv_cache_quantization(cls, value):
-        if value is not None:
-            value = str(value).lower()
-            if value in ("none", "off"):
-                value = None
-            elif value != "int8":
-                raise ValueError(
-                    f"kv_cache_quantization must be None or 'int8', "
-                    f"got {value!r}")
-        cls._kv_cache_quantization = value
 
     @property
     def mesh(cls):

@@ -4,8 +4,8 @@ A bounded in-memory ring of recent happenings (completed spans, step
 stats, scheduler lane decisions, structured events) that costs one
 deque append in the steady state and, when something dies, is written
 out as a post-mortem bundle instead of evaporating with the process —
-the PyTorch-NCCL-flight-recorder idea applied to this stack.  The red
-``MULTICHIP_r05.json`` rendezvous abort and the un-localized pipeline
+the PyTorch-NCCL-flight-recorder idea applied to this stack.  A red
+multi-chip rendezvous abort and the un-localized pipeline
 NaN flake are exactly the class of failure that previously left a bare
 ``rc=1``.
 

@@ -41,9 +41,8 @@ model dims); the SPMD estimator uses the standard ``6·P`` train /
 with the ledger's measured wall into ``mfu_ratio`` / ``mfu_decode`` /
 ``mfu_prefill`` gauges and the ``model_flops_total`` counter — peak is
 ``OrcaContext.hardware_peak_flops`` (default `DEFAULT_PEAK_FLOPS`).
-Bench windows report the numbers and `scripts/bench_diff.py` tracks
-``mfu_decode`` (higher-is-better) and ``compile_seconds_total``
-(lower).
+The benchmark (`benchmarks/run.py`) reads ``compile_seconds_total``
+and counts its own FLOPs (`benchmarks/harness/flops.py`).
 """
 
 from __future__ import annotations

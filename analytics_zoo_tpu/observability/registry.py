@@ -370,7 +370,7 @@ def merged_prometheus_text(*registries: MetricsRegistry) -> str:
 
 def parse_prometheus_text(text: str) -> Dict[str, Dict[str, Any]]:
     """Minimal parser for the exposition this module writes (what
-    bench.py uses to consume a live server's /metrics).  Returns
+    a client uses to consume a live server's /metrics).  Returns
     {name: {"type": str, "value": float, "sum": float, "count": float,
     "max": float, "quantiles": {q: v}}} with only the fields present.
     """

@@ -81,9 +81,9 @@ def flash_fwd_candidates(t: int, d: int):
     """The autotuner's forward candidate grid: (block_q, block_k)
     pairs that tile `t` and fit the VMEM budget at head dim `d`.
     The 128-tiles are the small-seq/small-head end of the grid
-    (BENCH_r05: flash_eff_t2048_d64 = 0.132 while dense sat at 0.534
-    — FlashAttention-2 reports exactly this block-schedule sensitivity
-    at d=64, where 128x128 MXU-native tiles cut the per-block online-
+    (one v5e chip, round 5: flash_eff_t2048_d64 = 0.132 while dense
+    sat at 0.534 — FlashAttention-2 reports exactly this
+    block-schedule sensitivity at d=64, where 128x128 MXU-native tiles cut the per-block online-
     softmax bookkeeping relative to useful work)."""
     out = []
     for bq in (128, 256, 512, 1024):
@@ -120,8 +120,7 @@ def flash_bwd_candidates(t: int, d: int):
 def _bench_flash_fwd(b, t, h, d, dtype, cfg, iters: int = 4):
     """Autotuner benchmark: forward-only wall time per call, the
     iterations chained output->input inside ONE compiled scan so
-    per-dispatch latency cannot masquerade as kernel time (the bench.py
-    technique).  All four block args are passed explicitly so the
+    per-dispatch latency cannot masquerade as kernel time.  All four block args are passed explicitly so the
     benchmark can never recurse into the tuner."""
     from analytics_zoo_tpu.observability import now
     k0 = jax.random.PRNGKey(0)
@@ -217,7 +216,7 @@ def tuned_flash_blocks(b, t, h, d, dtype, allow_search=None):
 
 
 def tune_flash_blocks(b, t, h, d, dtype=jnp.bfloat16, force=False):
-    """Search NOW (bench.py's kernel stage): benchmarks the candidate
+    """Search NOW (`ops.tuning.tune`): benchmarks the candidate
     grids on the attached accelerator, persists the winners to
     `OrcaContext.kernel_tuning_cache_dir`, and returns the merged
     config (same layout as `tuned_flash_blocks`)."""

@@ -546,7 +546,7 @@ def tuned_paged_block_gather(bs, lanes, h, d, dtype,
 
 def tune_paged_decode(bs, lanes, h, d, dtype=jnp.float32,
                       mb: Optional[int] = None, force=False) -> int:
-    """Search NOW (bench.py's kernel stage on a real TPU): benchmark
+    """Search NOW (`ops.tuning.tune`, on a real TPU): benchmark
     the candidate gather widths, persist the winner to
     `OrcaContext.kernel_tuning_cache_dir`, return it."""
     from analytics_zoo_tpu.ops import tuning

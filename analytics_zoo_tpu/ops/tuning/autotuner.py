@@ -263,7 +263,7 @@ def _search(kernel: str, key: str,
     RESUMABLE: each candidate's time persists to the cache file's
     `partials` section the moment it is measured, and candidates with
     a recorded time are not re-benchmarked.  A search killed mid-grid
-    by a stage deadline (bench.py's kernelbench subprocess) therefore
+    by its caller's deadline therefore
     makes monotonic progress across runs: every run times at least the
     candidates its slot affords, and the run that measures the last
     one writes the winner."""
@@ -362,7 +362,7 @@ def tune(kernel: str, shape: Dict[str, int], dtype,
          candidates: Sequence[Dict[str, int]],
          bench: Callable[[Dict[str, int]], float],
          force: bool = False) -> Dict[str, int]:
-    """Explicitly search now (what bench.py's kernel stage calls) and
+    """Explicitly search now (what the kernels' `tune_*` helpers call) and
     memoize + persist the winner.  `force=True` re-searches even when
     an answer is already memoized/cached — the ONE sanctioned way a
     key's config can change (a re-tune on new hardware); processes

@@ -20,7 +20,7 @@ When no replica admits, `submit` raises `QueueFull` carrying the
 smallest per-replica `retry_after_s` — the HTTP layer turns it into a
 503 with Retry-After, same as the single-engine shed path.
 
-Phase-aware routing (`OrcaContext.router_phase_aware`, default off —
+Phase-aware routing (`ReplicaRouter(phase_aware=True)`, default off —
 docs/distributed-serving.md): with >= 2 replicas, replica-0 is tagged
 ``prefill`` and the rest ``decode``; every submit is classified by
 its prefix-match fraction against the replicas' radix trees and the
@@ -64,6 +64,7 @@ from analytics_zoo_tpu.serving.generation.engine import (
     GenerationStream,
     QueueFull,
 )
+from analytics_zoo_tpu.serving.generation.host_tier import HostKVTier
 
 REPLICA_STATES = ("active", "draining", "dead")
 
@@ -183,7 +184,7 @@ class ReplicaRouter:
 
     def __init__(self, engines: List[GenerationEngine], *,
                  registry=None, occupancy_weight: float = 4.0,
-                 max_requeues: int = 1, phase_aware="auto"):
+                 max_requeues: int = 1, phase_aware: bool = False):
         if not engines:
             raise ValueError("ReplicaRouter needs at least one engine")
         regs = {id(e.registry) for e in engines}
@@ -232,27 +233,22 @@ class ReplicaRouter:
                                  for r in self.replicas),
                   help="waiting requests summed over all replicas")
         for r in self.replicas:
-            # one counter per replica: the served-skew bench gate and
-            # the /stats rows read these (family documented as
-            # replica_<name>_served_total)
+            # one counter per replica: the /stats rows read these
+            # (family documented as replica_<name>_served_total)
             reg.counter("replica_" + r.name.replace("-", "_")
                         + "_served_total",
                         help=f"requests dispatched to {r.name}")
-        #: prefill/decode disaggregation — "auto" reads
-        #: OrcaContext.router_phase_aware; arms only with >= 2
+        #: prefill/decode disaggregation; arms only with >= 2
         #: replicas (one replica has no phases to split)
-        if phase_aware == "auto":
-            from analytics_zoo_tpu.common.context import OrcaContext
-            phase_aware = OrcaContext.router_phase_aware
         self.phase_aware = bool(phase_aware) and len(self.replicas) >= 2
         self._c_phase_prefill = reg.counter(
             "router_phase_prefill_total",
             help="submits classified prefill-heavy (phase-aware "
-                 "routing; 0 while router_phase_aware is off)")
+                 "routing; 0 while phase_aware is off)")
         self._c_phase_decode = reg.counter(
             "router_phase_decode_total",
             help="submits classified decode-heavy (phase-aware "
-                 "routing; 0 while router_phase_aware is off)")
+                 "routing; 0 while phase_aware is off)")
         if self.phase_aware:
             self.replicas[0].phase = "prefill"
             for r in self.replicas[1:]:
@@ -267,30 +263,21 @@ class ReplicaRouter:
     # -- construction --------------------------------------------------
 
     @classmethod
-    def build(cls, model, params, *, n_replicas="auto", registry=None,
+    def build(cls, model, params, *, n_replicas: int, registry=None,
               occupancy_weight: float = 4.0, max_requeues: int = 1,
-              warmup: bool = True, **engine_kwargs) -> "ReplicaRouter":
-        """Construct N engines — each with a fresh `MetricsRegistry` —
-        over shared model/params.  ``n_replicas="auto"`` reads
-        `OrcaContext.serving_replicas`."""
-        from analytics_zoo_tpu.common.context import OrcaContext
-        if n_replicas == "auto":
-            n_replicas = OrcaContext.serving_replicas
+              phase_aware: bool = False, warmup: bool = True,
+              **engine_kwargs) -> "ReplicaRouter":
+        """Construct `n_replicas` engines — each with a fresh
+        `MetricsRegistry` — over shared model/params."""
         n = int(n_replicas)
         if n < 1:
-            raise ValueError(
-                f"n_replicas must be >= 1, got {n} (set "
-                "OrcaContext.serving_replicas or pass n_replicas)")
-        if "kv_host_tier" not in engine_kwargs \
-                and OrcaContext.kv_host_tier_bytes > 0:
+            raise ValueError(f"n_replicas must be >= 1, got {n}")
+        tier = engine_kwargs.get("kv_host_tier")
+        if isinstance(tier, int) and tier > 0:
             # ONE tier shared by every replica — the disaggregation
             # transport: a per-replica tier would privatize spills and
             # decode replicas could never adopt prefill-replica blocks
-            from analytics_zoo_tpu.serving.generation.host_tier import (
-                HostKVTier,
-            )
-            engine_kwargs["kv_host_tier"] = HostKVTier(
-                OrcaContext.kv_host_tier_bytes)
+            engine_kwargs["kv_host_tier"] = HostKVTier(tier)
         engines = []
         for _ in range(n):
             eng = GenerationEngine(model, params,
@@ -301,7 +288,7 @@ class ReplicaRouter:
             engines.append(eng)
         return cls(engines, registry=registry,
                    occupancy_weight=occupancy_weight,
-                   max_requeues=max_requeues)
+                   max_requeues=max_requeues, phase_aware=phase_aware)
 
     # -- health --------------------------------------------------------
 

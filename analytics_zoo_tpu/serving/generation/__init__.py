@@ -18,7 +18,7 @@ subsystem:
 * `PrefixCache` — radix tree over token-id block chunks mapping
   prompt prefixes to committed, refcount-shared KV pool blocks with
   copy-on-write and LRU eviction (prefix_cache.py;
-  `OrcaContext.prefix_caching`).
+  `GenerationEngine(prefix_caching=True)`).
 * `CausalLM` — a GPT-style decoder on
   `ops.attention.dot_product_attention`'s KV-cache read path
   (model.py), with greedy/temperature/top-k sampling (sampling.py).
@@ -29,11 +29,12 @@ subsystem:
 * `Speculator` / `ngram_draft` — draft-free speculative decoding:
   n-gram prompt-lookup proposals verified k-at-a-time by one compiled
   step, accepted-prefix emission, free-list rollback (speculation.py;
-  `OrcaContext.speculative_decoding`).
+  `GenerationEngine(speculative_decoding=True)`).
 * `GenerationEngine` — the decode loop tying them together: bucketed
   prefill + ONE static-shape decode step (zero recompiles after
-  warmup), token streaming, tokens/sec + cache-occupancy metrics
-  (engine.py).  `ServingServer` exposes it as POST /generate with
+  warmup; the compiled programs are steps.py's), token streaming,
+  tokens/sec + cache-occupancy metrics (engine.py).  Every feature is
+  an argument of its constructor, default off.  `ServingServer` exposes it as POST /generate with
   chunked streaming responses.
 """
 

@@ -16,15 +16,16 @@ engine: one block vocabulary, each layer's kinds read from lists.
     `num_experts`, the `experts_held` slice of them computed here, and
     a shared expert).
 
-The call contract is `CausalLM`'s, so `engine._build_steps` drives it
+The call contract is `CausalLM`'s, so `steps.build_steps` drives it
 unchanged: `input_ids, positions, token_mask | ctx_k/ctx_v/ctx_len |
 kv_pool/block_tables/ctx_len` -> `logits, new_k, new_v`, the new keys
 and values `[layers, batch, t, KV heads, head_dim]` as the pool stores
 them (normalised and rotated).  Attention goes through `ops.attention`
 in every mode.  What the engine has to know of the shapes it asks:
-`kv_geometry()` (layers, KV heads, head dim of a pool row) and
-`moe_counts_shape` (the expert layers' counts, sown under
-`MOE_COUNTS` when the caller makes that collection mutable).
+`kv_geometry()` (layers, KV heads, head dim of a pool row).  The
+expert layers' counts are sown under `MOE_COUNTS` when the caller
+makes that collection mutable, and `ExpertCounters` is the one reader
+of their layout.
 
 Weights are held in `param_dtype` (bfloat16 as served: at 6144 wide a
 float32 tree cast every step would be twice the chip) under leaves
@@ -39,6 +40,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention,
@@ -388,3 +390,59 @@ class DecoderLM(nn.Module):
         logits = dense(self.vocab, "lm_head")(norm("final_norm")(x))
         return (logits.astype(jnp.float32),
                 jnp.stack(new_k), jnp.stack(new_v))
+
+
+class ExpertCounters:
+    """The expert layers' counts in a registry: tokens each held expert
+    computed, by layer and global expert id (the registry has no
+    labels: they ride in the name), where the router's assignments
+    went, and the ones lost on the way.  The one reader of the
+    `[expert layers, held + 2]` array a `DecoderLM` sows
+    (`ExpertLayer`'s counts, stacked)."""
+
+    @classmethod
+    def of(cls, model, registry) -> Optional["ExpertCounters"]:
+        """None for a model that hands back no counts."""
+        if getattr(model, "moe_counts_shape", None) is None:
+            return None
+        return cls(model, registry)
+
+    def __init__(self, model: DecoderLM, reg):
+        first, held = model.held
+        self._tokens = [
+            [reg.counter(
+                f"generation_moe_expert_tokens_total_layer{layer}"
+                f"_expert{first + e}",
+                help="tokens this expert computed")
+             for e in range(held)] for layer in model.moe_layers]
+        self._held = reg.counter(
+            "generation_moe_assignments_total_held",
+            help="router assignments to experts held here")
+        self._elsewhere = reg.counter(
+            "generation_moe_assignments_total_elsewhere",
+            help="router assignments to experts other chips hold")
+        self._dropped = reg.counter(
+            "generation_moe_dropped_total",
+            help="assignments to held experts that no expert "
+                 "computed (must read 0)")
+        self._loads = {
+            program: reg.counter(
+                f"generation_moe_expert_loads_total_{program}",
+                help="(layer, held expert) pairs a dispatch had a "
+                     "token for: expert weights it had to read")
+            for program in ("prefill", "decode")}
+
+    def add(self, counts, program: str) -> None:
+        """Add one dispatch's fetched counts.  `program`: "prefill"
+        (chunks too) or "decode" (verify rounds too)."""
+        counts = np.asarray(counts)
+        held = counts.shape[1] - 2
+        self._loads[program].inc(int((counts[:, :held] > 0).sum()))
+        for row, counters in zip(counts, self._tokens):
+            for n, counter in zip(row[:held], counters):
+                if n:
+                    counter.inc(int(n))
+        to_held = int(counts[:, held].sum())
+        self._held.inc(to_held)
+        self._elsewhere.inc(int(counts[:, held + 1].sum()) - to_held)
+        self._dropped.inc(to_held - int(counts[:, :held].sum()))

@@ -15,19 +15,19 @@ the model's paged path, and the paged-attention kernel
 (ops/pallas/paged_attention.py via ops.attention.paged_decode_attention)
 gathers blocks by table index INSIDE the kernel — no contiguous
 [S, C, h, d] context tensor is materialized (`decode_attention=
-"concat"` keeps the legacy XLA-gather+concat path as the bench
-baseline).  New tokens' K/V are scattered back into block slots —
-quantized on write when the pool is int8 (`kv_quantization`, default
-from OrcaContext.kv_cache_quantization).  Inactive lanes carry the
-null block table and scribble into block 0 (kv_cache.py).
+"concat"` keeps the legacy XLA-gather+concat path as the parity
+oracle).  New tokens' K/V are scattered back into block slots —
+quantized on write when the pool is int8 (`kv_quantization="int8"`).
+Inactive lanes carry the null block table and scribble into block 0
+(kv_cache.py).
 
 Streaming: `submit()` returns a `GenerationStream`; the engine loop
 pushes each sampled token as it exists, so a consumer (the HTTP
 /generate chunked response) emits tokens with per-token latency, not
 per-request.
 
-Prefix caching + chunked prefill (`OrcaContext.prefix_caching` /
-`OrcaContext.chunked_prefill`, both default off → the legacy paths are
+Prefix caching + chunked prefill (`prefix_caching=True` /
+`chunked_prefill=True`, both default off → the legacy paths are
 bitwise untouched): with either on, prefill runs through ONE extra
 compiled family — the chunk step, which attends over the
 already-written pool context and writes a bucket-sized slab of new
@@ -39,7 +39,7 @@ copy-on-write live in prefix_cache.py + scheduler.py; the decode
 program is identical in every mode, so the zero-recompile contract
 survives with everything armed.
 
-Speculative decoding (`OrcaContext.speculative_decoding` +
+Speculative decoding (`speculative_decoding=True` +
 `speculative_k`, default off → the decode path is bitwise untouched):
 greedy lanes draft up to k continuation tokens from their own token
 history (speculation.py's n-gram prompt lookup), and ONE spec-verify
@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import queue
 import threading
-from functools import partial
 from typing import Dict, List, Optional, Sequence as Seq, Tuple
 
 import jax
@@ -82,13 +81,10 @@ from analytics_zoo_tpu.observability import (
     tracing,
 )
 from analytics_zoo_tpu.serving.generation import lane_state
-from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
+from analytics_zoo_tpu.serving.generation.decoder import ExpertCounters
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
-    block_view,
-    gather_kv,
     pool_geometry,
-    write_kv,
 )
 from analytics_zoo_tpu.resilience.faults import (
     FaultInjected,
@@ -101,11 +97,11 @@ from analytics_zoo_tpu.serving.generation.host_tier import (
     record_dma,
 )
 from analytics_zoo_tpu.serving.generation.prefix_cache import PrefixCache
-from analytics_zoo_tpu.serving.generation.sampling import sample_tokens
 from analytics_zoo_tpu.serving.generation.scheduler import (
     Sequence,
     SlotScheduler,
 )
+from analytics_zoo_tpu.serving.generation.steps import build_steps
 
 _STREAM_END = object()
 
@@ -177,12 +173,14 @@ class GenerationEngine:
                  prefill_token_budget: int = 2048,
                  cache_dtype=jnp.float32, registry=None, seed: int = 0,
                  max_queue: Optional[int] = None,
-                 kv_quantization: str = "auto",
+                 kv_quantization: Optional[str] = None,
                  decode_attention: str = "paged",
                  slo_shed_min_queue: Optional[int] = None,
-                 prefix_caching="auto", chunked_prefill="auto",
-                 tensor_parallel="auto", speculative_decoding="auto",
-                 speculative_k="auto", kv_host_tier="auto"):
+                 prefix_caching: bool = False,
+                 chunked_prefill: bool = False,
+                 tensor_parallel: int = 0,
+                 speculative_decoding: bool = False,
+                 speculative_k: int = 4, kv_host_tier=0):
         if model.max_position_len < max_context:
             raise ValueError(
                 f"model.max_position_len {model.max_position_len} < "
@@ -204,14 +202,12 @@ class GenerationEngine:
         #: before anything is placed (decoder.py says which and why)
         refused = set(getattr(model, "unsupported_features",
                               lambda: ())())
-        #: tensor-parallel decode (serving/distributed/tp.py) — "auto"
-        #: reads OrcaContext.decode_tensor_parallel; 0 (the default)
-        #: keeps the legacy single-device placement bitwise untouched
-        if tensor_parallel == "auto":
-            from analytics_zoo_tpu.common.context import OrcaContext \
-                as _Ctx
-            tensor_parallel = _Ctx.decode_tensor_parallel
+        #: tensor-parallel decode (serving/distributed/tp.py); 0 (the
+        #: default) keeps the legacy single-device placement bitwise
+        #: untouched
         self.tensor_parallel = int(tensor_parallel or 0)
+        if self.tensor_parallel < 0:
+            raise ValueError("tensor_parallel must be >= 0 (0 = off)")
         if self.tensor_parallel > 1 and "tensor_parallel" in refused:
             raise NotImplementedError(
                 f"{type(model).__name__} cannot be served with "
@@ -234,41 +230,28 @@ class GenerationEngine:
         #: "paged" (default) routes the decode step through
         #: ops.attention.paged_decode_attention (block-table gather
         #: inside the kernel on TPU); "concat" keeps the legacy
-        #: gather+concat-attend path (the bench baseline / parity
-        #: oracle)
+        #: gather+concat-attend path (the parity oracle)
         self.decode_attention = decode_attention
-        from analytics_zoo_tpu.common.context import OrcaContext
-        if kv_quantization == "auto":
-            kv_quantization = OrcaContext.kv_cache_quantization
         self.kv_quantization = kv_quantization
         self._quantized = kv_quantization == "int8"
         if self._quantized and "kv_quantization" in refused:
             raise NotImplementedError(
                 f"{type(model).__name__} cannot be served from an int8 "
                 f"KV pool")
-        #: radix-tree prompt-prefix reuse (prefix_cache.py) — "auto"
-        #: reads OrcaContext.prefix_caching; off (the default) keeps
-        #: the engine bitwise-identical to the pre-cache behavior
-        if prefix_caching == "auto":
-            prefix_caching = OrcaContext.prefix_caching
+        #: radix-tree prompt-prefix reuse (prefix_cache.py); off (the
+        #: default) keeps the engine bitwise-identical to the
+        #: pre-cache behavior
         self.prefix_caching = bool(prefix_caching)
-        #: chunked prefill — "auto" reads OrcaContext.chunked_prefill;
-        #: on, long prompts prefill in token-budget-bounded chunks
-        #: with decode steps for the other lanes in between
-        if chunked_prefill == "auto":
-            chunked_prefill = OrcaContext.chunked_prefill
+        #: chunked prefill: on, long prompts prefill in
+        #: token-budget-bounded chunks with decode steps for the other
+        #: lanes in between
         self.chunked_prefill = bool(chunked_prefill)
         #: either feature routes prefill through the chunk step (the
         #: ctx-aware prefill program); both off keeps the legacy
         #: whole-prompt prefill path untouched
         self._use_chunks = self.prefix_caching or self.chunked_prefill
-        #: draft-free speculative decoding (speculation.py) — "auto"
-        #: reads OrcaContext.speculative_decoding; off (the default)
-        #: keeps the decode loop bitwise untouched
-        if speculative_decoding == "auto":
-            speculative_decoding = OrcaContext.speculative_decoding
-        if speculative_k == "auto":
-            speculative_k = OrcaContext.speculative_k
+        #: draft-free speculative decoding (speculation.py); off (the
+        #: default) keeps the decode loop bitwise untouched
         self.speculative_decoding = bool(speculative_decoding)
         self.speculation = (Speculator(int(speculative_k))
                             if self.speculative_decoding else None)
@@ -307,19 +290,18 @@ class GenerationEngine:
                 f"max_context {max_context}")
         reg = registry if registry is not None else get_registry()
         self.registry = reg
-        #: host-RAM KV offload tier (host_tier.py) — "auto" reads
-        #: OrcaContext.kv_host_tier_bytes; 0 (the default) keeps the
-        #: eviction path bitwise untouched.  Accepts a byte capacity
-        #: OR an existing HostKVTier (the router shares ONE tier
-        #: across replicas for disaggregation).  Needs the prefix
+        #: host-RAM KV offload tier (host_tier.py); 0 (the default)
+        #: keeps the eviction path bitwise untouched.  Accepts a byte
+        #: capacity OR an existing HostKVTier (the router shares ONE
+        #: tier across replicas for disaggregation).  Needs the prefix
         #: cache; disabled under tensor parallelism (a head-sharded
         #: pool has no single-host slab to spill).
-        if kv_host_tier == "auto":
-            kv_host_tier = OrcaContext.kv_host_tier_bytes
         if isinstance(kv_host_tier, HostKVTier):
             host_tier = kv_host_tier
         else:
             cap = int(kv_host_tier or 0)
+            if cap < 0:
+                raise ValueError("kv_host_tier must be >= 0 (0 = off)")
             host_tier = (HostKVTier(cap, registry=reg) if cap > 0
                          else None)
         self.host_tier = (host_tier if self.prefix_caching
@@ -395,35 +377,9 @@ class GenerationEngine:
         reg.gauge("generation_preemptions",
                   fn=lambda: self.scheduler.n_preemptions,
                   help="sequences preempted under cache pressure")
-        #: expert layers (a model with `moe_counts_shape`): tokens each
-        #: held expert computed, by layer and global expert id (the
-        #: registry has no labels: they ride in the name), where the
-        #: router's assignments went, and the ones lost on the way
-        self._moe_shape = getattr(model, "moe_counts_shape", None)
-        if self._moe_shape is not None:
-            first, held = model.held
-            self._c_moe_tokens = [
-                [reg.counter(
-                    f"generation_moe_expert_tokens_total_layer{layer}"
-                    f"_expert{first + e}",
-                    help="tokens this expert computed")
-                 for e in range(held)] for layer in model.moe_layers]
-            self._c_moe_held = reg.counter(
-                "generation_moe_assignments_total_held",
-                help="router assignments to experts held here")
-            self._c_moe_elsewhere = reg.counter(
-                "generation_moe_assignments_total_elsewhere",
-                help="router assignments to experts other chips hold")
-            self._c_moe_dropped = reg.counter(
-                "generation_moe_dropped_total",
-                help="assignments to held experts that no expert "
-                     "computed (must read 0)")
-            self._c_moe_loads = {
-                program: reg.counter(
-                    f"generation_moe_expert_loads_total_{program}",
-                    help="(layer, held expert) pairs a dispatch had a "
-                         "token for: expert weights it had to read")
-                for program in ("prefill", "decode")}
+        #: the expert layers' counters, for a model that hands back
+        #: counts (decoder.py), else None
+        self._moe = ExpertCounters.of(model, reg)
         self._c_cow = (reg.counter(
             "prefix_cache_cow_copies_total",
             help="shared blocks copy-on-write un-shared before a "
@@ -471,8 +427,18 @@ class GenerationEngine:
         #: a cold dispatch's wall time lands in the goodput "compile"
         #: bucket instead of polluting warm decode latency
         self._goodput_warm: set = set()
-
-        self._build_steps()
+        #: the compiled programs (steps.py), under the names the tests,
+        #: the smoke and the benchmark's `decode_compile_count` read
+        (self._prefill_jit, self._chunk_jit, self._decode_jit,
+         self._spec_jit, self._copy_block_jit,
+         self._restore_block_jit) = build_steps(
+            model, block_size=block_size, n_head=kv_heads,
+            quantized=self._quantized,
+            paged=decode_attention == "paged", width=self._lanes.width,
+            counted=self._moe is not None, tp=self._tp,
+            prefill_variants=self.scheduler.expected_prefill_variants(),
+            verify_variants=(self.speculation.expected_verify_variants()
+                             if self.speculation is not None else None))
 
     def _kv_pool_stats(self):
         alloc = self.cache.allocator
@@ -505,310 +471,6 @@ class GenerationEngine:
             "used_bytes_logical": logical * used // nb,
             "used_bytes_physical": physical * used // nb,
         }
-
-    # ------------------------------------------------------------------
-    # compiled steps
-    # ------------------------------------------------------------------
-
-    def _build_steps(self) -> None:
-        model = self.model
-        bs = self.cache.block_size
-        n_head = self.cache.n_head
-        max_pos = model.max_position_len
-        quantized = self._quantized
-        paged = self.decode_attention == "paged"
-        # buffer donation lets XLA update the KV pool (and its scale
-        # vectors) in place; the CPU backend ignores donation and
-        # warns, so only donate off-CPU
-        donate = ((1, 2) if jax.devices()[0].platform != "cpu" else ())
-        # ... and the lane state with them, where a step takes it
-        donate_lanes = donate and donate + (3,)
-        width = self._lanes.width
-
-        counted = self._moe_shape is not None
-
-        def apply(params, *args, token_mask=None, **kw):
-            # model.apply and, beside its outputs, the expert layers'
-            # counts where the model has any (decoder.py sows them;
-            # `token_mask` tells it which tokens are real).  A model
-            # without them is called exactly as it always was.
-            if not counted:
-                return model.apply({"params": params}, *args, **kw), ()
-            out, state = model.apply(
-                {"params": params}, *args, token_mask=token_mask,
-                mutable=[MOE_COUNTS], **kw)
-            return out, (state[MOE_COUNTS]["tokens"],)
-
-        def paged_apply(params, kv, kv_scale, tokens, pos, block_tables,
-                        ctx_len, real=None):
-            # the pool goes to the model whole, as its block view (a
-            # bitcast — kv_cache.block_view), with each lane's block
-            # table: the attention op gathers pool blocks by table
-            # index itself (ops/pallas/paged_attention.py), so neither
-            # a [S, C, h, d] context nor a per-layer slice of the pool
-            # is ever materialized
-            return apply(
-                params, tokens, pos, token_mask=real,
-                kv_pool=block_view(kv, bs),
-                kv_scale=block_view(kv_scale, bs) if quantized else None,
-                block_tables=block_tables, ctx_len=ctx_len)
-
-        def concat_apply(params, kv, kv_scale, tokens, pos, tok_idx,
-                         ctx_len, real=None):
-            # the context gathered out of the pool by token slot
-            # (kv_cache.gather_kv) and attended by the concat read
-            # path: the parity oracle, and the chunk step's read
-            ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
-            return apply(params, tokens, pos, token_mask=real,
-                         ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
-
-        def prefill(params, kv, kv_scale, lanes, request):
-            # request = [slot | the lane's row | tokens, bucket-padded]
-            # (lane_state.split_request): writes KV for the row's
-            # `length` real tokens, samples the first new token from
-            # the last real position with the state's key, and leaves
-            # the row — that token pending — in the lane's place
-            slot, row, tokens = lane_state.split_request(request, width)
-            _, length, _, temperature, top_k, block_table = \
-                lane_state.fields(row)
-            B = tokens.shape[1]
-            pos = jnp.minimum(jnp.arange(B), max_pos - 1)
-            token_mask = (jnp.arange(B) < length)[None]
-            (logits, new_k, new_v), counts = apply(
-                params, tokens, pos[None], token_mask=token_mask)
-            dest = block_table[jnp.arange(B) // bs] * bs \
-                + jnp.arange(B) % bs
-            dest = jnp.where(jnp.arange(B) < length, dest, 0)
-            kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                    new_k[:, 0], new_v[:, 0])
-            last = logits[0, length - 1]
-            rng, sub = jax.random.split(lanes["rng"])
-            nxt = sample_tokens(last[None], sub, temperature[None],
-                                top_k[None])[0]
-            rows = lane_state.admitted(lanes["rows"], slot, row, nxt)
-            return (kv, kv_scale, nxt, last,
-                    {"rows": rows, "rng": rng}) + counts
-
-        def decode(params, kv, kv_scale, lanes, patch):
-            # ONE static-shape step for all lanes, over the resident
-            # rows once `patch` (the rows the host changed, or none)
-            # is applied: tokens [S] (each lane's pending token),
-            # ctx_len [S] (= its position), block_tables [S,
-            # max_blocks], active [S] lane mask.  Hands the rows back
-            # advanced and the key split, for the next round
-            rows = lane_state.patched(lanes["rows"], patch)
-            tokens, ctx_len, active, temperature, top_k, block_tables \
-                = lane_state.fields(rows)
-            S, MB = block_tables.shape
-            pos = jnp.minimum(ctx_len, max_pos - 1)
-            if paged:
-                (logits, new_k, new_v), counts = paged_apply(
-                    params, kv, kv_scale, tokens[:, None], pos[:, None],
-                    block_tables, ctx_len, active[:, None])
-            else:
-                tok_idx = (block_tables[:, :, None] * bs
-                           + jnp.arange(bs)[None, None, :]
-                           ).reshape(S, -1)
-                (logits, new_k, new_v), counts = concat_apply(
-                    params, kv, kv_scale, tokens[:, None], pos[:, None],
-                    tok_idx, ctx_len, active[:, None])
-            dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
-                + ctx_len % bs
-            dest = jnp.where(active, dest, 0)   # dead lanes → null block
-            kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                    new_k[:, :, 0], new_v[:, :, 0])
-            last = jnp.where(active[:, None], logits[:, 0], 0.0)
-            rng, sub = jax.random.split(lanes["rng"])
-            nxt = sample_tokens(last, sub, temperature, top_k)
-            return (kv, kv_scale, nxt, last,
-                    {"rows": lane_state.advanced(rows, nxt),
-                     "rng": rng}) + counts
-
-        def chunk_prefill(params, kv, kv_scale, tokens, start, length,
-                          block_table, temperature, top_k, rng):
-            # one chunk of a (possibly prefix-matched, possibly
-            # chunked) prefill: tokens [1, B] (bucket-padded), start
-            # scalar = context tokens whose KV is already written
-            # (cached prefix + earlier chunks), length scalar = real
-            # tokens in this chunk.  The chunk attends over the
-            # already-written context (gathered from the pool by block
-            # table — the concat read path, causal semantics implied by
-            # ops.attention's ctx path) plus itself causally, writes
-            # its KV into block slots, and samples from its last real
-            # position — only the FINAL chunk's sample is consumed by
-            # the host.  `rng` is the lane state's key: split here as
-            # the other steps split it, its successor handed back.
-            B = tokens.shape[1]
-            rel = jnp.arange(B)
-            pos = jnp.minimum(start + rel, max_pos - 1)
-            tok_idx = (block_table[:, None] * bs
-                       + jnp.arange(bs)[None, :]).reshape(1, -1)
-            (logits, new_k, new_v), counts = concat_apply(
-                params, kv, kv_scale, tokens, pos[None], tok_idx,
-                jnp.reshape(start, (1,)).astype(jnp.int32),
-                (rel < length)[None])
-            dest = block_table[(start + rel) // bs] * bs \
-                + (start + rel) % bs
-            dest = jnp.where(rel < length, dest, 0)
-            kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                    new_k[:, 0], new_v[:, 0])
-            last = logits[0, length - 1]
-            rng, sub = jax.random.split(rng)
-            nxt = sample_tokens(last[None], sub, temperature, top_k)[0]
-            return (kv, kv_scale, nxt, last, rng) + counts
-
-        def spec_verify(params, kv, kv_scale, tokens, block_tables,
-                        start, length, active):
-            # speculative verify over the whole slot grid: tokens
-            # [S, W] = each drafted lane's [pending token ; draft ;
-            # pad], start [S] = context tokens whose KV is already
-            # written (= context_len - 1), length [S] = 1 + real draft
-            # tokens, active [S].  Every position attends over the
-            # lane's pool context plus the preceding new tokens (the
-            # chunk step's ctx-read semantics, batched over lanes —
-            # ops.attention.paged_verify_attention), writes its KV
-            # into the lane's (pre-grown) block slots, and the host
-            # accepts the longest draft prefix matching the returned
-            # per-position greedy argmax.  Speculation is greedy-only,
-            # so no rng/temperature ride in.
-            S, W = tokens.shape
-            rel = jnp.arange(W)
-            pos = jnp.minimum(start[:, None] + rel[None], max_pos - 1)
-            real = (rel[None] < length[:, None]) & active[:, None]
-            if paged:
-                (logits, new_k, new_v), counts = paged_apply(
-                    params, kv, kv_scale, tokens, pos, block_tables,
-                    start, real)
-            else:
-                tok_idx = (block_tables[:, :, None] * bs
-                           + jnp.arange(bs)[None, None, :]
-                           ).reshape(S, -1)
-                (logits, new_k, new_v), counts = concat_apply(
-                    params, kv, kv_scale, tokens, pos, tok_idx, start,
-                    real)
-            abs_pos = start[:, None] + rel[None]        # [S, W]
-            dest = block_tables[jnp.arange(S)[:, None],
-                                abs_pos // bs] * bs + abs_pos % bs
-            dest = jnp.where((rel[None] < length[:, None])
-                             & active[:, None], dest, 0).reshape(-1)
-            L = new_k.shape[0]
-            kv, kv_scale = write_kv(
-                kv, kv_scale, dest,
-                new_k.reshape(L, S * W, *new_k.shape[-2:]),
-                new_v.reshape(L, S * W, *new_v.shape[-2:]))
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (kv, kv_scale, greedy) + counts
-
-        def copy_block(kv, kv_scale, src, dst):
-            # copy-on-write: duplicate one pool block's token slots
-            # (and their dequant scales) so a shared block becomes
-            # exclusively owned before it is written
-            rows = jax.lax.dynamic_slice_in_dim(kv, src * bs, bs,
-                                                axis=2)
-            kv = jax.lax.dynamic_update_slice_in_dim(kv, rows,
-                                                     dst * bs, axis=2)
-            if quantized:
-                srows = jax.lax.dynamic_slice_in_dim(
-                    kv_scale, src * bs, bs, axis=2)
-                kv_scale = jax.lax.dynamic_update_slice_in_dim(
-                    kv_scale, srows, dst * bs, axis=2)
-            return kv, kv_scale
-
-        def restore_block(kv, kv_scale, dst, rows, srows):
-            # host-tier restore: land one host slab's token slots
-            # (rows [L, 2, bs, h*d] in pool dtype, srows [L, 2, bs]
-            # scales — a 1-element placeholder unquantized) into pool
-            # block `dst`.  A separate single-shape program, warmed in
-            # warmup(), never touching the decode step.
-            kv = jax.lax.dynamic_update_slice_in_dim(
-                kv, rows.astype(kv.dtype), dst * bs, axis=2)
-            if quantized:
-                kv_scale = jax.lax.dynamic_update_slice_in_dim(
-                    kv_scale, srows.astype(kv_scale.dtype),
-                    dst * bs, axis=2)
-            return kv, kv_scale
-
-        # dispatch-ledger registration happens HERE, at jit-wrap time:
-        # every compiled program family the engine can dispatch gets a
-        # ledgered wrapper (signature forensics + call counting;
-        # `_cache_size` forwards so the compile-count pins below keep
-        # reading the real jit cache).  Argument names feed the
-        # compile-event differ so a recompile post-mortem names the
-        # guilty leaf as e.g. `tokens: int32[4] -> int32[5]`.
-        _ledger = profiling.instrument
-        _names_prefill = ("params", "kv", "kv_scale", "lanes", "request")
-        _names_chunk = ("params", "kv", "kv_scale", "tokens", "start",
-                        "length", "block_table", "temperature",
-                        "top_k", "rng")
-        _names_decode = ("params", "kv", "kv_scale", "lanes", "patch")
-        _names_spec = ("params", "kv", "kv_scale", "tokens",
-                       "block_tables", "start", "length", "active")
-        if self._tp is not None:
-            # identical step functions; only placement differs — the
-            # wrapper pins out_shardings (pool head-sharded, scales/
-            # tokens/logits replicated) so every step's outputs feed
-            # the next step in the same layout (zero-recompile holds)
-            self._prefill_jit = _ledger(
-                "prefill", self._tp.jit_step(prefill, donate_lanes, 5),
-                argnames=_names_prefill)
-            self._chunk_jit = _ledger(
-                "chunk_prefill",
-                self._tp.jit_step(chunk_prefill, donate, 5),
-                argnames=_names_chunk)
-            self._copy_block_jit = _ledger(
-                "copy_block",
-                self._tp.jit_step(copy_block,
-                                  ((0, 1) if donate else ()), 2),
-                argnames=("kv", "kv_scale", "src", "dst"))
-            self._restore_block_jit = None   # host tier off under TP
-            self._decode_jit = _ledger(
-                "decode", self._tp.jit_step(decode, donate_lanes, 5),
-                argnames=_names_decode)
-            self._spec_jit = _ledger(
-                "spec_verify",
-                self._tp.jit_step(spec_verify, donate, 3),
-                argnames=_names_spec)
-        else:
-            self._prefill_jit = _ledger(
-                "prefill", jax.jit(prefill, donate_argnums=donate_lanes),
-                argnames=_names_prefill)
-            self._chunk_jit = _ledger(
-                "chunk_prefill",
-                jax.jit(chunk_prefill, donate_argnums=donate),
-                argnames=_names_chunk)
-            self._copy_block_jit = _ledger(
-                "copy_block",
-                jax.jit(copy_block,
-                        donate_argnums=((0, 1) if donate else ())),
-                argnames=("kv", "kv_scale", "src", "dst"))
-            self._restore_block_jit = _ledger(
-                "host_restore",
-                jax.jit(restore_block,
-                        donate_argnums=((0, 1) if donate else ())),
-                argnames=("kv", "kv_scale", "dst", "rows", "srows"))
-            self._decode_jit = _ledger(
-                "decode", jax.jit(decode, donate_argnums=donate_lanes),
-                argnames=_names_decode)
-            self._spec_jit = _ledger(
-                "spec_verify",
-                jax.jit(spec_verify, donate_argnums=donate),
-                argnames=_names_spec)
-
-        # compile budgets: how many program variants each family's
-        # call-site geometry implies — the ledger flags `over_budget`
-        # the moment a family compiles MORE (a recompile storm is then
-        # a budget breach in /dispatch, not just a counter rate)
-        n_buckets = self.scheduler.expected_prefill_variants()
-        profiling.declare_expected("prefill", n_buckets)
-        profiling.declare_expected("chunk_prefill", n_buckets)
-        profiling.declare_expected("decode", 1)
-        profiling.declare_expected("copy_block", 1)
-        if self._restore_block_jit is not None:
-            profiling.declare_expected("host_restore", 1)
-        if self.speculation is not None:
-            profiling.declare_expected(
-                "spec_verify",
-                self.speculation.expected_verify_variants())
 
     def _store_kv_state(self, kv, kv_scale) -> None:
         self.cache.kv = kv
@@ -907,7 +569,7 @@ class GenerationEngine:
             self._goodput_warm.add("decode")
             if self._use_chunks:
                 self._goodput_warm.update(
-                    ("chunk", b) for b in chunk_buckets)
+                    ("chunk_prefill", b) for b in chunk_buckets)
             else:
                 self._goodput_warm.update(
                     ("prefill", b)
@@ -1046,31 +708,35 @@ class GenerationEngine:
         if reason:
             self._finish(seq, reason)
 
-    def _account_moe(self, fetched, program: str) -> None:
-        """Add one dispatch's expert counts ([expert layers, held + 2]:
-        decoder.ExpertLayer) to the registry; `fetched` is empty for a
-        model without expert layers.  `program`: "prefill" (chunks
-        too) or "decode" (verify rounds too)."""
-        if not fetched:
-            return
-        counts = np.asarray(fetched[0])
-        held = counts.shape[1] - 2
-        self._c_moe_loads[program].inc(int((counts[:, :held] > 0).sum()))
-        for row, counters in zip(counts, self._c_moe_tokens):
-            for n, counter in zip(row[:held], counters):
-                if n:
-                    counter.inc(int(n))
-        to_held = int(counts[:, held].sum())
-        self._c_moe_held.inc(to_held)
-        self._c_moe_elsewhere.inc(int(counts[:, held + 1].sum()) - to_held)
-        self._c_moe_dropped.inc(to_held - int(counts[:, :held].sum()))
-
     def _end_step(self, rec) -> None:
         """Close a step record: the goodput commit (counters, the
         timeline ring, the memory sampler) is accounting like the rest,
         and shows as such in a profiler trace."""
         with tracing.phase("generation.account"):
             rec.end()
+
+    def _account_prefill(self, seq: Sequence, family: str, bucket: int,
+                         tokens: int, t0: float, moe,
+                         start: Optional[int] = None) -> None:
+        """What a whole-prompt prefill and a chunk account alike, once
+        the sampled token is fetched: `tokens` real tokens through
+        `family`'s `bucket` program, dispatched at `t0`; `start`: a
+        chunk's first position (None: a whole prompt)."""
+        if moe:
+            self._moe.add(moe[0], "prefill")
+        self._goodput_warm.add((family, bucket))
+        dur = now() - t0
+        self._h_prefill.record(dur, tokens)
+        profiling.record_work(
+            family, dur, tokens=tokens,
+            flops=(self._flops.prefill(tokens, ctx_start=start or 0)
+                   if self._flops else 0.0))
+        self._c_prefill_tokens.inc(tokens)
+        request_log.attribute(seq.request_id, "prefill_compute", dur)
+        where = {} if start is None else {"start": start}
+        request_log.event(seq.request_id, "prefill", bucket=bucket,
+                          tokens=tokens, **where, dur_s=round(dur, 6),
+                          resumed=seq.n_preempted > 0)
 
     def _prefill_seq(self, seq: Sequence) -> None:
         rec = self._clock_prefill.begin(force_fence=True)
@@ -1095,20 +761,7 @@ class GenerationEngine:
                 moe = jax.device_get(moe)
                 lanes.landed(slot, row, nxt)
             with rec.phase("generation.account"):
-                self._account_moe(moe, "prefill")
-                self._goodput_warm.add(("prefill", bucket))
-                dur = now() - t0
-                self._h_prefill.record(dur, L)
-                profiling.record_work(
-                    "prefill", dur, tokens=L,
-                    flops=self._flops.prefill(L) if self._flops else 0.0)
-                self._c_prefill_tokens.inc(L)
-                request_log.attribute(seq.request_id, "prefill_compute",
-                                      dur)
-                request_log.event(seq.request_id, "prefill",
-                                  bucket=bucket, tokens=L,
-                                  dur_s=round(dur, 6),
-                                  resumed=seq.n_preempted > 0)
+                self._account_prefill(seq, "prefill", bucket, L, t0, moe)
             with rec.phase("generation.emit"):
                 self._emit(seq, nxt)
             self._end_step(rec)
@@ -1164,7 +817,8 @@ class GenerationEngine:
                 table = np.zeros(MB, np.int32)
                 table[:len(seq.block_table)] = seq.block_table
             t0 = now()
-            rec.cold = ("chunk", bucket) not in self._goodput_warm
+            rec.cold = ("chunk_prefill", bucket) \
+                not in self._goodput_warm
             with rec.phase("generation.dispatch"), self._lanes.guard():
                 state = self._lanes.state
                 kv, scl, nxt, _, state["rng"], *moe = self._chunk_jit(
@@ -1178,22 +832,9 @@ class GenerationEngine:
                 nxt = int(nxt)            # token fetch = device fence
                 moe = jax.device_get(moe)
             with rec.phase("generation.account"):
-                self._account_moe(moe, "prefill")
-                self._goodput_warm.add(("chunk", bucket))
-                dur = now() - t0
-                self._h_prefill.record(dur, real)
-                profiling.record_work(
-                    "chunk_prefill", dur, tokens=real,
-                    flops=(self._flops.prefill(real, ctx_start=start)
-                           if self._flops else 0.0))
-                self._c_prefill_tokens.inc(real)
+                self._account_prefill(seq, "chunk_prefill", bucket, real,
+                                      t0, moe, start=start)
                 seq.prefill_pos = start + real
-                request_log.attribute(seq.request_id, "prefill_compute",
-                                      dur)
-                request_log.event(seq.request_id, "prefill",
-                                  bucket=bucket, tokens=real,
-                                  start=start, dur_s=round(dur, 6),
-                                  resumed=seq.n_preempted > 0)
             with rec.phase("generation.emit"):
                 if seq.prefill_pos >= L:
                     if self.prefix_cache is not None:
@@ -1397,7 +1038,8 @@ class GenerationEngine:
             # finish), and a trace shows two spans, not two a lane
             accepted = []
             with rec.phase("generation.account"):
-                self._account_moe(moe, "decode")
+                if moe:
+                    self._moe.add(moe[0], "decode")
                 self._goodput_warm.add(("spec", W - 1))
                 dur = now() - t0
                 self._h_decode.record(dur, len(drafted) + len(riders))
@@ -1512,7 +1154,8 @@ class GenerationEngine:
             # each request's log keeps its order (decode round, token,
             # finish), and a trace shows two spans, not two a lane
             with rec.phase("generation.account"):
-                self._account_moe(moe, "decode")
+                if moe:
+                    self._moe.add(moe[0], "decode")
                 self._goodput_warm.add("decode")
                 dur = now() - t0
                 self._h_decode.record(dur, len(lanes))

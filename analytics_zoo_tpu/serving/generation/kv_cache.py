@@ -33,8 +33,8 @@ Allocation is host-side (the free list is python state; the device
 never sees it) — the allocator hands block ids to the scheduler, which
 bakes them into the block-table arrays fed to the jitted step.
 
-Quantized mode (`quantization="int8"`, the
-`OrcaContext.kv_cache_quantization` knob): the pool stores int8 with a
+Quantized mode (`quantization="int8"`, the engine's
+`kv_quantization="int8"`): the pool stores int8 with a
 per-token-slot symmetric scale vector `kv_scale`
 [n_layers, 2, num_blocks * block_size] f32 — the `serving/quantize.py`
 amax/127 calibration idiom applied at token granularity, so appends

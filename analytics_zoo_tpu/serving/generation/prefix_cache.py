@@ -42,7 +42,7 @@ metric index, docs/generation.md).
 resilience/faults.py): a "raise" there must surface as a failed
 admission, never a corrupted tree.
 
-Host tier (host_tier.py, `OrcaContext.kv_host_tier_bytes`): with a
+Host tier (host_tier.py, the engine's `kv_host_tier=`): with a
 `HostKVTier` attached, `evict` copies each victim's KV rows to host
 RAM before freeing the block, and `restore` extends a device radix
 match with host-resident blocks — allocating a fresh pool block per

@@ -121,7 +121,7 @@ class Speculator:
     """Drafting policy + k-bucket geometry for one engine.
 
     `k` is the max drafted tokens per lane per round
-    (`OrcaContext.speculative_k`).  Verify programs compile per pow2
+    (the engine's `speculative_k=`).  Verify programs compile per pow2
     bucket (`buckets`), so draft lengths map onto O(log k) compiled
     families — the zero-recompile contract holds with speculation
     armed (1 decode family + len(buckets) verify families, pinned by

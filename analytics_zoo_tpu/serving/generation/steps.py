@@ -1,0 +1,296 @@
+"""The generation engine's compiled step programs, built once.
+
+Nothing here knows the engine, the scheduler or a request: a program's
+arguments are the device state (params, pool, scales, lane state:
+lane_state.py) and one upload, its results the same state advanced.
+The engine's loop (engine.py) owns when each one runs and what the
+host does with what it fetches.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from analytics_zoo_tpu.observability import profiling
+from analytics_zoo_tpu.serving.generation import lane_state
+from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
+from analytics_zoo_tpu.serving.generation.kv_cache import (
+    block_view,
+    gather_kv,
+    write_kv,
+)
+from analytics_zoo_tpu.serving.generation.sampling import sample_tokens
+
+
+def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
+                paged: bool, width: int, counted: bool, tp=None,
+                prefill_variants: int,
+                verify_variants: Optional[int] = None) -> tuple:
+    """The six program families the engine dispatches, jitted and
+    registered with the dispatch ledger: (`prefill`, `chunk_prefill`,
+    `decode`, `spec_verify`, `copy_block`, `restore_block`), the last
+    None under tensor parallelism (the host tier is off there).
+
+    `block_size`, `n_head`: the pool's block length and KV heads;
+    `quantized`: an int8 pool with scales beside it; `paged`: decode
+    and verify read the pool through the paged kernel (else the
+    gather+concat oracle); `width`: a lane row's width
+    (`LaneState.width`); `counted`: the model sows expert counts, which
+    ride back as one more result (decoder.py); `tp`: the
+    `TensorParallelPlacement`, or None on one device.
+    `prefill_variants` / `verify_variants` are the compile budgets of
+    the bucketed families (None: speculation is off)."""
+    bs = block_size
+    max_pos = model.max_position_len
+    # buffer donation lets XLA update the KV pool (and its scale
+    # vectors) in place; the CPU backend ignores donation and
+    # warns, so only donate off-CPU
+    donate = ((1, 2) if jax.devices()[0].platform != "cpu" else ())
+    # ... and the lane state with them, where a step takes it
+    donate_lanes = donate and donate + (3,)
+    # the block programs take the pool first
+    donate_pool = (0, 1) if donate else ()
+
+    def apply(params, *args, token_mask=None, **kw):
+        # model.apply and, beside its outputs, the expert layers'
+        # counts where the model has any (decoder.py sows them;
+        # `token_mask` tells it which tokens are real).  A model
+        # without them is called exactly as it always was.
+        if not counted:
+            return model.apply({"params": params}, *args, **kw), ()
+        out, state = model.apply(
+            {"params": params}, *args, token_mask=token_mask,
+            mutable=[MOE_COUNTS], **kw)
+        return out, (state[MOE_COUNTS]["tokens"],)
+
+    def paged_apply(params, kv, kv_scale, tokens, pos, block_tables,
+                    ctx_len, real=None):
+        # the pool goes to the model whole, as its block view (a
+        # bitcast — kv_cache.block_view), with each lane's block
+        # table: the attention op gathers pool blocks by table
+        # index itself (ops/pallas/paged_attention.py), so neither
+        # a [S, C, h, d] context nor a per-layer slice of the pool
+        # is ever materialized
+        return apply(
+            params, tokens, pos, token_mask=real,
+            kv_pool=block_view(kv, bs),
+            kv_scale=block_view(kv_scale, bs) if quantized else None,
+            block_tables=block_tables, ctx_len=ctx_len)
+
+    def concat_apply(params, kv, kv_scale, tokens, pos, tok_idx,
+                     ctx_len, real=None):
+        # the context gathered out of the pool by token slot
+        # (kv_cache.gather_kv) and attended by the concat read
+        # path: the parity oracle, and the chunk step's read
+        ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
+        return apply(params, tokens, pos, token_mask=real,
+                     ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
+
+    def prefill(params, kv, kv_scale, lanes, request):
+        # request = [slot | the lane's row | tokens, bucket-padded]
+        # (lane_state.split_request): writes KV for the row's
+        # `length` real tokens, samples the first new token from
+        # the last real position with the state's key, and leaves
+        # the row — that token pending — in the lane's place
+        slot, row, tokens = lane_state.split_request(request, width)
+        _, length, _, temperature, top_k, block_table = \
+            lane_state.fields(row)
+        B = tokens.shape[1]
+        pos = jnp.minimum(jnp.arange(B), max_pos - 1)
+        token_mask = (jnp.arange(B) < length)[None]
+        (logits, new_k, new_v), counts = apply(
+            params, tokens, pos[None], token_mask=token_mask)
+        dest = block_table[jnp.arange(B) // bs] * bs \
+            + jnp.arange(B) % bs
+        dest = jnp.where(jnp.arange(B) < length, dest, 0)
+        kv, kv_scale = write_kv(kv, kv_scale, dest,
+                                new_k[:, 0], new_v[:, 0])
+        last = logits[0, length - 1]
+        rng, sub = jax.random.split(lanes["rng"])
+        nxt = sample_tokens(last[None], sub, temperature[None],
+                            top_k[None])[0]
+        rows = lane_state.admitted(lanes["rows"], slot, row, nxt)
+        return (kv, kv_scale, nxt, last,
+                {"rows": rows, "rng": rng}) + counts
+
+    def decode(params, kv, kv_scale, lanes, patch):
+        # ONE static-shape step for all lanes, over the resident
+        # rows once `patch` (the rows the host changed, or none)
+        # is applied: tokens [S] (each lane's pending token),
+        # ctx_len [S] (= its position), block_tables [S,
+        # max_blocks], active [S] lane mask.  Hands the rows back
+        # advanced and the key split, for the next round
+        rows = lane_state.patched(lanes["rows"], patch)
+        tokens, ctx_len, active, temperature, top_k, block_tables \
+            = lane_state.fields(rows)
+        S, MB = block_tables.shape
+        pos = jnp.minimum(ctx_len, max_pos - 1)
+        if paged:
+            (logits, new_k, new_v), counts = paged_apply(
+                params, kv, kv_scale, tokens[:, None], pos[:, None],
+                block_tables, ctx_len, active[:, None])
+        else:
+            tok_idx = (block_tables[:, :, None] * bs
+                       + jnp.arange(bs)[None, None, :]
+                       ).reshape(S, -1)
+            (logits, new_k, new_v), counts = concat_apply(
+                params, kv, kv_scale, tokens[:, None], pos[:, None],
+                tok_idx, ctx_len, active[:, None])
+        dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
+            + ctx_len % bs
+        dest = jnp.where(active, dest, 0)   # dead lanes → null block
+        kv, kv_scale = write_kv(kv, kv_scale, dest,
+                                new_k[:, :, 0], new_v[:, :, 0])
+        last = jnp.where(active[:, None], logits[:, 0], 0.0)
+        rng, sub = jax.random.split(lanes["rng"])
+        nxt = sample_tokens(last, sub, temperature, top_k)
+        return (kv, kv_scale, nxt, last,
+                {"rows": lane_state.advanced(rows, nxt),
+                 "rng": rng}) + counts
+
+    def chunk_prefill(params, kv, kv_scale, tokens, start, length,
+                      block_table, temperature, top_k, rng):
+        # one chunk of a (possibly prefix-matched, possibly
+        # chunked) prefill: tokens [1, B] (bucket-padded), start
+        # scalar = context tokens whose KV is already written
+        # (cached prefix + earlier chunks), length scalar = real
+        # tokens in this chunk.  The chunk attends over the
+        # already-written context (gathered from the pool by block
+        # table — the concat read path, causal semantics implied by
+        # ops.attention's ctx path) plus itself causally, writes
+        # its KV into block slots, and samples from its last real
+        # position — only the FINAL chunk's sample is consumed by
+        # the host.  `rng` is the lane state's key: split here as
+        # the other steps split it, its successor handed back.
+        B = tokens.shape[1]
+        rel = jnp.arange(B)
+        pos = jnp.minimum(start + rel, max_pos - 1)
+        tok_idx = (block_table[:, None] * bs
+                   + jnp.arange(bs)[None, :]).reshape(1, -1)
+        (logits, new_k, new_v), counts = concat_apply(
+            params, kv, kv_scale, tokens, pos[None], tok_idx,
+            jnp.reshape(start, (1,)).astype(jnp.int32),
+            (rel < length)[None])
+        dest = block_table[(start + rel) // bs] * bs \
+            + (start + rel) % bs
+        dest = jnp.where(rel < length, dest, 0)
+        kv, kv_scale = write_kv(kv, kv_scale, dest,
+                                new_k[:, 0], new_v[:, 0])
+        last = logits[0, length - 1]
+        rng, sub = jax.random.split(rng)
+        nxt = sample_tokens(last[None], sub, temperature, top_k)[0]
+        return (kv, kv_scale, nxt, last, rng) + counts
+
+    def spec_verify(params, kv, kv_scale, tokens, block_tables,
+                    start, length, active):
+        # speculative verify over the whole slot grid: tokens
+        # [S, W] = each drafted lane's [pending token ; draft ;
+        # pad], start [S] = context tokens whose KV is already
+        # written (= context_len - 1), length [S] = 1 + real draft
+        # tokens, active [S].  Every position attends over the
+        # lane's pool context plus the preceding new tokens (the
+        # chunk step's ctx-read semantics, batched over lanes —
+        # ops.attention.paged_verify_attention), writes its KV
+        # into the lane's (pre-grown) block slots, and the host
+        # accepts the longest draft prefix matching the returned
+        # per-position greedy argmax.  Speculation is greedy-only,
+        # so no rng/temperature ride in.
+        S, W = tokens.shape
+        rel = jnp.arange(W)
+        pos = jnp.minimum(start[:, None] + rel[None], max_pos - 1)
+        real = (rel[None] < length[:, None]) & active[:, None]
+        if paged:
+            (logits, new_k, new_v), counts = paged_apply(
+                params, kv, kv_scale, tokens, pos, block_tables,
+                start, real)
+        else:
+            tok_idx = (block_tables[:, :, None] * bs
+                       + jnp.arange(bs)[None, None, :]
+                       ).reshape(S, -1)
+            (logits, new_k, new_v), counts = concat_apply(
+                params, kv, kv_scale, tokens, pos, tok_idx, start,
+                real)
+        abs_pos = start[:, None] + rel[None]        # [S, W]
+        dest = block_tables[jnp.arange(S)[:, None],
+                            abs_pos // bs] * bs + abs_pos % bs
+        dest = jnp.where((rel[None] < length[:, None])
+                         & active[:, None], dest, 0).reshape(-1)
+        L = new_k.shape[0]
+        kv, kv_scale = write_kv(
+            kv, kv_scale, dest,
+            new_k.reshape(L, S * W, *new_k.shape[-2:]),
+            new_v.reshape(L, S * W, *new_v.shape[-2:]))
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (kv, kv_scale, greedy) + counts
+
+    def copy_block(kv, kv_scale, src, dst):
+        # copy-on-write: duplicate one pool block's token slots
+        # (and their dequant scales) so a shared block becomes
+        # exclusively owned before it is written
+        rows = jax.lax.dynamic_slice_in_dim(kv, src * bs, bs,
+                                            axis=2)
+        kv = jax.lax.dynamic_update_slice_in_dim(kv, rows,
+                                                 dst * bs, axis=2)
+        if quantized:
+            srows = jax.lax.dynamic_slice_in_dim(
+                kv_scale, src * bs, bs, axis=2)
+            kv_scale = jax.lax.dynamic_update_slice_in_dim(
+                kv_scale, srows, dst * bs, axis=2)
+        return kv, kv_scale
+
+    def restore_block(kv, kv_scale, dst, rows, srows):
+        # host-tier restore: land one host slab's token slots
+        # (rows [L, 2, bs, h*d] in pool dtype, srows [L, 2, bs]
+        # scales — a 1-element placeholder unquantized) into pool
+        # block `dst`.  A separate single-shape program, warmed in
+        # warmup(), never touching the decode step.
+        kv = jax.lax.dynamic_update_slice_in_dim(
+            kv, rows.astype(kv.dtype), dst * bs, axis=2)
+        if quantized:
+            kv_scale = jax.lax.dynamic_update_slice_in_dim(
+                kv_scale, srows.astype(kv_scale.dtype),
+                dst * bs, axis=2)
+        return kv, kv_scale
+
+    def ledgered(family, fn, donated, n_out, argnames, expected):
+        # ONE wrap a program: jitted where the steps run — under
+        # tensor parallelism the placement pins out_shardings (pool
+        # head-sharded, scales/tokens/logits replicated) so every
+        # step's outputs feed the next step in the same layout
+        # (zero-recompile holds) — and registered with the dispatch
+        # ledger (signature forensics + call counting; `_cache_size`
+        # forwards so the compile-count pins keep reading the real
+        # jit cache).  Argument names feed the compile-event differ so
+        # a recompile post-mortem names the guilty leaf as e.g.
+        # `tokens: int32[4] -> int32[5]`.  `expected`: the family's
+        # compile budget, how many program variants its call-site
+        # geometry implies — the ledger flags `over_budget` the moment
+        # it compiles MORE (a recompile storm is then a budget breach
+        # in /dispatch, not just a counter rate).
+        jitted = (tp.jit_step(fn, donated, n_out) if tp is not None
+                  else jax.jit(fn, donate_argnums=donated))
+        if expected is not None:
+            profiling.declare_expected(family, expected)
+        return profiling.instrument(family, jitted, argnames=argnames)
+
+    return (
+        ledgered("prefill", prefill, donate_lanes, 5,
+                 ("params", "kv", "kv_scale", "lanes", "request"),
+                 prefill_variants),
+        ledgered("chunk_prefill", chunk_prefill, donate, 5,
+                 ("params", "kv", "kv_scale", "tokens", "start",
+                  "length", "block_table", "temperature", "top_k",
+                  "rng"), prefill_variants),
+        ledgered("decode", decode, donate_lanes, 5,
+                 ("params", "kv", "kv_scale", "lanes", "patch"), 1),
+        ledgered("spec_verify", spec_verify, donate, 3,
+                 ("params", "kv", "kv_scale", "tokens", "block_tables",
+                  "start", "length", "active"), verify_variants),
+        ledgered("copy_block", copy_block, donate_pool, 2,
+                 ("kv", "kv_scale", "src", "dst"), 1),
+        None if tp is not None else ledgered(
+            "host_restore", restore_block, donate_pool, 2,
+            ("kv", "kv_scale", "dst", "rows", "srows"), 1))

@@ -13,7 +13,7 @@ Layers (docs/streaming.md):
 * consumers (consumer.py) — both serving backends draining a stream
   as a group (worker-pool batch predict, generation token streaming);
 * `open_loop` — the seeded Poisson/bursty arrival harness every
-  serving stack is graded under (`bench.py overload`).
+  serving stack is graded under (`tests/test_overload_harness.py`).
 """
 
 from analytics_zoo_tpu.serving.streaming.consumer import (

@@ -71,7 +71,7 @@ ENGINE = dict(max_slots=8, block_size=16, max_context=1024,
               prefill_buckets=(128, 256, 512, 1024))
 PROMPT_LENS = (64, 160, 300, 512)
 MAX_NEW_TOKENS = 32
-#: bench.py's BERT fine-tune rate.  From a random init, post-LN
+#: the BERT fine-tune rate.  From a random init, post-LN
 #: BERT-base takes no more without warm-up (at 1e-4 the loss jumps
 #: about), and at this rate Adam fits the smoke's four batches in
 #: sixteen steps — at BERT-base as at the tests' toy width

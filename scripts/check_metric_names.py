@@ -10,7 +10,7 @@ metric that exists but is absent from docs/observability.md's metric
 index is unfindable by the operator the observability layer exists
 for.  This check closes both gaps statically:
 
-* scan `analytics_zoo_tpu/` (plus `bench.py`) for
+* scan `analytics_zoo_tpu/` for
   ``.counter("name")`` / ``.gauge("name")`` / ``.histogram("name")``
   registrations whose first argument is a PLAIN string literal
   (f-strings and concatenations — the `span_<name>_seconds` /
@@ -41,7 +41,6 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PACKAGE = os.path.join(REPO, "analytics_zoo_tpu")
 DOCS = os.path.join(REPO, "docs", "observability.md")
-EXTRA_FILES = (os.path.join(REPO, "bench.py"),)
 
 #: `.counter("…")`, `.gauge('…')`, `.histogram("…")` with a plain
 #: string literal (no f/r/b prefix — constructed names are matched by
@@ -57,7 +56,6 @@ def _source_files():
         for fn in sorted(filenames):
             if fn.endswith(".py"):
                 yield os.path.join(dirpath, fn)
-    yield from EXTRA_FILES
 
 
 def find_violations():

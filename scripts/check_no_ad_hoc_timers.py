@@ -11,7 +11,7 @@ sanctioned clock — fails the build (use `observability.now`, a
 registry `Histogram.time()`, a `Timer.timing(...)` block, or a
 `trace(...)` span instead).  Since the goodput/flight-recorder/
 watchdog modules landed, the rest of `observability/` is held to the
-same rule as everyone else.  `bench.py` and `tests/` are exempt —
+same rule as everyone else.  `tests/` are exempt —
 external stopwatches measuring the system from outside are the point
 there.
 

@@ -17,7 +17,7 @@ if "xla_force_host_platform_device_count" not in flags:
 # force CPU: the tests run on the host's virtual devices whatever the
 # machine has attached
 os.environ["JAX_PLATFORMS"] = "cpu"
-# persistent compile cache (same rule as bench.py/__graft_entry__.py/
+# persistent compile cache (same rule as __graft_entry__.py/
 # chip_smoke.py: the environment's directory when it names one, else a
 # fixed path in the checkout): the suite is dominated by XLA CPU
 # compiles of conv/transformer train steps; warm reruns skip them

@@ -197,7 +197,7 @@ def test_deferred_set_params_and_load_order(tmp_path):
 @pytest.mark.slow   # ~18s warm (PR 10 budget trim): the import/export
                     # mechanics above stay tier-1, BERT-head training
                     # stays via test_multihost_and_bert_heads ner/squad,
-                    # and bench.py's BERT stage measures finetune on TPU
+                    # and the `bert_base_finetune` cell measures finetune on TPU
 def test_finetune_beats_scratch():
     """Fine-tuning from a 'pretrained' checkpoint (a previously trained
     model exported to published names) beats from-scratch under the same

@@ -37,6 +37,9 @@ from analytics_zoo_tpu.serving.generation import (  # noqa: E402
     GenerationEngine,
     lane_state,
 )
+from analytics_zoo_tpu.serving.generation.decoder import (  # noqa: E402
+    ExpertCounters,
+)
 
 TOL = 5e-5
 WINDOW = 8
@@ -246,6 +249,39 @@ def test_flops_model_is_not_guessed_for_a_model_it_cannot_count(lm):
                            max_context=32, registry=MetricsRegistry())
     assert eng._flops is None
     assert eng.cache.kv.shape == (4, 2, eng.cache.num_blocks * 4, 2 * 16)
+
+
+def test_expert_counters_read_the_counts_layout(lm):
+    """`ExpertCounters` against a hand-made `[expert layers, held + 2]`
+    array (tokens a held expert, then assignments to held experts, then
+    all assignments): tokens by layer and global expert id, held,
+    elsewhere, dropped, and the (layer, expert) pairs with a token —
+    the weights that dispatch had to read — by program."""
+    _, model, _ = lm                   # experts 2..5 held, layers 1 2 3
+    reg = MetricsRegistry()
+    counters = ExpertCounters.of(model, reg)
+    counts = np.array([[3, 0, 1, 0, 4, 10],
+                       [0, 0, 0, 0, 0, 10],
+                       [2, 2, 2, 1, 8, 10]])      # 1 of 8 went missing
+    counters.add(counts, "decode")
+    counters.add(counts[::-1] * 0 + [1, 0, 0, 0, 1, 2], "prefill")
+    snap = {k[len("generation_moe_"):]: v
+            for k, v in reg.snapshot().items()
+            if k.startswith("generation_moe_")}
+    assert snap["expert_tokens_total_layer1_expert2"] == 3 + 1
+    assert snap["expert_tokens_total_layer1_expert4"] == 1
+    assert snap["expert_tokens_total_layer3_expert5"] == 1
+    assert snap["expert_tokens_total_layer2_expert3"] == 0
+    assert snap["assignments_total_held"] == 12 + 3
+    assert snap["assignments_total_elsewhere"] == 18 + 3
+    assert snap["dropped_total"] == 1
+    assert snap["expert_loads_total_decode"] == 2 + 0 + 4
+    assert snap["expert_loads_total_prefill"] == 3
+    assert len(snap) == 3 * 4 + 5
+    from analytics_zoo_tpu.serving.generation import CausalLM
+    assert ExpertCounters.of(CausalLM(
+        vocab=8, hidden_size=8, n_head=2, n_block=1, intermediate_size=8,
+        max_position_len=8), reg) is None
 
 
 # --- the expert layer ---------------------------------------------------

@@ -412,12 +412,25 @@ def test_router_zero_recompile_fully_armed(lm, tmp_path):
         OrcaContext.metrics_history_interval_s = prev_hist
 
 
-def test_knobs_default_off():
-    """Both knobs ship off: a plain engine takes the legacy
-    single-device path (no mesh placement object at all)."""
-    assert OrcaContext.decode_tensor_parallel == 0
-    assert OrcaContext.serving_replicas == 0
+@pytest.mark.parametrize("build, kw", [
+    ("engine", dict(tensor_parallel=-1)),
+    ("engine", dict(speculative_decoding=True, speculative_k=0)),
+    ("engine", dict(prefix_caching=True, kv_host_tier=-1)),
+    ("engine", dict(kv_quantization="int4")),
+    ("router", dict(n_replicas=0)),
+    ("router", dict(n_replicas=-2)),
+], ids=lambda v: v if isinstance(v, str) else
+    ",".join(f"{k}={x}" for k, x in v.items()))
+def test_constructors_refuse_what_the_setters_refused(lm, build, kw):
+    """A feature's value is validated where it arrives, the
+    constructor: what a global's setter used to refuse raises the same
+    ValueError there, before anything serves."""
+    model, params = lm
+    geometry = dict(max_slots=2, block_size=8, max_context=32)
     with pytest.raises(ValueError):
-        OrcaContext.decode_tensor_parallel = -1
-    with pytest.raises(ValueError):
-        OrcaContext.serving_replicas = -2
+        if build == "engine":
+            GenerationEngine(model, params, registry=MetricsRegistry(),
+                             **geometry, **kw)
+        else:
+            ReplicaRouter.build(model, params, warmup=False,
+                                **geometry, **kw)

@@ -238,7 +238,7 @@ def test_decode_hot_loop_zero_recompile_with_watchdog(obs_dir):
     kinds = {r["kind"] for r in flight_recorder.ring_contents()}
     assert {"sched_admit", "sched_release"} <= kinds
     # and the goodput clocks decomposed the loops, buckets summing to
-    # the fenced wall (the invariant bench.py gates on)
+    # the fenced wall (the invariant the step clock is built on)
     for name in ("generation_prefill", "generation_decode"):
         t = goodput_tables()[name]
         assert t["fenced_steps"] > 0

@@ -288,10 +288,6 @@ def test_defaults_off_is_legacy_eviction_path(lm):
     and eviction frees blocks without recording a single DMA — the
     legacy path the parity suites pin is untouched."""
     model, params = lm
-    assert OrcaContext.kv_host_tier_bytes == 0
-    assert OrcaContext.router_phase_aware is False
-    with pytest.raises(ValueError):
-        OrcaContext.kv_host_tier_bytes = -1
     engine = GenerationEngine(model, params, max_slots=2, block_size=8,
                               max_context=64, prefix_caching=True)
     engine.warmup()

@@ -1,6 +1,6 @@
 """Tier-1 wiring for scripts/check_no_ad_hoc_timers.py: the build goes
 red if a new `perf_counter` stopwatch appears in the package outside
-analytics_zoo_tpu/observability/ (bench.py and tests are exempt)."""
+analytics_zoo_tpu/observability/ (tests are exempt)."""
 
 import os
 import subprocess

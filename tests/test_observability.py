@@ -147,7 +147,7 @@ def test_gauge_min_max_tracking():
 
 def test_step_clock_partition_invariant():
     """Fenced bucket totals sum to the fenced wall by construction —
-    the invariant bench.py's 5% assertion gates on."""
+    the invariant the goodput tables rest on."""
     from analytics_zoo_tpu.observability.goodput import StepClock
     clock = StepClock("unit_clock", registry=MetricsRegistry())
     for fence in (True, True, False):

@@ -2,7 +2,7 @@
 harness"): seeded arrival traces are deterministic and statistically
 sane, and `run_open_loop` classifies/score outcomes correctly against
 a synthetic submit function — the full against-a-live-server run is
-bench.py's `overload` stage (slow, not tier-1)."""
+slow and not tier-1."""
 
 import numpy as np
 import pytest

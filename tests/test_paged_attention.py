@@ -370,14 +370,13 @@ def test_zero_recompile_paged_int8_with_full_telemetry(lm_params):
     prev_slo = OrcaContext.slo_targets
     prev_mem = OrcaContext.memory_sample_interval_s
     prev_wd = OrcaContext.watchdog_deadline_s
-    prev_q = OrcaContext.kv_cache_quantization
     try:
         OrcaContext.slo_targets = {"ttft_s": 30.0, "e2e_s": 60.0}
         OrcaContext.memory_sample_interval_s = 0.0
         OrcaContext.watchdog_deadline_s = 60.0
-        OrcaContext.kv_cache_quantization = "int8"   # the knob path
         engine = GenerationEngine(model, params, max_slots=2,
-                                  block_size=8, max_context=64)
+                                  block_size=8, max_context=64,
+                                  kv_quantization="int8")
         assert engine.cache.quantization == "int8"
         assert engine.cache.kv.dtype == jnp.int8
         assert engine.watchdog is not None
@@ -400,7 +399,6 @@ def test_zero_recompile_paged_int8_with_full_telemetry(lm_params):
         OrcaContext._slo_targets = prev_slo
         OrcaContext.memory_sample_interval_s = prev_mem
         OrcaContext.watchdog_deadline_s = prev_wd
-        OrcaContext.kv_cache_quantization = prev_q
         get_registry()  # keep import used; registry state is shared
 
 

@@ -340,8 +340,6 @@ def test_prefix_caching_off_is_default_and_legacy(lm):
     bit-identical behavior is pinned by the untouched
     tests/test_generation.py suite)."""
     model, params = lm
-    assert OrcaContext.prefix_caching is False
-    assert OrcaContext.chunked_prefill is False
     engine = GenerationEngine(model, params, max_slots=2, block_size=8,
                               max_context=32)
     assert engine.prefix_cache is None

@@ -320,29 +320,24 @@ def test_spec_verify_fault_falls_back_to_decode(cyc, spec_pair):
 
 
 def test_speculation_defaults_off_and_knob_plumbs(cyc):
-    """Knob defaults off: no Speculator, no verify families, the
-    engine is the legacy engine.  OrcaContext knobs flow through
-    'auto' construction; bad k is rejected at the setter."""
+    """Speculation defaults off: no Speculator, no verify families,
+    the engine is the legacy engine.  The constructor's arguments arm
+    it; a bad k is refused there."""
     model, params, _perm = cyc
-    assert OrcaContext.speculative_decoding is False
-    assert OrcaContext.speculative_k == 4
     eng = GenerationEngine(model, params, max_slots=2, block_size=8,
                            max_context=32, registry=MetricsRegistry())
     assert eng.speculation is None
     assert eng.spec_verify_compile_count == 0
-    OrcaContext.speculative_decoding = True
-    OrcaContext.speculative_k = 2
-    try:
-        eng2 = GenerationEngine(model, params, max_slots=2,
-                                block_size=8, max_context=32,
-                                registry=MetricsRegistry())
-        assert eng2.speculation is not None
-        assert eng2.speculation.k == 2
-        with pytest.raises(ValueError):
-            OrcaContext.speculative_k = 0
-    finally:
-        OrcaContext.speculative_decoding = False
-        OrcaContext.speculative_k = 4
+    geometry = dict(max_slots=2, block_size=8, max_context=32)
+    eng2 = GenerationEngine(model, params, registry=MetricsRegistry(),
+                            speculative_decoding=True, speculative_k=2,
+                            **geometry)
+    assert eng2.speculation is not None
+    assert eng2.speculation.k == 2
+    with pytest.raises(ValueError):
+        GenerationEngine(model, params, registry=MetricsRegistry(),
+                         speculative_decoding=True, speculative_k=0,
+                         **geometry)
 
 
 # ----------------------------------------------------------------------
