@@ -139,6 +139,15 @@ class _StepRecord:
             self._laps[bucket] = self._laps.get(bucket, 0.0) + dt
         return dt
 
+    def resume(self) -> None:
+        """Leave the time since the last lap out of this step, its
+        wall too: it was another record's — a dispatch collected
+        some time after it was enqueued, a round that collected a
+        prefill's token in its middle."""
+        t = now()
+        self._t0 += t - self._t_last
+        self._t_last = t
+
     def phase(self, name: str, bucket: Optional[str] = None) -> _Phase:
         """The enclosed block as the span ``azt:<name>`` of a profiler
         trace; on leaving it, `lap(bucket)`."""

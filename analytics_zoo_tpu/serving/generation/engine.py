@@ -10,6 +10,18 @@ compiled program: steady-state serving triggers ZERO recompiles after
 per-sequence over power-of-two length buckets, so any prompt length
 hits one of O(log max_context) compiled programs.
 
+A round is collected one round late.  The step needs nothing from the
+host that depends on the round before it, and stops a lane that is
+done itself, so `step()` enqueues this round's prefills and decode
+step first and only then fetches, accounts and emits what was in
+flight before that step, in dispatch order — the previous `step()`'s
+decode round, this round's prefills: the host's part of a round runs
+while the device works on the next.  Wherever the host has to be
+exact — before a preemption, with chunked or prefix-matched prefill,
+speculation or the host tier on, an eviction, an error, out of work —
+what is in flight is collected first (`_drain`), decided from what the
+round is about to do and from nothing else.
+
 Paging: the decode step hands the pool and each lane's block table to
 the model's paged path, and the paged-attention kernel
 (ops/pallas/paged_attention.py via ops.attention.paged_decode_attention)
@@ -60,7 +72,11 @@ from __future__ import annotations
 
 import queue
 import threading
-from typing import Dict, List, Optional, Sequence as Seq, Tuple
+import time
+from collections import deque
+from contextlib import contextmanager
+from typing import (Deque, Dict, List, NamedTuple, Optional,
+                    Sequence as Seq, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +120,46 @@ from analytics_zoo_tpu.serving.generation.scheduler import (
 from analytics_zoo_tpu.serving.generation.steps import build_steps
 
 _STREAM_END = object()
+
+#: seconds the serving loop lets go of the interpreter lock after each
+#: round (`_loop`).  A round enqueued ahead is hardly ever waited for,
+#: so the loop is a thread that computes, and the interpreter takes
+#: its lock from such a thread only when a waiting one has waited a
+#: whole switch interval (5 ms): each wake-up of a handler — a request
+#: to submit, a token to write — would cost that.  After a round's
+#: collection is when they all have something to do
+TURN_S = 0.0003
+
+#: why what is in flight was collected before its time (the suffixes of
+#: `generation_pipeline_drains_total_<reason>`): an allocation that
+#: would fail, so that the preemption reads exact sequences; a
+#: copy-on-write check; a host-tier restore; a chunked or
+#: prefix-matched prefill; a verify round; a poisoned request's
+#: eviction; a step that failed; no round left to enqueue (out of work,
+#: `run_until_idle()`'s end, `stop()`)
+DRAINS = ("preempt", "cow", "host_restore", "chunk", "verify", "evict",
+          "error", "idle")
+
+
+class _Prefill(NamedTuple):
+    """A prefill enqueued and not collected: its first token is still
+    on the device."""
+    seq: Sequence
+    head: np.ndarray          # the step's columns it was given
+    bucket: int
+    tokens: int               # real tokens prefilled
+    t0: float
+    nxt: jax.Array
+    moe: list
+    rec: object               # its goodput record, laps so far
+
+
+class _Decode(NamedTuple):
+    """A decode round enqueued and not collected."""
+    lanes: Dict[int, Sequence]   # who the host put in it, by lane
+    t0: float
+    nxt: jax.Array
+    moe: list
 
 # admission policy lives in the unified AdmissionCore
 # (serving/control_plane/admission.py) — one door policy for the
@@ -342,6 +398,13 @@ class GenerationEngine:
         self._lanes = lane_state.LaneState(
             self.scheduler, seed,
             lane_state.placement(self.params, self._tp), reg)
+        #: dispatches enqueued and not collected, in dispatch order
+        #: (`_Prefill`, `_Decode`): between two `step()`s the decode
+        #: round the last one enqueued, inside one also its prefills
+        #: and its own decode round
+        self._in_flight: Deque = deque()
+        #: when the last of them was collected (`_lap`)
+        self._collected_at = 0.0
         self._lock = threading.RLock()
         self._wake = threading.Event()
         self._stop = threading.Event()
@@ -365,6 +428,18 @@ class GenerationEngine:
         self._h_decode = reg.histogram(
             "generation_decode_seconds",
             help="per-step decode latency (records = active lanes)")
+        self._c_ahead = reg.counter(
+            "generation_rounds_ahead_total",
+            help="decode rounds enqueued while the one before was "
+                 "still uncollected (over dispatch_decode_calls_total: "
+                 "the share of rounds whose host part ran beside the "
+                 "device)")
+        self._c_drains = {
+            reason: reg.counter(
+                f"generation_pipeline_drains_total_{reason}",
+                help="times what was in flight was collected before "
+                     f"the next round was enqueued: {reason}")
+            for reason in DRAINS}
         reg.gauge("generation_cache_occupancy",
                   fn=self.cache.allocator.occupancy,
                   help="fraction of KV blocks held by live sequences")
@@ -411,7 +486,11 @@ class GenerationEngine:
         memory.register_provider("kv_pool", self._kv_pool_stats)
         #: goodput decomposition of the two hot loops.  Both fence
         #: naturally (prefill fetches the sampled token, decode fetches
-        #: the token vector), so every iteration is fully accounted
+        #: the token vector), so every iteration is fully accounted: a
+        #: round's record holds the laps of one `step()` (`stage` and
+        #: `dispatch` of the round it enqueues, `fetch`, `account` and
+        #: `emit` of the round it collects), a prefill's its enqueue
+        #: and, once the round's decode step is enqueued, its collection
         self._clock_prefill = step_clock("generation_prefill")
         self._clock_decode = step_clock("generation_decode")
         #: speculative verify rounds get their own goodput track, so
@@ -715,6 +794,18 @@ class GenerationEngine:
         with tracing.phase("generation.account"):
             rec.end()
 
+    def _lap(self, t0: float) -> float:
+        """Seconds the dispatch now collected took the engine: since it
+        was enqueued at `t0`, or since the collection before it where
+        that came later — a round enqueued ahead is measured between
+        consecutive collections, which is the round's length (what
+        `retry_after_s()` reads) and lets the requests' phase ledgers
+        add up."""
+        t = now()
+        dur = t - max(t0, self._collected_at)
+        self._collected_at = t
+        return dur
+
     def _account_prefill(self, seq: Sequence, family: str, bucket: int,
                          tokens: int, t0: float, moe,
                          start: Optional[int] = None) -> None:
@@ -725,7 +816,7 @@ class GenerationEngine:
         if moe:
             self._moe.add(moe[0], "prefill")
         self._goodput_warm.add((family, bucket))
-        dur = now() - t0
+        dur = self._lap(t0)
         self._h_prefill.record(dur, tokens)
         profiling.record_work(
             family, dur, tokens=tokens,
@@ -738,17 +829,35 @@ class GenerationEngine:
                           tokens=tokens, **where, dur_s=round(dur, 6),
                           resumed=seq.n_preempted > 0)
 
+    @contextmanager
+    def _guard(self):
+        """Around a dispatch that takes the lane state: a failure
+        leaves the device's rows unknown, so all of them are sent
+        again (`LaneState.guard`) — whole, which the host can only
+        build once it is exact: what is in flight is collected
+        first."""
+        try:
+            with self._lanes.guard():
+                yield
+        except BaseException as e:
+            self._abandon("evict" if isinstance(e, PoisonedRequestError)
+                          else "error")
+            raise
+
     def _prefill_seq(self, seq: Sequence) -> None:
+        """Enqueue `seq`'s whole-prompt prefill.  Its first token is
+        collected once the round's decode step is enqueued too
+        (`_collect_prefill`)."""
         rec = self._clock_prefill.begin(force_fence=True)
-        lanes, slot = self._lanes, seq.slot
-        with tracing.phase("generation.prefill"), lanes.guard():
+        lanes = self._lanes
+        with tracing.phase("generation.prefill"), self._guard():
             with rec.phase("generation.stage", "host_input"):
                 ctx = seq.prompt + seq.generated
                 L = len(ctx)
                 bucket = self.scheduler.bucket_for(L)
                 # one upload: the prompt and the lane's row, which the
                 # program leaves in the resident state itself
-                request, row = lanes.prefill_request(seq, ctx, bucket)
+                request, head = lanes.prefill_request(seq, ctx, bucket)
             t0 = now()
             rec.cold = ("prefill", bucket) not in self._goodput_warm
             with rec.phase("generation.dispatch"):
@@ -756,11 +865,31 @@ class GenerationEngine:
                     self.params, self.cache.kv, self._kv_scale,
                     lanes.state, request)
                 self._store_kv_state(kv, scl)
+                self._enqueued(_Prefill(seq, head, bucket, L, t0, nxt,
+                                        moe, rec))
+
+    def _enqueued(self, item) -> None:
+        """`item` is in flight: its results start for the host now,
+        its sequences are that many tokens behind the device."""
+        for out in (item.nxt, *item.moe):
+            out.copy_to_host_async()
+        for seq in ([item.seq] if isinstance(item, _Prefill)
+                    else item.lanes.values()):
+            seq.in_flight += 1
+        self._in_flight.append(item)
+
+    def _collect_prefill(self, item: _Prefill) -> None:
+        seq, head, bucket, L, t0, nxt, moe, rec = item
+        # the span a prefill's enqueue has: what the host does for a
+        # prefill, here or there, is found under the one name
+        with tracing.phase("generation.prefill"):
+            rec.resume()
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
                 moe = jax.device_get(moe)
-                lanes.landed(slot, row, nxt)
             with rec.phase("generation.account"):
+                self._lanes.landed(seq.slot, head, nxt)
+                seq.in_flight -= 1
                 self._account_prefill(seq, "prefill", bucket, L, t0, moe)
             with rec.phase("generation.emit"):
                 self._emit(seq, nxt)
@@ -819,7 +948,7 @@ class GenerationEngine:
             t0 = now()
             rec.cold = ("chunk_prefill", bucket) \
                 not in self._goodput_warm
-            with rec.phase("generation.dispatch"), self._lanes.guard():
+            with rec.phase("generation.dispatch"), self._guard():
                 state = self._lanes.state
                 kv, scl, nxt, _, state["rng"], *moe = self._chunk_jit(
                     self.params, self.cache.kv, self._kv_scale,
@@ -1116,26 +1245,35 @@ class GenerationEngine:
             self._end_step(rec)
         return done
 
-    def _decode_all(self, skip: frozenset = frozenset()) -> None:
+    def _decode_all(self, lanes: Dict[int, Sequence], skip) -> bool:
+        """The round's decode step enqueued for `lanes` (none: no
+        step), then everything that was in flight before it collected:
+        the decode round the previous `step()` enqueued, this round's
+        prefills.  `skip`: the lanes that already advanced via verify.
+        Whether anything ran or was collected."""
+        if not lanes and not self._in_flight:
+            return False
         rec = self._clock_decode.begin(force_fence=True)
-        # `skip`: the lanes that already advanced via verify
-        lanes = {seq.slot: seq for seq in self.scheduler.running()
-                 if seq not in skip}
+        if not lanes:
+            self._collect(rec)
+            self._end_step(rec)
+            return True
         # the two counts ride in the span's name: a reader of the trace
         # keeps an event's name and drops its other fields
         with tracing.phase(
                 f"generation.decode[l={len(lanes)},"
                 f"w={min(len(self.scheduler.waiting), 99)}]"):
-            with self._lanes.guard():
+            with self._guard():
                 with rec.phase("generation.stage", "host_input"):
                     # the rows the scheduler changed since the last
                     # round, as one upload — or none (lane_state.py)
                     patch = self._lanes.sync(skip)
                 # fault-injection site: "poison_request" raises
                 # PoisonedRequestError BEFORE dispatch (no KV change
-                # happened; the guard has every lane's row resent, so
-                # surviving lanes replay this round untouched);
-                # "stall" wedges the loop for the watchdog
+                # happened; the guard collects what is in flight and
+                # has every lane's row resent, so surviving lanes
+                # replay this round untouched); "stall" wedges the
+                # loop for the watchdog
                 fault_point("generation.decode",
                             request_ids=[s.request_id
                                          for s in lanes.values()])
@@ -1147,35 +1285,89 @@ class GenerationEngine:
                             self.params, self.cache.kv, self._kv_scale,
                             self._lanes.state, patch)
                     self._store_kv_state(kv, scl)
-            with rec.phase("generation.fetch", "device_compute"):
-                nxt = np.asarray(nxt)     # token fetch = device fence
-                moe = jax.device_get(moe)
-            # accounting for every lane, then emission for every lane:
-            # each request's log keeps its order (decode round, token,
-            # finish), and a trace shows two spans, not two a lane
-            with rec.phase("generation.account"):
-                if moe:
-                    self._moe.add(moe[0], "decode")
-                self._goodput_warm.add("decode")
-                dur = now() - t0
-                self._h_decode.record(dur, len(lanes))
-                ctx_mean = (self._lanes.ctx_sum() / len(lanes)
-                            if lanes else 0.0)
-                self._lanes.advance(nxt)
-                profiling.record_work(
-                    "decode", dur, tokens=len(lanes),
-                    flops=(self._flops.decode(len(lanes), ctx_mean)
-                           if self._flops else 0.0))
-                for seq in lanes.values():
-                    request_log.decode_round(seq.request_id)
-                    # per-request wall-clock experience: every riding
-                    # lane waited out the whole fenced round
-                    request_log.attribute(seq.request_id,
-                                          "decode_active", dur)
-            with rec.phase("generation.emit"):
-                for i, seq in lanes.items():
-                    self._emit(seq, nxt[i])
+                    # the oldest in flight: the previous round, if any
+                    if self._in_flight \
+                            and isinstance(self._in_flight[0], _Decode):
+                        self._c_ahead.inc()
+                    self._enqueued(_Decode(lanes, t0, nxt, moe))
+            self._collect(rec, keep=1)
             self._end_step(rec)
+        return True
+
+    def _collect(self, rec=None, keep: int = 0) -> None:
+        """Fetch, account and emit what is in flight, in dispatch
+        order, but for the `keep` newest dispatches.  `rec`: the
+        goodput record a decode round's laps go to (None: one of its
+        own)."""
+        while len(self._in_flight) > keep:
+            item = self._in_flight.popleft()
+            if isinstance(item, _Prefill):
+                self._collect_prefill(item)
+                if rec is not None:
+                    rec.resume()      # that time was the prefill's
+            elif rec is not None:
+                self._collect_decode(item, rec)
+            else:
+                own = self._clock_decode.begin(force_fence=True)
+                self._collect_decode(item, own)
+                self._end_step(own)
+
+    def _collect_decode(self, item: _Decode, rec) -> None:
+        lanes, t0, nxt, moe = item
+        with rec.phase("generation.fetch", "device_compute"):
+            nxt = np.asarray(nxt)         # token fetch = device fence
+            moe = jax.device_get(moe)
+        # accounting for every lane, then emission for every lane:
+        # each request's log keeps its order (decode round, token,
+        # finish), and a trace shows two spans, not two a lane
+        with rec.phase("generation.account"):
+            if moe:
+                self._moe.add(moe[0], "decode")
+            self._goodput_warm.add("decode")
+            dur = self._lap(t0)
+            ctx_sum = self._lanes.ctx_sum()
+            live = self._lanes.advance(nxt)
+            for seq in lanes.values():
+                seq.in_flight -= 1
+            # a lane the step had stopped the round before (its last
+            # token or its `eos`, collected since) computed nothing
+            lanes = {i: seq for i, seq in lanes.items() if live[i]}
+            self._h_decode.record(dur, len(lanes))
+            ctx_mean = ctx_sum / len(lanes) if lanes else 0.0
+            profiling.record_work(
+                "decode", dur, tokens=len(lanes),
+                flops=(self._flops.decode(len(lanes), ctx_mean)
+                       if self._flops else 0.0))
+            for seq in lanes.values():
+                request_log.decode_round(seq.request_id)
+                # per-request wall-clock experience: every riding
+                # lane waited out the whole round
+                request_log.attribute(seq.request_id,
+                                      "decode_active", dur)
+        with rec.phase("generation.emit"):
+            for i, seq in lanes.items():
+                self._emit(seq, nxt[i])
+
+    def _drain(self, reason: str) -> bool:
+        """Collect everything in flight, because of `reason` (one of
+        `DRAINS`): the host is exact afterwards — every sequence's
+        tokens, every finished lane released.  Whether there was
+        anything."""
+        if not self._in_flight:
+            return False
+        self._c_drains[reason].inc()
+        self._collect()
+        return True
+
+    def _abandon(self, reason: str) -> None:
+        """`_drain` for a step that failed or an engine that stops:
+        what cannot be collected went down with it."""
+        try:
+            self._drain(reason)
+        finally:
+            self._in_flight.clear()
+            for seq in self.scheduler.slotted():
+                seq.in_flight = 0
 
     def _evict_poisoned(self, e: PoisonedRequestError) -> None:
         """Graceful degradation: a step failure attributable to ONE
@@ -1201,14 +1393,43 @@ class GenerationEngine:
         if victim is not None:
             self._finish(victim, f"error: evicted ({e})")
 
+    def _exact_round(self) -> Optional[str]:
+        """Why the round about to run needs the host exact from its
+        first line (the `DRAINS` reason), or None.  A host-tier restore
+        and a prefix match read the waiting prompts' whole context, a
+        chunk its lane's progress, the copy-on-write guard the block a
+        lane writes next, a draft the lane's own tokens: an engine
+        with one of them on collects every round before the next."""
+        if self.host_tier is not None and self.scheduler.waiting:
+            return "host_restore"
+        if self._use_chunks:
+            chunks = self.scheduler.waiting or self.scheduler.prefilling()
+            return ("chunk" if chunks or self.prefix_cache is None
+                    else "cow")
+        if self.speculation is not None:
+            return "verify"
+        return None
+
     def step(self) -> bool:
-        """One scheduling round: admit → prefill (whole prompts on the
-        legacy path; budget-bounded chunks with prefix reuse on the
-        chunk path) → grow/preempt for decode capacity (+ copy-on-
-        write un-sharing) → one decode step.  Returns whether any
-        device work ran."""
+        """One scheduling round: admit → prefill (whole prompts
+        enqueued on the legacy path; budget-bounded chunks with prefix
+        reuse on the chunk path) → grow/preempt for decode capacity
+        (+ copy-on-write un-sharing) → one decode step enqueued → and
+        only then the collection of what was in flight before it, in
+        dispatch order (the decode round the PREVIOUS `step()`
+        enqueued, this round's prefills' first tokens): fetch,
+        account, emit, finish — while the device works on this round.
+        Returns whether any device work ran or was collected.
+
+        So the tokens of the decode round a `step()` enqueues are
+        visible after the next `step()`, or after a drain: whatever
+        needs the host exact collects what is in flight first
+        (`_drain`), and `run_until_idle()` and `generate()` return
+        with nothing in flight.  Per request the order of emissions is
+        what it always was: the first token, then one a round."""
         with self._lock, tracing.phase("generation.round"):
-            did = False
+            exact = self._exact_round()
+            did = exact is not None and self._drain(exact)
             spec_budget = self.scheduler.prefill_token_budget
             with tracing.phase("generation.admit"):
                 if self.host_tier is not None:
@@ -1220,33 +1441,45 @@ class GenerationEngine:
             else:
                 for seq in admitted:
                     self._prefill_seq(seq)
-                    did = True
             with tracing.phase("generation.capacity"):
+                if self._in_flight \
+                        and self.scheduler.decode_blocks_short() > 0:
+                    # an allocation is about to fail: the eviction or
+                    # preemption it leads to reads exact sequences
+                    did = self._drain("preempt")
                 self.scheduler.ensure_decode_capacity()
                 if self.prefix_cache is not None:
                     self._apply_cow()
             advanced: set = set()
             if self.speculation is not None \
                     and self.scheduler.running():
+                # a draft is made of the lane's own tokens, the first
+                # one, of a prefill this round enqueued, among them
+                did = self._drain("verify") or did
                 advanced = self._spec_round(spec_budget)
-                did = did or bool(advanced)
-            if any(s not in advanced
-                   for s in self.scheduler.running()):
-                try:
-                    self._decode_all(skip=advanced)
-                except PoisonedRequestError as e:
-                    self._evict_poisoned(e)
+            # a lane whose last token is in flight is stopped by the
+            # step itself: it is in no further round
+            lanes = {seq.slot: seq for seq in self.scheduler.running()
+                     if seq not in advanced and not seq.spent}
+            try:
+                did = self._decode_all(lanes, advanced) or did
+            except PoisonedRequestError as e:
+                self._evict_poisoned(e)
                 did = True
             if self.watchdog is not None:
                 self.watchdog.beat()
-            return did
+            return bool(did or admitted or advanced)
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> None:
+        """`step()` until the scheduler has no work; returns with
+        nothing in flight."""
         if self.watchdog is not None:
             self.watchdog.arm()
         try:
             for _ in range(max_steps):
                 if not self.scheduler.has_work():
+                    with self._lock:
+                        self._drain("idle")
                     return
                 if not self.step():
                     stuck_ids = [s.request_id
@@ -1294,6 +1527,10 @@ class GenerationEngine:
                 # metrics_history_interval_s is set)
                 maybe_record((self.registry,))
             if not self.scheduler.has_work():
+                with self._lock:
+                    # a round enqueued for lanes that had all sampled
+                    # their `eos`: nobody waits for it
+                    self._drain("idle")
                 if self.watchdog is not None:
                     # idle is not a stall: disarm until work arrives
                     self.watchdog.disarm()
@@ -1305,6 +1542,11 @@ class GenerationEngine:
                 self.watchdog.arm()
             try:
                 did = self.step()
+                if did:
+                    # the other threads' turn, the engine's own lock
+                    # free for the requests they submit
+                    with tracing.phase("generation.wait"):
+                        time.sleep(TURN_S)
                 with self._lock, \
                         tracing.phase("generation.housekeeping"):
                     if did or not self.scheduler.waiting:
@@ -1340,6 +1582,7 @@ class GenerationEngine:
                 flight_recorder.dump("generation_step_error", exc=e,
                                      extra={"request_ids": affected})
                 with self._lock:
+                    self._abandon("error")
                     self._lanes.invalidate()
                     for seq in list(self.scheduler.slotted()):
                         self._finish(seq, f"error: {e}")
@@ -1366,8 +1609,10 @@ class GenerationEngine:
         if self._thread is not None:
             self._thread.join(timeout=5)
             self._thread = None
-        # unblock consumers of requests that will never run
+        # unblock consumers of requests that will never run, once the
+        # tokens already computed for them are out
         with self._lock:
+            self._abandon("idle")
             for seq in list(self.scheduler.slotted()):
                 self._finish(seq, "error: engine stopped")
             while self.scheduler.waiting:

@@ -42,7 +42,11 @@ decode step and clears the set.
 Invariant the engine relies on: a RUNNING sequence has KV written for
 exactly `context_len - 1` tokens — the newest sampled token is pending,
 and the next decode step feeds it, writes its KV, and samples its
-successor.  A resume-prefill re-writes KV for all `context_len` known
+successor.  With `in_flight` tokens of it enqueued and not collected,
+the device is that many positions further on: capacity is grown for
+where the device is, and everything else here (admission, preemption,
+copy-on-write, release) runs with nothing in flight for the sequences
+it reads.  A resume-prefill re-writes KV for all `context_len` known
 tokens (minus any re-matched cached prefix) and samples the next,
 restoring the same invariant.
 """
@@ -66,7 +70,8 @@ class Sequence:
                  "temperature", "top_k", "eos_id", "stream",
                  "block_table", "slot", "status", "finish_reason",
                  "n_preempted", "_admit_order", "request_id",
-                 "prefill_pos", "prefix_tokens", "priority", "spec")
+                 "prefill_pos", "prefix_tokens", "priority", "spec",
+                 "in_flight")
 
     def __init__(self, prompt, max_new_tokens: int = 32,
                  temperature: float = 0.0, top_k: int = 0,
@@ -105,10 +110,22 @@ class Sequence:
         #: survives preemption — drafting reads only the token
         #: history, which recompute-on-resume preserves.
         self.spec = None
+        #: tokens the device has been asked for and the engine has not
+        #: collected: a prefill's first, a decode round's (engine.py).
+        #: `generated` and `context_len` lag the device by as many
+        self.in_flight = 0
 
     @property
     def context_len(self) -> int:
         return len(self.prompt) + len(self.generated)
+
+    @property
+    def spent(self) -> bool:
+        """Every token it may produce is sampled or in flight: the
+        step itself stops the lane (lane_state.py), so it joins no
+        further round and needs no further block."""
+        return len(self.generated) + self.in_flight \
+            >= self.max_new_tokens
 
     def should_finish(self) -> Optional[str]:
         if self.eos_id is not None and self.generated and \
@@ -250,18 +267,32 @@ class SlotScheduler:
         self.waiting.appendleft(victim)
         return victim
 
+    def decode_blocks_short(self) -> int:
+        """Blocks `ensure_decode_capacity` would have to find beyond
+        the free list: above 0 it evicts or preempts, which needs
+        every sequence exact (the engine collects what is in flight
+        first)."""
+        bs = self.cache.block_size
+        grow = sum(
+            max(0, (s.context_len - 1 + s.in_flight) // bs + 1
+                - len(s.block_table))
+            for s in self.running() if not s.spent)
+        return grow - self.cache.allocator.available()
+
     def ensure_decode_capacity(self) -> None:
-        """Before a decode step: every running sequence writes one KV
-        entry at position context_len - 1; grow its block table (or
-        evict cold cache blocks, then preempt, newest first, under
+        """Before a decode step is enqueued: every running sequence
+        writes one KV entry at the position the device has reached —
+        context_len - 1 plus what is in flight; grow its block table
+        (or evict cold cache blocks, then preempt, newest first, under
         cache pressure — possibly the needy sequence itself)."""
         # highest class then oldest first: under pressure the newest
         # and least-important lanes yield to the oldest interactive
         for seq in sorted(self.running(),
                           key=lambda s: (s.priority, s._admit_order)):
-            if seq.slot is None:      # already preempted this round
-                continue
-            need = seq.context_len - 1  # position being written
+            if seq.slot is None or seq.spent:
+                continue              # preempted this round; or done
+            # position being written
+            need = seq.context_len - 1 + seq.in_flight
             while len(seq.block_table) <= need // self.cache.block_size:
                 got = self._alloc_with_evict(1)
                 if got is not None:

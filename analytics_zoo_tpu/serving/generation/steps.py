@@ -94,7 +94,8 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         # (lane_state.split_request): writes KV for the row's
         # `length` real tokens, samples the first new token from
         # the last real position with the state's key, and leaves
-        # the row — that token pending — in the lane's place
+        # the row — that token pending, the lane stopped if it was
+        # its only one or its `eos` — in the lane's place
         slot, row, tokens = lane_state.split_request(request, width)
         _, length, _, temperature, top_k, block_table = \
             lane_state.fields(row)
@@ -122,7 +123,9 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         # is applied: tokens [S] (each lane's pending token),
         # ctx_len [S] (= its position), block_tables [S,
         # max_blocks], active [S] lane mask.  Hands the rows back
-        # advanced and the key split, for the next round
+        # advanced — a lane that sampled its last token or its `eos`
+        # inactive, so the next round may be enqueued before this
+        # one's tokens are fetched — and the key split
         rows = lane_state.patched(lanes["rows"], patch)
         tokens, ctx_len, active, temperature, top_k, block_tables \
             = lane_state.fields(rows)

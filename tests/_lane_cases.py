@@ -9,6 +9,16 @@ parent, 8f62d1d), made by
     cd <checkout of that commit> && JAX_PLATFORMS=cpu \\
         XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
         PYTHONPATH=$PWD python <this file> > lane_state_parent_tokens.json
+
+`tests/data/round_ahead_tokens.json` is the same command's output at
+PR 33, which collects a round after the next one is enqueued: a lane
+that finishes is freed a round later, so in the six cases with no
+default-off feature on (`mixed`, `preempted`, `int8_pool`, `concat`,
+`decoder_lm`, `tp2`) a waiting request is admitted a round later, the
+dispatches split the key in another order, and the SAMPLED requests
+get other tokens (as many).  Every greedy request's tokens, every
+preemption count and the other five cases whole are the parent's, and
+tests/test_lane_state.py holds them to it.
 """
 
 from __future__ import annotations

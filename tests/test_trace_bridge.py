@@ -146,10 +146,14 @@ def test_engine_spans_land_in_a_profiler_trace(engine):
     spans = span_metrics.engine_spans(trace)
     for span in spans:
         if span.name in LEAVES:
-            assert span.parents[-1] in ("prefill", "decode"), span
+            # the last round of all is collected with none to enqueue
+            assert span.parents[-1] in ("prefill", "decode", "round"), span
             assert span.parents[0] == "round"
-        elif span.name in ("admit", "capacity", "prefill", "decode"):
+        elif span.name in ("admit", "capacity", "decode"):
             assert span.parents == ("round",), span
+        elif span.name == "prefill":
+            # enqueued in a round, collected inside its decode span
+            assert span.parents in (("round",), ("round", "decode")), span
         else:
             assert span.parents == (), span
     # one decode span a dispatch, with the lanes and the queue in its name
@@ -158,15 +162,29 @@ def test_engine_spans_land_in_a_profiler_trace(engine):
     assert all(found)
     assert [int(m.group(1)) for m in found] == lanes
     assert {int(m.group(2)) for m in found} == {0}
-    assert sum(s.name == "prefill" for s in spans) == 3
-    # each dispatch: one stage, dispatch, fetch and emit, two accounts
-    # (the planes' writes, then the goodput commit)
-    for parent in ("prefill", "decode"):
-        n = sum(s.name == parent for s in spans)
-        for leaf in LEAVES:
-            got = sum(s.name == leaf and s.parents[-1] == parent
-                      for s in spans)
-            assert got == (2 * n if leaf == "account" else n), (parent, leaf)
+    # a prefill is two spans, its enqueue and its collection; a round's
+    # decode span enqueues one step and collects the one before it, so
+    # the first collects none and the last is collected outside any:
+    # one stage, dispatch, fetch and emit a dispatch, two accounts (the
+    # planes' writes, then the goodput commit)
+    count = {}
+    for span in spans:
+        if span.name in LEAVES:
+            key = (span.parents[-1], span.name)
+            count[key] = count.get(key, 0) + 1
+    assert sum(s.name == "prefill" for s in spans) == 2 * 3
+    assert sum(s.parents[-1:] == ("decode",) and s.name == "prefill"
+               for s in spans) == 3
+    rounds = len(lanes)
+    assert count == {
+        ("prefill", "stage"): 3, ("prefill", "dispatch"): 3,
+        ("prefill", "fetch"): 3, ("prefill", "emit"): 3,
+        ("prefill", "account"): 2 * 3,
+        ("decode", "stage"): rounds, ("decode", "dispatch"): rounds,
+        ("decode", "fetch"): rounds - 1, ("decode", "emit"): rounds - 1,
+        ("decode", "account"): 2 * rounds - 1,
+        ("round", "fetch"): 1, ("round", "emit"): 1,
+        ("round", "account"): 2}
 
     # the ring behind GET /spans holds no phase
     assert not [s for s in tracing.recent_spans(4096)
