@@ -26,6 +26,11 @@ subsystem:
   RMSNorm, rotary positions, grouped query heads, window and full
   attention layers mixed, dense and expert (`ExpertLayer`) FFNs, under
   the same call contract, so the one engine serves both.
+* `HybridLM` — one sub-layer a layer, by a pattern string (hybrid.py):
+  Mamba-2 state-space mixers (`ops.ssm`), attention without positions,
+  latent squared-ReLU experts; its state-space layers' fixed-size
+  state a lane lives in a second pool beside the KV pool
+  (`kv_cache.RecurrentStatePool`), found by the lane's slot.
 * `Speculator` / `ngram_draft` — draft-free speculative decoding:
   n-gram prompt-lookup proposals verified k-at-a-time by one compiled
   step, accepted-prefix emission, free-list rollback (speculation.py;
@@ -47,12 +52,16 @@ from analytics_zoo_tpu.serving.generation.engine import (  # noqa: F401
 from analytics_zoo_tpu.serving.generation.kv_cache import (  # noqa: F401
     BlockAllocator,
     PagedKVCache,
+    RecurrentStatePool,
     dequantize_kv_tokens,
     quantize_kv_tokens,
 )
 from analytics_zoo_tpu.serving.generation.decoder import (  # noqa: F401
     DecoderLM,
     ExpertLayer,
+)
+from analytics_zoo_tpu.serving.generation.hybrid import (  # noqa: F401
+    HybridLM,
 )
 from analytics_zoo_tpu.serving.generation.model import (  # noqa: F401
     CausalLM,
@@ -75,7 +84,7 @@ from analytics_zoo_tpu.serving.generation.speculation import (  # noqa: F401,E50
 
 __all__ = ["BlockAllocator", "CausalLM", "DecoderLM", "ExpertLayer",
            "GenerationEngine",
-           "GenerationStream", "PagedKVCache", "PrefixCache",
-           "QueueFull", "RequestTooLarge", "Sequence", "SlotScheduler",
+           "GenerationStream", "HybridLM", "PagedKVCache", "PrefixCache",
+           "QueueFull", "RecurrentStatePool", "RequestTooLarge", "Sequence", "SlotScheduler",
            "SpecState", "Speculator", "dequantize_kv_tokens",
            "ngram_draft", "quantize_kv_tokens", "sample_tokens"]

@@ -70,6 +70,35 @@ def rotary(x, positions, theta: float):
     return (xf * cos + turned * sin).astype(x.dtype)
 
 
+def attend(q, k, v, *, layer: int, window=None, mask=None, ctx_k=None,
+           ctx_v=None, ctx_len=None, kv_pool=None, kv_scale=None,
+           block_tables=None, impl: str = "auto", compute_dtype=None):
+    """Attention of q [b, t, heads, d] over the new k, v [b, t, KV
+    heads, d] and whatever the call's mode says lies before them —
+    `CausalLM`'s modes: the paged pool (t = 1: the decode kernel; t > 1:
+    the verify form), a gathered context (`ctx_k`/`ctx_v` stacked over
+    layers), or nothing (a whole prompt, causal under `mask`).
+    `layer` is the pool's (and the gathered context's) row of this
+    layer, `window` a sliding layer's reach."""
+    if kv_pool is not None and q.shape[1] == 1:
+        return paged_decode_attention(
+            q[:, 0], k[:, 0], v[:, 0], kv_pool, block_tables, ctx_len,
+            layer=layer, kv_scale=kv_scale, impl=impl,
+            compute_dtype=compute_dtype, window=window)[:, None]
+    if kv_pool is not None:
+        return paged_verify_attention(
+            q, k, v, kv_pool, block_tables, ctx_len, layer=layer,
+            kv_scale=kv_scale, impl=impl, compute_dtype=compute_dtype,
+            window=window)
+    if ctx_k is not None:
+        return dot_product_attention(
+            q, k, v, compute_dtype=compute_dtype, ctx_k=ctx_k[layer],
+            ctx_v=ctx_v[layer], ctx_len=ctx_len, window=window)
+    return dot_product_attention(
+        q, k, v, mask=mask, causal=True, compute_dtype=compute_dtype,
+        window=window)
+
+
 class GatedMLP(nn.Module):
     """down(silu(gate x) * up x), no biases."""
     width: int
@@ -84,6 +113,21 @@ class GatedMLP(nn.Module):
         h = nn.silu(proj(self.width, "gate")(x)) \
             * proj(self.width, "up")(x)
         return proj(x.shape[-1], "down")(h)
+
+
+class SquaredReluMLP(nn.Module):
+    """down(relu(up x) ** 2): not gated, no biases."""
+    width: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        def proj(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype, name=name)
+        return proj(x.shape[-1], "down")(
+            jnp.square(nn.relu(proj(self.width, "up")(x))))
 
 
 class Kernel(nn.Module):
@@ -111,6 +155,15 @@ class ExpertLayer(nn.Module):
     others are left to the chips that hold them: nothing here stands
     in for those chips or their traffic.  The shared expert is whole.
 
+    Two forms of expert.  `gated` (the default): down(silu(gate x) *
+    up x) at the hidden size, the shared expert alike.  Not gated:
+    down(relu(up x) ** 2).  With `latent` > 0 the routed experts live
+    in a latent space of that width, entered (`latent_in`) and left
+    (`latent_out`) by two projections all experts share: the picked
+    experts' weighted sum is taken in the latent space and projected
+    back once; the shared expert stays at the hidden size,
+    `shared_width` wide (0: `width * num_shared_experts`).
+
     Returns (y [b, t, d], counts int32 [held + 2]): tokens computed by
     each held expert, then the assignments the router gave to held
     experts, then all it gave (real tokens * top_k).  The first `held`
@@ -122,6 +175,9 @@ class ExpertLayer(nn.Module):
     scale: float = 1.0
     norm_topk_prob: bool = True
     num_shared_experts: int = 1
+    gated: bool = True
+    latent: int = 0
+    shared_width: int = 0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -148,6 +204,14 @@ class ExpertLayer(nn.Module):
                 weight = weight / weight.sum(-1, keepdims=True)
             weight = weight * self.scale                    # [n, k]
 
+        inner, source = d, flat
+        if self.latent:
+            with jax.named_scope("moe.latent_in"):
+                inner = self.latent
+                source = nn.Dense(inner, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype,
+                                  name="latent_in")(flat.astype(self.dtype))
+
         with jax.named_scope("moe.experts"):
             local = picked - first
             mine = (local >= 0) & (local < held) & real[:, None]
@@ -158,26 +222,42 @@ class ExpertLayer(nn.Module):
             back = jnp.argsort(order)
             sizes = jnp.bincount(key, length=held + 1)[:held] \
                 .astype(jnp.int32)
-            rows = flat[order // k].astype(self.dtype)      # [n*k, d]
-            w_gate, w_up, w_down = (
-                Kernel((held,) + shape, self.param_dtype, name=name)()
-                for name, shape in (("experts_gate", (d, self.width)),
-                                    ("experts_up", (d, self.width)),
-                                    ("experts_down", (self.width, d))))
-            h = nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes)) \
-                * jax.lax.ragged_dot(rows, w_up, sizes)
-            out = jax.lax.ragged_dot(h.astype(self.dtype), w_down, sizes)
+            rows = source[order // k].astype(self.dtype)  # [n*k, inner]
+
+            def stacked(name, *shape):
+                return Kernel((held,) + shape, self.param_dtype,
+                              name=name)()
+            if self.gated:
+                h = nn.silu(jax.lax.ragged_dot(
+                    rows, stacked("experts_gate", inner, self.width),
+                    sizes)) * jax.lax.ragged_dot(
+                        rows, stacked("experts_up", inner, self.width),
+                        sizes)
+            else:
+                h = jnp.square(nn.relu(jax.lax.ragged_dot(
+                    rows, stacked("experts_up", inner, self.width),
+                    sizes)))
+            out = jax.lax.ragged_dot(
+                h.astype(self.dtype),
+                stacked("experts_down", self.width, inner), sizes)
             # back to [token, pick]; rows past the groups hold nothing
             # a sum may see
-            out = out[back].reshape(n, k, d).astype(jnp.float32)
+            out = out[back].reshape(n, k, inner).astype(jnp.float32)
             routed = jnp.where(mine[..., None],
                                out * weight[..., None], 0.0).sum(1)
 
+        if self.latent:
+            with jax.named_scope("moe.latent_out"):
+                routed = nn.Dense(d, use_bias=False, dtype=self.dtype,
+                                  param_dtype=self.param_dtype,
+                                  name="latent_out")(
+                    routed.astype(self.dtype)).astype(jnp.float32)
+
         with jax.named_scope("moe.shared"):
-            shared = GatedMLP(self.width * self.num_shared_experts,
-                              dtype=self.dtype,
-                              param_dtype=self.param_dtype,
-                              name="shared")(flat.astype(self.dtype))
+            shared = (GatedMLP if self.gated else SquaredReluMLP)(
+                self.shared_width or self.width * self.num_shared_experts,
+                dtype=self.dtype, param_dtype=self.param_dtype,
+                name="shared")(flat.astype(self.dtype))
         counts = jnp.concatenate([
             sizes, jnp.stack([mine.sum(), real.sum() * k]
                              ).astype(jnp.int32)])
@@ -346,24 +426,11 @@ class DecoderLM(nn.Module):
                     k = rotary(k, positions, self.rope_theta)
                 new_k.append(k.astype(jnp.float32))
                 new_v.append(v.astype(jnp.float32))
-                if kv_pool is not None and t == 1:
-                    a = paged_decode_attention(
-                        q[:, 0], k[:, 0], v[:, 0], kv_pool, block_tables,
-                        ctx_len, layer=i, kv_scale=kv_scale, impl=impl,
-                        compute_dtype=cd, window=window)[:, None]
-                elif kv_pool is not None:
-                    a = paged_verify_attention(
-                        q, k, v, kv_pool, block_tables, ctx_len, layer=i,
-                        kv_scale=kv_scale, impl=impl, compute_dtype=cd,
-                        window=window)
-                elif ctx_k is not None:
-                    a = dot_product_attention(
-                        q, k, v, compute_dtype=cd, ctx_k=ctx_k[i],
-                        ctx_v=ctx_v[i], ctx_len=ctx_len, window=window)
-                else:
-                    a = dot_product_attention(
-                        q, k, v, mask=additive_mask, causal=True,
-                        compute_dtype=cd, window=window)
+                a = attend(q, k, v, layer=i, window=window,
+                           mask=additive_mask, ctx_k=ctx_k, ctx_v=ctx_v,
+                           ctx_len=ctx_len, kv_pool=kv_pool,
+                           kv_scale=kv_scale, block_tables=block_tables,
+                           impl=impl, compute_dtype=cd)
                 a = dense(self.hidden_size, f"{blk}_o")(
                     a.reshape(b, t, h * hd).astype(cd))
             x = x + a.astype(jnp.float32)

@@ -100,7 +100,9 @@ from analytics_zoo_tpu.serving.generation import lane_state
 from analytics_zoo_tpu.serving.generation.decoder import ExpertCounters
 from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
+    RecurrentStatePool,
     pool_geometry,
+    state_geometry,
 )
 from analytics_zoo_tpu.resilience.faults import (
     FaultInjected,
@@ -254,20 +256,31 @@ class GenerationEngine:
                            profiling.CausalLMFlops.from_model(model))
         except (AttributeError, TypeError):
             self._flops = None
-        #: features a model cannot serve are refused here, by name,
-        #: before anything is placed (decoder.py says which and why)
-        refused = set(getattr(model, "unsupported_features",
-                              lambda: ())())
         #: tensor-parallel decode (serving/distributed/tp.py); 0 (the
         #: default) keeps the legacy single-device placement bitwise
         #: untouched
         self.tensor_parallel = int(tensor_parallel or 0)
         if self.tensor_parallel < 0:
             raise ValueError("tensor_parallel must be >= 0 (0 = off)")
-        if self.tensor_parallel > 1 and "tensor_parallel" in refused:
-            raise NotImplementedError(
-                f"{type(model).__name__} cannot be served with "
-                f"tensor_parallel={self.tensor_parallel}")
+        #: features a model cannot serve are refused here, by name and
+        #: with the model's reason, before anything is placed
+        #: (decoder.py and hybrid.py say which and why)
+        refused = getattr(model, "unsupported_features", lambda: ())()
+        asked = dict(
+            tensor_parallel=self.tensor_parallel > 1 and tensor_parallel,
+            kv_quantization=kv_quantization == "int8" and "int8",
+            prefix_caching=prefix_caching, chunked_prefill=chunked_prefill,
+            speculative_decoding=speculative_decoding,
+            kv_host_tier=kv_host_tier)
+        for feature in refused:
+            # (an empty HostKVTier is falsy, and asked for all the same)
+            if asked.get(feature) not in (None, False, 0):
+                reason = (refused[feature] if isinstance(refused, dict)
+                          else None)
+                raise NotImplementedError(
+                    f"{type(model).__name__} cannot be served with "
+                    f"{feature}={asked[feature]!r}"
+                    + (f": {reason}" if reason else ""))
         if self.tensor_parallel > 1:
             from analytics_zoo_tpu.serving.distributed.tp import (
                 TensorParallelPlacement)
@@ -290,10 +303,6 @@ class GenerationEngine:
         self.decode_attention = decode_attention
         self.kv_quantization = kv_quantization
         self._quantized = kv_quantization == "int8"
-        if self._quantized and "kv_quantization" in refused:
-            raise NotImplementedError(
-                f"{type(model).__name__} cannot be served from an int8 "
-                f"KV pool")
         #: radix-tree prompt-prefix reuse (prefix_cache.py); off (the
         #: default) keeps the engine bitwise-identical to the
         #: pre-cache behavior
@@ -394,10 +403,19 @@ class GenerationEngine:
         #: registry label ("model@version") stamped on this engine's
         #: request-log records; None outside a ModelRegistry
         self.model_label: Optional[str] = None
-        #: lane rows + sampling key, resident where the steps run
+        #: the second kind of state — what a model's state layers carry
+        #: a lane, found by its slot (kv_cache.RecurrentStatePool) — or
+        #: None for a model all of whose state is keys and values
+        recurrent = state_geometry(model)
+        self.state_pool = (RecurrentStatePool(recurrent, max_slots)
+                           if recurrent is not None else None)
+        #: lane rows + sampling key (+ that pool), resident where the
+        #: steps run
         self._lanes = lane_state.LaneState(
             self.scheduler, seed,
-            lane_state.placement(self.params, self._tp), reg)
+            lane_state.placement(self.params, self._tp), reg,
+            recurrent=(self.state_pool.zeros()
+                       if self.state_pool is not None else None))
         #: dispatches enqueued and not collected, in dispatch order
         #: (`_Prefill`, `_Decode`): between two `step()`s the decode
         #: round the last one enqueued, inside one also its prefills
@@ -452,6 +470,22 @@ class GenerationEngine:
         reg.gauge("generation_preemptions",
                   fn=lambda: self.scheduler.n_preemptions,
                   help="sequences preempted under cache pressure")
+        if self.state_pool is not None:
+            reg.gauge("generation_state_slots_in_use",
+                      fn=lambda: len(self.scheduler.slotted()),
+                      help="lanes whose recurrent state is live")
+            reg.gauge("generation_state_bytes",
+                      fn=lambda: self.state_pool.nbytes,
+                      help="bytes of the recurrent-state pool (every "
+                           "lane's, in use or not)")
+            self._c_state_resets = reg.counter(
+                "generation_state_resets_total",
+                help="admissions: a prefill that replaced a slot's "
+                     "recurrent state by the one after its prompt")
+            self._c_state_rebuilds = reg.counter(
+                "generation_state_rebuilds_total",
+                help="of those, resumes of a preempted lane: a state "
+                     "recomputed over prompt and generated tokens")
         #: the expert layers' counters, for a model that hands back
         #: counts (decoder.py), else None
         self._moe = ExpertCounters.of(model, reg)
@@ -514,7 +548,8 @@ class GenerationEngine:
             model, block_size=block_size, n_head=kv_heads,
             quantized=self._quantized,
             paged=decode_attention == "paged", width=self._lanes.width,
-            counted=self._moe is not None, tp=self._tp,
+            counted=self._moe is not None,
+            stateful=self.state_pool is not None, tp=self._tp,
             prefill_variants=self.scheduler.expected_prefill_variants(),
             verify_variants=(self.speculation.expected_verify_variants()
                              if self.speculation is not None else None))
@@ -550,6 +585,19 @@ class GenerationEngine:
             "used_bytes_logical": logical * used // nb,
             "used_bytes_physical": physical * used // nb,
         }
+
+    def recurrent_state(self, slot: int):
+        """What the state layers hold for lane `slot` as the device
+        has it once everything enqueued has run (a fetch): {"ssm": one
+        [*state shape] array a state layer, "conv": one [rows,
+        channels] a layer}, or None for a model without state layers.
+        A finished lane's stays until its slot is admitted again: the
+        state after its prompt and all but the last of its tokens."""
+        if self.state_pool is None:
+            return None
+        pool = self._lanes.state["recurrent"]
+        return {"ssm": [np.asarray(h[slot]) for h in pool["ssm"]],
+                "conv": [np.asarray(c[:, slot]) for c in pool["conv"]]}
 
     def _store_kv_state(self, kv, kv_scale) -> None:
         self.cache.kv = kv
@@ -865,6 +913,10 @@ class GenerationEngine:
                     self.params, self.cache.kv, self._kv_scale,
                     lanes.state, request)
                 self._store_kv_state(kv, scl)
+                if self.state_pool is not None:
+                    self._c_state_resets.inc()
+                    if seq.n_preempted:
+                        self._c_state_rebuilds.inc()
                 self._enqueued(_Prefill(seq, head, bucket, L, t0, nxt,
                                         moe, rec))
 

@@ -50,8 +50,10 @@ XLA fallback) — a dequantized pool never exists in HBM.
 
 from __future__ import annotations
 
+import math
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -93,6 +95,16 @@ def pool_geometry(model) -> Tuple[int, int, int]:
         return tuple(int(n) for n in geometry())
     return (int(model.n_block), int(model.n_head),
             int(model.hidden_size) // int(model.n_head))
+
+
+def state_geometry(model):
+    """What the recurrent pool holds a lane for `model`: (state
+    layers, (shape, dtype) of a layer's scan state, (shape, dtype) of
+    its convolution tail), as the model says of itself
+    (`state_geometry()`: hybrid.py), or None for a model all of whose
+    state is keys and values.  The one place it is read from a model."""
+    geometry = getattr(model, "state_geometry", None)
+    return geometry() if geometry is not None else None
 
 
 def block_view(x, block_size: int):
@@ -149,6 +161,68 @@ def write_kv(kv, kv_scale, dest, new_k, new_v):
         kv_scale = kv_scale.at[idx].set(scales)
     kv = kv.at[idx].set(rows.reshape(L, 2, n, -1).astype(kv.dtype))
     return kv, kv_scale
+
+
+class RecurrentStatePool:
+    """The second kind of state: what a state-space layer carries for a
+    lane, of a FIXED size whatever the lane's context, so it is found
+    by the lane's slot and not through a block table.  A state layer
+    holds two arrays, both functional state like `PagedKVCache.kv`
+    (the `decode` and `prefill` programs take them donated, inside the
+    lane state, and hand them back):
+
+      * "ssm": `[max_slots, *state shape]` (float32: the scan's state
+        is summed into for a lane's whole life);
+      * "conv": `[rows, max_slots, channels]`, the rows before the
+        next position the convolution still needs — the slot axis
+        second, so that the two minor axes fill whole tiles (3 rows
+        innermost-but-one would be padded to 16).
+
+    One array a layer, not one stacked over layers: a decode round
+    rewrites every live lane's state of a layer whole, which XLA does
+    in place on a donated array and would do through a slice of a
+    stacked one.  Admission needs no reset of its own — a prefill
+    starts from no state and leaves the state after the prompt in the
+    lane's slot (`admit_state`) — release needs nothing, and a
+    preempted lane's state is rebuilt by the prefill of its resume."""
+
+    def __init__(self, geometry, max_slots: int):
+        self.n_layers, (ssm, ssm_dtype), (conv, conv_dtype) = geometry
+        self.max_slots = max_slots
+        self.shapes = {
+            "ssm": ((max_slots,) + tuple(ssm), jnp.dtype(ssm_dtype)),
+            "conv": ((conv[0], max_slots) + tuple(conv[1:]),
+                     jnp.dtype(conv_dtype))}
+
+    def zeros(self):
+        """The pool, empty, on the default device."""
+        return {kind: tuple(jnp.zeros(shape, dtype)
+                            for _ in range(self.n_layers))
+                for kind, (shape, dtype) in self.shapes.items()}
+
+    @property
+    def nbytes(self) -> int:
+        return self.n_layers * sum(
+            math.prod(shape) * dtype.itemsize
+            for shape, dtype in self.shapes.values())
+
+
+def admit_state(pool, slot, fresh):
+    """`pool` with `fresh` — the state a prefill of ONE row left, in
+    the pool's form at a batch of 1 — in lane `slot`: whatever the
+    slot held before is gone (inside the jitted prefill)."""
+    zero = jnp.int32(0)
+    return {
+        "ssm": tuple(
+            jax.lax.dynamic_update_slice(
+                old, new.astype(old.dtype),
+                (slot,) + (zero,) * (old.ndim - 1))
+            for old, new in zip(pool["ssm"], fresh["ssm"])),
+        "conv": tuple(
+            jax.lax.dynamic_update_slice(
+                old, new.astype(old.dtype),
+                (zero, slot) + (zero,) * (old.ndim - 2))
+            for old, new in zip(pool["conv"], fresh["conv"]))}
 
 
 class BlockAllocator:

@@ -40,6 +40,13 @@ columns what the device held after the last dispatch COLLECTED
 With nothing in flight the two are one and the mirror is the device's
 rows.
 
+A model with state layers (hybrid.py) has a third resident entry,
+"recurrent": every lane's fixed-size state, found by the lane's slot
+like its row (kv_cache.RecurrentStatePool).  `decode` rewrites the
+live lanes' states in place and leaves a dead lane's alone; `prefill`
+leaves the state after the prompt's real tokens in the admitted lane's
+slot.  The host never reads or patches it.
+
 `chunk_prefill` and `spec_verify` keep host-built arguments: the chunk
 step takes the key out of the state and hands its successor back, a
 verify round consumes none, and the rows either advances are touched.
@@ -149,7 +156,8 @@ class LaneState:
     flight (`Sequence.in_flight`) is patched by the scheduler's columns
     alone.  The engine's loop is the single caller."""
 
-    def __init__(self, scheduler, seed: int, put, registry):
+    def __init__(self, scheduler, seed: int, put, registry,
+                 recurrent=None):
         self.scheduler = scheduler
         self._put = put           # host array -> where the steps run
         lanes = scheduler.max_slots
@@ -160,6 +168,12 @@ class LaneState:
         self._no_patch = put(np.zeros((lanes, 1 + self.width), np.int32))
         self.state = {"rows": put(self.mirror),
                       "rng": put(jax.random.PRNGKey(seed))}
+        if recurrent is not None:
+            # a model with state layers: each lane's recurrent state
+            # rides with its row (kv_cache.RecurrentStatePool), taken
+            # and handed back by the same two programs
+            self.state["recurrent"] = jax.tree_util.tree_map(
+                put, recurrent)
         self._c_rows = registry.counter(
             "generation_lane_rows_sent_total",
             help="lane rows uploaded to the device-resident lane state "

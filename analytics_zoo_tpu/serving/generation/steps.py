@@ -18,6 +18,7 @@ from analytics_zoo_tpu.observability import profiling
 from analytics_zoo_tpu.serving.generation import lane_state
 from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
 from analytics_zoo_tpu.serving.generation.kv_cache import (
+    admit_state,
     block_view,
     gather_kv,
     write_kv,
@@ -26,8 +27,8 @@ from analytics_zoo_tpu.serving.generation.sampling import sample_tokens
 
 
 def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
-                paged: bool, width: int, counted: bool, tp=None,
-                prefill_variants: int,
+                paged: bool, width: int, counted: bool,
+                stateful: bool = False, tp=None, prefill_variants: int,
                 verify_variants: Optional[int] = None) -> tuple:
     """The six program families the engine dispatches, jitted and
     registered with the dispatch ledger: (`prefill`, `chunk_prefill`,
@@ -39,7 +40,11 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
     and verify read the pool through the paged kernel (else the
     gather+concat oracle); `width`: a lane row's width
     (`LaneState.width`); `counted`: the model sows expert counts, which
-    ride back as one more result (decoder.py); `tp`: the
+    ride back as one more result (decoder.py); `stateful`: the model
+    has state layers, whose recurrent state rides in the lane state as
+    "recurrent" — `decode` hands the model every lane's and takes them
+    back advanced, `prefill` starts from none and leaves the row's in
+    its slot (hybrid.py, kv_cache.RecurrentStatePool); `tp`: the
     `TensorParallelPlacement`, or None on one device.
     `prefill_variants` / `verify_variants` are the compile budgets of
     the bucketed families (None: speculation is off)."""
@@ -59,15 +64,16 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         # counts where the model has any (decoder.py sows them;
         # `token_mask` tells it which tokens are real).  A model
         # without them is called exactly as it always was.
+        if counted or stateful:
+            kw["token_mask"] = token_mask
         if not counted:
             return model.apply({"params": params}, *args, **kw), ()
         out, state = model.apply(
-            {"params": params}, *args, token_mask=token_mask,
-            mutable=[MOE_COUNTS], **kw)
+            {"params": params}, *args, mutable=[MOE_COUNTS], **kw)
         return out, (state[MOE_COUNTS]["tokens"],)
 
     def paged_apply(params, kv, kv_scale, tokens, pos, block_tables,
-                    ctx_len, real=None):
+                    ctx_len, real=None, **kw):
         # the pool goes to the model whole, as its block view (a
         # bitcast — kv_cache.block_view), with each lane's block
         # table: the attention op gathers pool blocks by table
@@ -78,16 +84,16 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
             params, tokens, pos, token_mask=real,
             kv_pool=block_view(kv, bs),
             kv_scale=block_view(kv_scale, bs) if quantized else None,
-            block_tables=block_tables, ctx_len=ctx_len)
+            block_tables=block_tables, ctx_len=ctx_len, **kw)
 
     def concat_apply(params, kv, kv_scale, tokens, pos, tok_idx,
-                     ctx_len, real=None):
+                     ctx_len, real=None, **kw):
         # the context gathered out of the pool by token slot
         # (kv_cache.gather_kv) and attended by the concat read
         # path: the parity oracle, and the chunk step's read
         ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
         return apply(params, tokens, pos, token_mask=real,
-                     ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len)
+                     ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len, **kw)
 
     def prefill(params, kv, kv_scale, lanes, request):
         # request = [slot | the lane's row | tokens, bucket-padded]
@@ -102,7 +108,7 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         B = tokens.shape[1]
         pos = jnp.minimum(jnp.arange(B), max_pos - 1)
         token_mask = (jnp.arange(B) < length)[None]
-        (logits, new_k, new_v), counts = apply(
+        (logits, new_k, new_v, *fresh), counts = apply(
             params, tokens, pos[None], token_mask=token_mask)
         dest = block_table[jnp.arange(B) // bs] * bs \
             + jnp.arange(B) % bs
@@ -113,9 +119,14 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         rng, sub = jax.random.split(lanes["rng"])
         nxt = sample_tokens(last[None], sub, temperature[None],
                             top_k[None])[0]
-        rows = lane_state.admitted(lanes["rows"], slot, row, nxt)
-        return (kv, kv_scale, nxt, last,
-                {"rows": rows, "rng": rng}) + counts
+        out = {"rows": lane_state.admitted(lanes["rows"], slot, row, nxt),
+               "rng": rng}
+        if stateful:
+            # the state after the row's `length` real tokens, in the
+            # lane's slot: what the slot held is gone
+            out["recurrent"] = admit_state(lanes["recurrent"], slot,
+                                           fresh[0])
+        return (kv, kv_scale, nxt, last, out) + counts
 
     def decode(params, kv, kv_scale, lanes, patch):
         # ONE static-shape step for all lanes, over the resident
@@ -131,17 +142,20 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
             = lane_state.fields(rows)
         S, MB = block_tables.shape
         pos = jnp.minimum(ctx_len, max_pos - 1)
+        # every lane's recurrent state goes in and comes back: a live
+        # lane's advanced by its token, a dead lane's as it was
+        carried = {"recurrent": lanes["recurrent"]} if stateful else {}
         if paged:
-            (logits, new_k, new_v), counts = paged_apply(
+            (logits, new_k, new_v, *stepped), counts = paged_apply(
                 params, kv, kv_scale, tokens[:, None], pos[:, None],
-                block_tables, ctx_len, active[:, None])
+                block_tables, ctx_len, active[:, None], **carried)
         else:
             tok_idx = (block_tables[:, :, None] * bs
                        + jnp.arange(bs)[None, None, :]
                        ).reshape(S, -1)
-            (logits, new_k, new_v), counts = concat_apply(
+            (logits, new_k, new_v, *stepped), counts = concat_apply(
                 params, kv, kv_scale, tokens[:, None], pos[:, None],
-                tok_idx, ctx_len, active[:, None])
+                tok_idx, ctx_len, active[:, None], **carried)
         dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
             + ctx_len % bs
         dest = jnp.where(active, dest, 0)   # dead lanes → null block
@@ -150,9 +164,10 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         last = jnp.where(active[:, None], logits[:, 0], 0.0)
         rng, sub = jax.random.split(lanes["rng"])
         nxt = sample_tokens(last, sub, temperature, top_k)
-        return (kv, kv_scale, nxt, last,
-                {"rows": lane_state.advanced(rows, nxt),
-                 "rng": rng}) + counts
+        out = {"rows": lane_state.advanced(rows, nxt), "rng": rng}
+        if stateful:
+            out["recurrent"] = stepped[0]
+        return (kv, kv_scale, nxt, last, out) + counts
 
     def chunk_prefill(params, kv, kv_scale, tokens, start, length,
                       block_table, temperature, top_k, rng):

@@ -279,7 +279,10 @@ def test_spool_maybe_write_race_collapses_to_one(spool_dir):
     snapshots or inexact counter sums."""
     import threading
     prev = OrcaContext.telemetry_spool_interval_s
-    OrcaContext.telemetry_spool_interval_s = 0.01
+    # (an interval five times what a loaded suite may keep a thread
+    # off its core between the barrier and its call: at 0.01 a thread
+    # that late wrote again, rightly, and the round counted two)
+    OrcaContext.telemetry_spool_interval_s = 0.05
     local = MetricsRegistry()
     c = local.counter("fleet_race_total")
     c.inc(7)
@@ -297,7 +300,7 @@ def test_spool_maybe_write_race_collapses_to_one(spool_dir):
                 for _ in range(n_rounds):
                     barrier.wait(timeout=30)
                     results[slot].append(bool(sp.maybe_write()))
-                    time.sleep(0.012)       # next round is due again
+                    time.sleep(0.06)        # next round is due again
             except Exception as e:          # pragma: no cover
                 errors.append(e)
 
@@ -312,7 +315,7 @@ def test_spool_maybe_write_race_collapses_to_one(spool_dir):
                 "fleet_race_total"]["value"] == 7
             for doc in read_snapshots():
                 assert doc["proc"] == "hammer"   # valid JSON, whole
-            time.sleep(0.02)                     # let each round be due
+            time.sleep(0.07)                     # let each round be due
         for t in threads:
             t.join(timeout=60)
         assert not errors, errors
