@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from analytics_zoo_tpu.ops import grouped
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention,
     paged_decode_attention,
@@ -149,8 +150,9 @@ class ExpertLayer(nn.Module):
     own scores, normalised and scaled.  Of the `top_k * tokens`
     assignments those that name one of the `experts_held` = (first id,
     count) experts are computed here — grouped by expert with a stable
-    sort and multiplied through `jax.lax.ragged_dot`, every one of
-    them, at 1 token or 1,024: the row buffer holds all
+    sort and multiplied through `ops.grouped.grouped_matmul` (the
+    Pallas grouped kernel on a TPU, `jax.lax.ragged_dot` elsewhere),
+    every one of them, at 1 token or 1,024: the row buffer holds all
     `top_k * tokens` assignments, so none can be dropped — and the
     others are left to the chips that hold them: nothing here stands
     in for those chips or their traffic.  The shared expert is whole.
@@ -228,20 +230,20 @@ class ExpertLayer(nn.Module):
                 return Kernel((held,) + shape, self.param_dtype,
                               name=name)()
             if self.gated:
-                h = nn.silu(jax.lax.ragged_dot(
+                h = nn.silu(grouped.grouped_matmul(
                     rows, stacked("experts_gate", inner, self.width),
-                    sizes)) * jax.lax.ragged_dot(
+                    sizes)) * grouped.grouped_matmul(
                         rows, stacked("experts_up", inner, self.width),
                         sizes)
             else:
-                h = jnp.square(nn.relu(jax.lax.ragged_dot(
+                h = jnp.square(nn.relu(grouped.grouped_matmul(
                     rows, stacked("experts_up", inner, self.width),
                     sizes)))
-            out = jax.lax.ragged_dot(
+            out = grouped.grouped_matmul(
                 h.astype(self.dtype),
                 stacked("experts_down", self.width, inner), sizes)
             # back to [token, pick]; rows past the groups hold nothing
-            # a sum may see
+            # a sum may see (the kernel leaves them unwritten)
             out = out[back].reshape(n, k, inner).astype(jnp.float32)
             routed = jnp.where(mine[..., None],
                                out * weight[..., None], 0.0).sum(1)
@@ -465,7 +467,10 @@ class ExpertCounters:
     labels: they ride in the name), where the router's assignments
     went, and the ones lost on the way.  The one reader of the
     `[expert layers, held + 2]` array a `DecoderLM` sows
-    (`ExpertLayer`'s counts, stacked)."""
+    (`ExpertLayer`'s counts, stacked).  Beside them, the grouped
+    products built since the engine was (`ops.grouped.BUILT`: a
+    program's are built when it is first traced), by the path each
+    took and the row tile the kernel chose."""
 
     @classmethod
     def of(cls, model, registry) -> Optional["ExpertCounters"]:
@@ -476,6 +481,8 @@ class ExpertCounters:
 
     def __init__(self, model: DecoderLM, reg):
         first, held = model.held
+        self._reg = reg
+        self._built = grouped.built()
         self._tokens = [
             [reg.counter(
                 f"generation_moe_expert_tokens_total_layer{layer}"
@@ -502,6 +509,7 @@ class ExpertCounters:
     def add(self, counts, program: str) -> None:
         """Add one dispatch's fetched counts.  `program`: "prefill"
         (chunks too) or "decode" (verify rounds too)."""
+        self._count_built()
         counts = np.asarray(counts)
         held = counts.shape[1] - 2
         self._loads[program].inc(int((counts[:, :held] > 0).sum()))
@@ -513,3 +521,16 @@ class ExpertCounters:
         self._held.inc(to_held)
         self._elsewhere.inc(int(counts[:, held + 1].sum()) - to_held)
         self._dropped.inc(to_held - int(counts[:, :held].sum()))
+
+    def _count_built(self) -> None:
+        built = grouped.built()
+        for (path, tile), n in (built - self._built).items():
+            self._reg.counter(
+                f"generation_grouped_products_total_{path}",
+                help="grouped products in the programs built, by "
+                     "the path the dispatcher took").inc(n)
+            if path == grouped.KERNEL:
+                self._reg.counter(
+                    f"generation_grouped_kernel_tile_rows_total_{tile}",
+                    help="of the kernel's, by the row tile").inc(n)
+        self._built = built

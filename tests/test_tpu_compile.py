@@ -260,6 +260,118 @@ def test_tuning_table_block_gathers_compile_for_v5e(topo):
         _assert_kernel_compiles(build, _one_chip(topo), key)
 
 
+def test_tuning_table_grouped_matmuls_compile_for_v5e(topo):
+    """Every `grouped_matmul|tpu|*` row of the table compiles at the
+    shape it was measured at (its `shape`: the key holds the same dims
+    rounded up to powers of two), with the Pallas kernel in it."""
+    import json
+
+    from analytics_zoo_tpu.ops import grouped, tuning
+    with open(tuning.DEFAULT_TABLE_PATH) as f:
+        entries = json.load(f)["entries"]
+    rows = {k: v for k, v in entries.items()
+            if k.startswith("grouped_matmul|tpu|")}
+    assert rows, "no grouped_matmul|tpu rows in the default table"
+    for key, row in rows.items():
+        dtype = jnp.dtype(key.split("|")[2])
+        shape = row["shape"]
+        assert tuning.make_key("grouped_matmul", shape, dtype,
+                               platform="tpu") == key
+        m, g, k, n = (shape[d] for d in "mgkn")
+
+        def build(place, m=m, g=g, k=k, n=n, dtype=dtype, row=row):
+            def product(rows, kernels, sizes):
+                return grouped.grouped_matmul(
+                    rows, kernels, sizes, impl="kernel", interpret=False,
+                    **row["config"])
+            return product, (place((m, k), dtype), place((g, k, n), dtype),
+                             place((g,), jnp.int32))
+        _assert_kernel_compiles(build, _one_chip(topo), key)
+
+
+def _expert_models():
+    """One expert layer of each expert configuration of the benchmark
+    at its published widths (`kexaone_236b_ep8_serve`: gated experts
+    at the hidden size; `nemotron3_super_120b_ep4_serve`: un-gated, in
+    a latent space), with the lanes its cell decodes."""
+    from analytics_zoo_tpu.serving.generation import DecoderLM
+    from analytics_zoo_tpu.serving.generation.hybrid import HybridLM
+    bf16 = dict(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    return {
+        "kexaone": (64, DecoderLM(
+            vocab=19200, hidden_size=6144, n_head=64, n_kv_head=8,
+            head_dim=128, layer_types=("full_attention",),
+            mlp_layer_types=("sparse",), intermediate_size=18432,
+            moe_intermediate_size=2048, num_experts=128,
+            num_experts_per_tok=8, experts_held=(0, 16),
+            routed_scaling_factor=2.5, **bf16)),
+        "nemotron3": (128, HybridLM(
+            vocab=32768, hidden_size=4096, pattern="*E", n_head=32,
+            n_kv_head=2, head_dim=128, mamba_num_heads=128,
+            mamba_head_dim=64, n_groups=8, ssm_state_size=128,
+            conv_kernel=4, chunk_size=128, moe_intermediate_size=2688,
+            moe_latent_size=1024,
+            moe_shared_expert_intermediate_size=5376, num_experts=512,
+            num_experts_per_tok=22, experts_held=(0, 128),
+            routed_scaling_factor=5.0, norm_eps=1e-5,
+            max_position_len=262144, **bf16)),
+    }
+
+
+@pytest.mark.parametrize("config", ["kexaone", "nemotron3"])
+def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
+    """The `decode` program of each expert configuration, lowered for
+    the described v5e from shapes alone (no weight is made): every
+    grouped product is the Pallas kernel — no `ragged-dot` left, whose
+    TPU lowering multiplies a whole row tile for every group (PERF.md
+    section 6, PR 36) — and no stacked expert kernel is copied on the
+    way to it."""
+    import re
+
+    from analytics_zoo_tpu.ops import grouped
+    from analytics_zoo_tpu.serving.generation import lane_state, steps
+    from analytics_zoo_tpu.serving.generation.kv_cache import (
+        PagedKVCache, pool_geometry)
+    lanes_n, model = _expert_models()[config]
+    # the dispatchers ask the backend whether to take their Pallas
+    # form, the builder whether to donate: here it is a TPU
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, kv_heads, head_dim = pool_geometry(model)
+    bs, blocks = 16, 64
+    width = lane_state.TABLE + blocks
+    decode = steps.build_steps(
+        model, block_size=bs, n_head=kv_heads, quantized=False,
+        paged=True, width=width, counted=True, prefill_variants=3)[2]
+    place = _one_chip(topo)
+    params, kv, key = jax.eval_shape(lambda: (
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                   jnp.arange(8)[None])["params"],
+        PagedKVCache(layers, 1 + lanes_n * blocks, bs, kv_heads,
+                     head_dim, dtype=jnp.bfloat16).kv,
+        jax.random.PRNGKey(0)))
+    args = jax.tree_util.tree_map(
+        lambda x: place(x.shape, x.dtype),
+        (params, kv, jax.ShapeDtypeStruct((1,), jnp.float32),
+         {"rows": jax.ShapeDtypeStruct((lanes_n, width), jnp.int32),
+          "rng": key},
+         jax.ShapeDtypeStruct((lanes_n, 1 + width), jnp.int32)))
+    built = dict(grouped.BUILT)
+    text = decode.fn.lower(*args).compile().as_text()
+    took = {k: v - built.get(k, 0) for k, v in grouped.BUILT.items()
+            if v - built.get(k, 0)}
+    assert took and all(path == grouped.KERNEL for path, _ in took), took
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+    stacked = {leaf.shape for leaf in jax.tree_util.tree_leaves(params)
+               if leaf.ndim == 3 and leaf.shape[0] == model.held[1]}
+    assert len(stacked) == 2, stacked
+    shapes = "|".join(re.escape("bf16[" + ",".join(map(str, s)) + "]")
+                      for s in stacked)
+    copies = [ln.strip()[:200] for ln in text.splitlines()
+              if re.search(rf"= (?:{shapes})\S* copy\(", ln)]
+    assert not copies, f"stacked expert kernels copied: {copies}"
+
+
 def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
     """The engine's own `decode` and 1024-bucket `prefill` programs at
     `gpt2_small_serve`'s widths and full pool (32 lanes x 64 blocks of
