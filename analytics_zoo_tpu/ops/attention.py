@@ -21,6 +21,15 @@ paged context in one call.  It reuses the decode path's XLA fallback
 (block-table gather + the `dot_product_attention` ctx read path) on
 every backend today — the dedicated q_len>1 Pallas kernel is future
 TPU-round work, and the gather path is what the CPU parity tests pin.
+
+`latent_decode_attention` is the decode path of a model that caches ONE
+row a token (latent attention in its absorbed form,
+serving/generation/decoder.py): each lane's heads all read the same
+cached rows, as key over the whole row and as value over its leading
+columns.  The same kind of dispatch point: the Pallas latent kernel on
+a TPU, the gather by table and the same mathematics in XLA elsewhere.
+`latent_paged_context` is the gather alone, for the forms that expand
+the rows (verify).
 """
 
 from __future__ import annotations
@@ -36,7 +45,8 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
                           dropout_rate: float = 0.0, dropout_rng=None,
                           compute_dtype=jnp.bfloat16,
                           ctx_k=None, ctx_v=None, ctx_len=None,
-                          window: Optional[int] = None):
+                          window: Optional[int] = None,
+                          scale: Optional[float] = None):
     """q, k, v: [batch, time, heads, head_dim] (BTHD).  `mask` is an
     additive float mask broadcastable to [batch, heads, q_time, k_time].
     Returns [batch, time, heads, head_dim].
@@ -60,8 +70,24 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
     p - j < w).  Either one takes `_grouped_attention`, the same
     mathematics with the KV heads kept as an axis of their own; plain
     multi-head attention without a window runs the code below as it
-    always has."""
+    always has.
+
+    `scale` multiplies the scores in place of head_dim ** -0.5 (a
+    rotary scaling's factor rides on it).  A key wider than the value
+    (latent attention expanded: a rotary part behind the key alone)
+    takes `_blocked_attention`: the same mathematics in blocks of
+    query rows, each over the keys it can see, so that no [t, t] array
+    of scores exists at any prompt length."""
     b, t, h, d = q.shape
+    if v.shape[-1] != d:
+        if dropout_rate > 0.0 or window is not None or k.shape[2] != h:
+            raise ValueError("a key wider than the value comes with "
+                             "neither dropout, a window nor grouped "
+                             "heads")
+        return _blocked_attention(
+            q, k, v, mask=mask, causal=causal,
+            compute_dtype=compute_dtype, ctx_k=ctx_k, ctx_v=ctx_v,
+            ctx_len=ctx_len, scale=scale)
     if k.shape[2] != h or window is not None:
         if dropout_rate > 0.0:
             raise ValueError("dropout is not supported with grouped "
@@ -69,8 +95,9 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
         return _grouped_attention(
             q, k, v, mask=mask, causal=causal,
             compute_dtype=compute_dtype, ctx_k=ctx_k, ctx_v=ctx_v,
-            ctx_len=ctx_len, window=window)
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+            ctx_len=ctx_len, window=window, scale=scale)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q = q.astype(compute_dtype)
     k = k.astype(compute_dtype)
     v = v.astype(compute_dtype)
@@ -114,7 +141,7 @@ def dot_product_attention(q, k, v, mask=None, causal: bool = False,
 
 
 def _grouped_attention(q, k, v, *, mask, causal, compute_dtype, ctx_k,
-                       ctx_v, ctx_len, window):
+                       ctx_v, ctx_len, window, scale=None):
     """`dot_product_attention` for g KV heads under h = g * r query
     heads and/or a window: q [b, t, h, d], k/v [b, t, g, d] (and
     ctx_k/ctx_v [b, c, g, d]).  Scores are [b, g, r, q, k] — a K or V
@@ -125,7 +152,8 @@ def _grouped_attention(q, k, v, *, mask, causal, compute_dtype, ctx_k,
         raise ValueError(f"{h} query heads do not divide over {g} KV "
                          f"heads")
     r = h // g
-    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     q = q.astype(compute_dtype).reshape(b, t, g, r, d)
     keys, vals = k.astype(compute_dtype), v.astype(compute_dtype)
     if ctx_k is not None:
@@ -159,6 +187,61 @@ def _grouped_attention(q, k, v, *, mask, causal, compute_dtype, ctx_k,
     return out.reshape(b, t, h, d).astype(jnp.float32)
 
 
+#: query rows a block of `_blocked_attention` holds: at 64 heads and
+#: 4,096 keys its float32 scores are 0.54 GB
+QUERY_BLOCK = 512
+
+
+def _blocked_attention(q, k, v, *, mask, causal, compute_dtype, ctx_k,
+                       ctx_v, ctx_len, scale, q_block: int = QUERY_BLOCK):
+    """`dot_product_attention` for keys [b, t, h, dk] beside values
+    [b, t, h, dv], dk != dv, in blocks of `q_block` query rows.  Without
+    a context a causal block reads the keys up to its own last row and
+    no further (half the products of the whole square); with one
+    (`ctx_k` [b, c, h, dk], `ctx_v` [b, c, h, dv], `ctx_len`) every
+    block reads the cached columns, masked past `ctx_len`, and the new
+    ones up to its last row.  Scores and softmax in float32."""
+    b, t, h, dk = q.shape
+    if scale is None:
+        scale = dk ** -0.5
+    q = q.astype(compute_dtype)
+    keys, vals = k.astype(compute_dtype), v.astype(compute_dtype)
+    c = 0
+    if ctx_k is not None:
+        c = ctx_k.shape[1]
+        ctx_len = jnp.asarray(ctx_len, jnp.int32)
+        keys = jnp.concatenate([ctx_k.astype(compute_dtype), keys], 1)
+        vals = jnp.concatenate([ctx_v.astype(compute_dtype), vals], 1)
+        causal, mask = True, None
+    out = []
+    for start in range(0, t, q_block):
+        stop = min(start + q_block, t)
+        seen = c + stop if causal else c + t
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q[:, start:stop], keys[:, :seen],
+            preferred_element_type=jnp.float32) * scale  # [b, h, rows, seen]
+        col = jnp.arange(seen)[None, :]
+        rows = jnp.arange(start, stop)[None, :]
+        if ctx_k is not None:
+            # cached column j is position j; new column c + j is the
+            # token at ctx_len + j
+            valid = (((col < c) & (col < ctx_len[:, None]))[:, None, :]
+                     | ((col >= c)[:, None, :]
+                        & (col - c <= rows[..., None])))
+        elif causal:
+            valid = col[:, None, :] <= rows[..., None]
+        else:
+            valid = jnp.ones((1, stop - start, seen), bool)
+        scores = jnp.where(valid[:, None], scores, -1e9)
+        if mask is not None:
+            scores = scores + jnp.asarray(mask)[..., :seen]
+        probs = jax.nn.softmax(scores, axis=-1)
+        out.append(jnp.einsum("bhqk,bkhd->bqhd",
+                              probs.astype(compute_dtype), vals[:, :seen]))
+    out = out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
+    return out.astype(jnp.float32)
+
+
 def _paged_context(kv_pool, kv_scale, layer, which, block_tables, h):
     """Every lane's table blocks of `layer`'s K (which=0) or V (1),
     gathered out of the pool's block view as a [S, C, h, d] context and
@@ -172,6 +255,18 @@ def _paged_context(kv_pool, kv_scale, layer, which, block_tables, h):
         ctx = (ctx.astype(jnp.float32)
                * scale.reshape(s, mb * bs)[:, :, None, None])
     return ctx
+
+
+def _resolved(impl: str) -> str:
+    """`impl` with "auto" settled: the Pallas kernel on a TPU, the XLA
+    form elsewhere."""
+    if impl != "auto":
+        return impl
+    try:
+        platform = jax.default_backend()
+    except Exception:
+        platform = "cpu"
+    return "pallas" if platform == "tpu" else "xla"
 
 
 def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
@@ -220,12 +315,7 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
     s, h, d = q.shape
     g = new_k.shape[1]                 # KV heads (= h without grouping)
     bs = kv_pool.shape[3]
-    if impl == "auto":
-        try:
-            platform = jax.default_backend()
-        except Exception:
-            platform = "cpu"
-        impl = "pallas" if platform == "tpu" else "xla"
+    impl = _resolved(impl)
     if impl == "xla":
         out = dot_product_attention(
             q[:, None], new_k[:, None], new_v[:, None],
@@ -316,3 +406,79 @@ def paged_verify_attention(q, new_k, new_v, kv_pool, block_tables,
         ctx_v=_paged_context(kv_pool, kv_scale, layer, 1, block_tables,
                              g),
         ctx_len=ctx_len, window=window)
+
+
+def latent_paged_context(kv_pool, block_tables, *, layer: int, width: int):
+    """Every lane's table blocks of `layer` of a latent pool (its block
+    view, [n_layers, 1, num_blocks, block_size, columns as stored])
+    gathered as cached rows [S, C, width]: the read of the forms that
+    expand the rows (verify).  The stored padding is cut off what was
+    gathered, never of the pool."""
+    blocks = kv_pool[layer, 0, block_tables]          # [S, MB, bs, W]
+    s, mb, bs, _ = blocks.shape
+    return blocks.reshape(s, mb * bs, -1)[..., :width]
+
+
+def latent_decode_attention(q_abs, new_row, kv_pool, block_tables, ctx_len,
+                            *, layer: int, value_width: int, scale: float,
+                            impl: str = "auto",
+                            block_gather: Optional[int] = None,
+                            interpret: Optional[bool] = None):
+    """Decode-step attention of one new token a lane over a pool of ONE
+    row a token — latent attention in its absorbed form
+    (serving/generation/decoder.py, docs/generation.md).
+
+    q_abs: [S, heads, width] — each head's query in the cached row's
+    space (the key up-projection absorbed into the no-position part,
+    the rotated part behind it).  new_row: [S, width], the pending
+    token's own row (it attends to itself).  kv_pool: the latent pool's
+    block view [n_layers, 1, num_blocks, block_size, columns as stored
+    >= width], `layer` (static) the layer read; block_tables / ctx_len
+    as `paged_decode_attention`'s.  For head h and cached row c_j:
+
+        s_j = scale * q_abs[h] . c_j
+        out[h] = sum_j softmax(s)_j c_j[:value_width]
+
+    — one row read once serves as key (all of it) and as value (its
+    first `value_width` columns) for every head.  Returns [S, heads,
+    value_width] float32; the caller projects it up to the value heads.
+
+    impl: "auto" (the Pallas latent kernel on a TPU, XLA elsewhere) |
+    "pallas" | "xla".  The XLA form gathers each lane's rows by table
+    and computes the same sums with a float32 softmax; it is what the
+    kernel is held to in the interpreter (`interpret=True`).
+    `block_gather`: pool blocks a grid step of the kernel reads (None:
+    the kernel's default)."""
+    s, h, width = q_abs.shape
+    stored = kv_pool.shape[-1]
+    impl = _resolved(impl)
+    if impl == "xla":
+        cd = q_abs.dtype
+        ctx = latent_paged_context(kv_pool, block_tables, layer=layer,
+                                   width=width).astype(cd)   # [S, C, W]
+        rows = jnp.concatenate([ctx, new_row[:, None].astype(cd)], 1)
+        scores = jnp.einsum("shw,scw->shc", q_abs, rows,
+                            preferred_element_type=jnp.float32) * scale
+        c = ctx.shape[1]
+        col = jnp.arange(c + 1)[None, :]
+        valid = (col < jnp.asarray(ctx_len, jnp.int32)[:, None]) \
+            | (col == c)
+        probs = jax.nn.softmax(
+            jnp.where(valid[:, None], scores, -1e9), axis=-1)
+        out = jnp.einsum("shc,scv->shv", probs.astype(cd),
+                         rows[..., :value_width])
+        return out.astype(jnp.float32)
+    if impl != "pallas":
+        raise ValueError(f"unknown latent_decode_attention impl "
+                         f"{impl!r}; use 'auto', 'pallas' or 'xla'")
+    from analytics_zoo_tpu.ops.pallas.paged_attention import (
+        latent_decode_pallas)
+    if interpret is None:
+        interpret = jax.devices()[0].platform == "cpu"
+    # the kernel's rows are the pool's: padded to the stored columns
+    # (the pool's own padding is zeros: a query's meets nothing)
+    pad = ((0, 0),) * 2 + ((0, stored - width),)
+    return latent_decode_pallas(
+        jnp.pad(q_abs, pad), jnp.pad(new_row, pad[1:]), kv_pool,
+        block_tables, ctx_len, layer=layer, value_width=value_width,
+        scale=scale, block_gather=block_gather, interpret=interpret)

@@ -473,6 +473,132 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     )(block_tables, ctx_len, *args)[:, 0]
 
 
+#: pool blocks a grid step of the latent kernel reads where the caller
+#: names none.  Measured at `sarvam_105b_ep8_serve`'s decode shapes (128
+#: lanes, contexts about 2,900, tables of 320 blocks of 16 rows; my chip
+#: run, PR 37): 5 layers take 24.5 / 16.5 / 14.0 / 12.3 ms at 4 / 8 / 16
+#: / 32 — a grid step costs some 0.3 us and each 20 KB block DMA some
+#: 0.05 us whatever the width, so fewer, wider steps win until the tile
+#: (32 blocks: 512 rows, 0.66 MB a buffer) crowds VMEM
+LATENT_BLOCK_GATHER = 32
+
+
+def _dot_pool(x, w, contract_w: int):
+    """x [r, k] (float32 or the pool's dtype) times a pool tile `w`,
+    contracting w's axis `contract_w`, to float32: ONE MXU pass in the
+    pool's dtype where that is bfloat16 (x rounded to it, as the XLA
+    form rounds its probabilities), float32 at the highest precision
+    for any other pool (the float32 pools of the CPU tests)."""
+    dims = (((1,), (contract_w,)), ((), ()))
+    if w.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(x.astype(jnp.bfloat16), w, dims,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(x.astype(jnp.float32),
+                               w.astype(jnp.float32), dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _kernel_latent(tbl_ref, cl_ref, q_ref, new_ref, *rest, g: int, bs: int,
+                   num_j: int, vw: int, scale: float):
+    # one row a token: q_ref [h, w] the lane's heads in the cached
+    # row's space, new_ref [1, w] the pending token's own row.  rest: g
+    # gathered blocks [bs, w] of the ONE pool row kind, then o_ref
+    # [h, vw] and the o [h, vw] / m / l [h, 128] VMEM scratch carried
+    # across the block axis.  A staged tile is read once and used
+    # twice: whole as the keys of all h heads, its first vw columns
+    # (whole lanes) as their values.
+    rest = list(rest)
+    blocks = [rest.pop(0) for _ in range(g)]
+    o_ref, o_scr, m_scr, l_scr = rest
+    s_idx = pl.program_id(0)
+    j = pl.program_id(1)
+    n = g * bs
+    cl = cl_ref[s_idx]
+    base = j * n
+
+    @pl.when(j == 0)
+    def _init():
+        # the new token attends to itself: see `_kernel`
+        own = new_ref[...].astype(jnp.float32)               # [1, w]
+        s_self = (q_ref[...].astype(jnp.float32) * own).sum(
+            axis=1, keepdims=True) * scale                   # [h, 1]
+        m_scr[...] = jnp.broadcast_to(s_self, m_scr.shape)
+        l_scr[...] = jnp.ones_like(l_scr)
+        o_scr[...] = jnp.broadcast_to(own[:, :vw], o_scr.shape)
+
+    # a dead lane's table names the null block and its context is 0:
+    # every group is past it, and it hands back its own row
+    @pl.when(base < cl)
+    def _compute():
+        rows = _rows([blk[...] for blk in blocks])           # [n, w]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+        valid = pos < cl                                     # [1, n]
+        s = _dot_pool(q_ref[...], rows, 1) * scale           # [h, n]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:, 0:1]                               # [h, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        o_scr[...] = o_scr[...] * alpha + _dot_pool(p, rows[:, :vw], 0)
+
+    @pl.when(j == num_j - 1)
+    def _finalize():
+        o_ref[...] = (o_scr[...] / l_scr[:, 0:1]).astype(o_ref.dtype)
+
+
+def latent_decode_pallas(q, new_row, kv_pool, block_tables, ctx_len, *,
+                         layer: int, value_width: int, scale: float,
+                         block_gather: Optional[int] = None,
+                         interpret: bool = False):
+    """The raw latent kernel call (dispatch through
+    `ops.attention.latent_decode_attention`).
+
+    q: [S, h, w] — lane S's heads in the cached row's space, w the
+    pool's columns as stored (padded with zeros past the row's own);
+    new_row: [S, w], the pending token's row.  kv_pool: [n_layers, 1,
+    num_blocks, block_size, w] — the whole latent pool (block 0 = the
+    null block), `layer` (static) picked in the index map.
+    block_tables [S, max_blocks] int32, ctx_len [S] int32 as
+    `paged_decode_pallas`'s.  Returns [S, h, value_width] float32: per
+    head, the softmax over scale * q . row of the cached rows and the
+    token's own, times the rows' first `value_width` columns."""
+    s, h, w = q.shape
+    bs = kv_pool.shape[3]
+    mb = block_tables.shape[1]
+    g = max(1, min(int(block_gather or LATENT_BLOCK_GATHER), mb))
+    if mb % g:
+        pad = g - mb % g
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+        mb += pad
+    num_j = mb // g
+    heads = pl.BlockSpec((None, h, w), lambda si, j, tbl, cl: (si, 0, 0))
+    own = pl.BlockSpec((None, 1, w), lambda si, j, tbl, cl: (si, 0, 0))
+    pool = [pl.BlockSpec(
+        (None, None, None, bs, w),
+        lambda si, j, tbl, cl, i=i: (layer, 0, tbl[si, j * g + i], 0, 0))
+        for i in range(g)]
+    return pl.pallas_call(
+        partial(_kernel_latent, g=g, bs=bs, num_j=num_j, vw=value_width,
+                scale=float(scale)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(s, num_j),
+            in_specs=[heads, own] + pool,
+            out_specs=pl.BlockSpec((None, h, value_width),
+                                   lambda si, j, tbl, cl: (si, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((h, value_width), jnp.float32),
+                pltpu.VMEM((h, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((h, _STAT_LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((s, h, value_width), jnp.float32),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), jnp.asarray(ctx_len, jnp.int32),
+      q, new_row[:, None], *[kv_pool] * g)
+
+
 # ----------------------------------------------------------------------
 # autotuning (fwd-only key family "paged_decode")
 # ----------------------------------------------------------------------

@@ -10,7 +10,8 @@ engine: one block vocabulary, each layer's kinds read from lists.
     `full_attention` (every earlier position, no rotary); grouped
     queries (`n_head` query heads over `n_kv_head` KV heads of
     `head_dim`, so heads * head_dim need not be the hidden size) with an
-    RMSNorm over each head of q and k;
+    RMSNorm over each head of q and k; or, in every layer of a model,
+    `latent_attention` (below);
   * FFN kind per layer, from `mlp_layer_types`: `dense` (gated SiLU at
     `intermediate_size`) or `sparse` (`ExpertLayer`: a router over
     `num_experts`, the `experts_held` slice of them computed here, and
@@ -27,6 +28,33 @@ expert layers' counts are sown under `MOE_COUNTS` when the caller
 makes that collection mutable, and `ExpertCounters` is the one reader
 of their layout.
 
+Latent attention (`kv_lora_rank` > 0; DeepSeek-V2's, as `sarvam_mla`
+configures it).  A token caches ONE row a layer, `[c | k_r]`: a latent
+`c` of `kv_lora_rank` columns (RMSNorm'd) that every head's key and
+value are projections of, and one rotary key `k_r` of
+`qk_rope_head_dim` columns (rotated) that all heads share.  Query head
+h is `[q_nope | q_rope]` (`qk_nope_head_dim | qk_rope_head_dim`,
+RMSNorm over the whole head, then the rotation of `q_rope`); with
+`W_kvb,h = [W_UK,h ; W_UV,h]` the up-projection of head h,
+
+    k_h,j = [W_UK,h c_j | k_r,j]     v_h,j = W_UV,h c_j
+    s_h,ij = scale * q_h,i . k_h,j   o_h,i = sum_j softmax(s)_ij v_h,j
+
+Two forms of that one mathematics, chosen by the call's shape:
+EXPANDED where many new tokens meet the cache (a prompt, a chunk or a
+verify window over cached rows, the concat oracle): every row in sight
+is up-projected and `ops.attention.dot_product_attention` runs over
+keys 192 wide beside values 128 wide, in blocks of query rows;
+ABSORBED where one token a lane meets the pool (`decode`):
+`q'_h = W_UK,h^T q_nope_h`, so `s_h,j = scale * [q'_h | q_rope_h] .
+[c_j | k_r,j]` is one product with the cached row, `o'_h = sum_j p_j
+c_j`, `o_h = W_UV,h o'_h` — `ops.attention.latent_decode_attention`
+reads each cached row once for all heads and never expands it.  The
+rotation's frequencies and the factor on `scale` are `deepseek_yarn`'s
+(`yarn_frequencies`, `yarn_mscale`).  Such a model's `new_k` is the
+cached rows `[layers, batch, t, 1, row]`, its `new_v` None, and a
+gathered context comes back the same way (kv_cache.py's latent form).
+
 Weights are held in `param_dtype` (bfloat16 as served: at 6144 wide a
 float32 tree cast every step would be twice the chip) under leaves
 named `kernel` / `embedding` / `scale` / `bias` only; a layer's held
@@ -35,7 +63,8 @@ experts are ONE stacked `kernel` `[held, in, out]` a projection.
 
 from __future__ import annotations
 
-from typing import Any, Optional, Tuple
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -45,23 +74,60 @@ import numpy as np
 from analytics_zoo_tpu.ops import grouped
 from analytics_zoo_tpu.ops.attention import (
     dot_product_attention,
+    latent_decode_attention,
+    latent_paged_context,
     paged_decode_attention,
     paged_verify_attention,
 )
 from analytics_zoo_tpu.ops.normalization import RMSNorm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+LATENT = "latent_attention"
 DENSE, SPARSE = "dense", "sparse"
 #: the flax collection an `ExpertLayer` sows its counts into
 MOE_COUNTS = "moe_counts"
 
 
-def rotary(x, positions, theta: float):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """`deepseek_yarn`'s attention factor 0.1 * mscale * ln(factor) + 1
+    (1 where nothing is scaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(d: int, theta: float, scaling: Dict) -> np.ndarray:
+    """The d/2 rotary frequencies of `deepseek_yarn` (`scaling`: the
+    config's `rope_scaling`).  With f_i = theta ** (-2i / d): a
+    dimension that turns more than `beta_fast` times over the original
+    context keeps f_i, one that turns less than `beta_slow` times
+    takes f_i / factor, and the ramp between the two dimensions
+    (`yarn_correction_range`) mixes them."""
+    f = theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    low, high = yarn_correction_range(d, theta, scaling)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (f / scaling["factor"] * ramp + f * (1 - ramp)).astype(np.float32)
+
+
+def yarn_correction_range(d: int, theta: float, scaling: Dict
+                          ) -> Tuple[int, int]:
+    """(low, high): the dimensions between which `yarn_frequencies`
+    ramps — where a dimension turns `beta_fast` and `beta_slow` times
+    over `original_max_position_embeddings` positions."""
+    def dim(turns):
+        return d * math.log(scaling["original_max_position_embeddings"]
+                            / (turns * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(dim(scaling["beta_fast"])), 0),
+            min(math.ceil(dim(scaling["beta_slow"])), d - 1))
+
+
+def rotary(x, positions, theta: float, inv_freq=None):
     """Rotate-half rotary embedding over the whole head: x [b, t, heads,
     d], positions [b, t].  Pair (i, i + d/2) turns by position *
-    theta ** (-2i / d); float32 inside, x's dtype out."""
+    theta ** (-2i / d), or by position * `inv_freq`[i] where the
+    frequencies are given (`yarn_frequencies`); float32 inside, x's
+    dtype out."""
     d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     angle = positions.astype(jnp.float32)[..., None] * inv   # [b, t, d/2]
     cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[:, :, None]
     sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[:, :, None]
@@ -293,6 +359,15 @@ class DecoderLM(nn.Module):
     rope_theta: float = 1e6
     rms_norm_eps: float = 1e-5
     max_position_len: int = 262144
+    #: latent attention (module docstring): the cached latent's width
+    #: (0: the model has none), a query head's two parts, a value head
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: the config's `rope_scaling` (`deepseek_yarn`) as sorted items,
+    #: or None: plain frequencies
+    rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
     compute_dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     paged_attention_impl: Optional[str] = None
@@ -303,14 +378,28 @@ class DecoderLM(nn.Module):
             value = getattr(self, name)
             if isinstance(value, list):
                 object.__setattr__(self, name, tuple(value))
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
         super().__post_init__()
         if len(self.layer_types) != len(self.mlp_layer_types):
             raise ValueError("layer_types and mlp_layer_types differ in "
                              "length")
-        unknown = (set(self.layer_types) - {SLIDING, FULL}) \
+        unknown = (set(self.layer_types) - {SLIDING, FULL, LATENT}) \
             | (set(self.mlp_layer_types) - {DENSE, SPARSE})
         if unknown:
             raise ValueError(f"unknown layer kinds {sorted(unknown)}")
+        if LATENT in self.layer_types:
+            if set(self.layer_types) != {LATENT}:
+                raise ValueError(
+                    "latent_attention caches one row a token and the "
+                    "other kinds two: one pool holds one form, so a "
+                    "model has it in every layer or in none")
+            if not (self.kv_lora_rank and self.qk_nope_head_dim
+                    and self.qk_rope_head_dim and self.v_head_dim):
+                raise ValueError(
+                    "latent_attention needs kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
         if self.n_head % self.n_kv_head:
             raise ValueError(f"{self.n_head} query heads over "
                              f"{self.n_kv_head} KV heads")
@@ -319,18 +408,40 @@ class DecoderLM(nn.Module):
     def from_config(cls, config, **kw) -> "DecoderLM":
         """The module a Hugging-Face-style `config.json` mapping
         describes, by `exaone_moe`'s keys (`layer_types`,
-        `mlp_layer_types`, `num_key_value_heads`, `num_experts`, ...);
+        `mlp_layer_types`, `num_key_value_heads`, `num_experts`, ...)
+        or, where it has a `kv_lora_rank`, by `sarvam_mla`'s
+        (`first_k_dense_replace`, `qk_nope_head_dim`, `rope_scaling`,
+        ...; its `head_dim` is the cached row's width);
         `experts_held` = [first id, count] is this chip's share of the
         experts (all of them when absent).  `kw`: the fields no
         `config.json` has (dtypes, the paged kernel's impl)."""
         hidden, heads = config["hidden_size"], config["num_attention_heads"]
         held = config.get("experts_held")
+        if "kv_lora_rank" in config:
+            # `sarvam_mla`'s keys: latent attention in every layer, the
+            # first `first_k_dense_replace` FFNs dense, `rope_theta`
+            # and `rope_scaling` at the top level
+            n, dense = config["num_hidden_layers"], \
+                config.get("first_k_dense_replace", 0)
+            kw = dict(
+                layer_types=(LATENT,) * n,
+                mlp_layer_types=(DENSE,) * dense + (SPARSE,) * (n - dense),
+                kv_lora_rank=config["kv_lora_rank"],
+                qk_nope_head_dim=config["qk_nope_head_dim"],
+                qk_rope_head_dim=config["qk_rope_head_dim"],
+                v_head_dim=config["v_head_dim"],
+                rope_scaling=config.get("rope_scaling"),
+                rope_theta=float(config["rope_theta"]), **kw)
+        else:
+            kw = dict(
+                layer_types=tuple(config["layer_types"]),
+                mlp_layer_types=tuple(config["mlp_layer_types"]),
+                rope_theta=float(config["rope_parameters"]["rope_theta"]),
+                **kw)
         return cls(
             vocab=config["vocab_size"], hidden_size=hidden, n_head=heads,
-            n_kv_head=config.get("num_key_value_heads", heads),
+            n_kv_head=config.get("num_key_value_heads") or heads,
             head_dim=config.get("head_dim") or hidden // heads,
-            layer_types=tuple(config["layer_types"]),
-            mlp_layer_types=tuple(config["mlp_layer_types"]),
             intermediate_size=config["intermediate_size"],
             moe_intermediate_size=config.get("moe_intermediate_size", 0),
             num_experts=config.get("num_experts", 0),
@@ -340,7 +451,6 @@ class DecoderLM(nn.Module):
             routed_scaling_factor=config.get("routed_scaling_factor", 1.0),
             norm_topk_prob=config.get("norm_topk_prob", True),
             sliding_window=config.get("sliding_window", 0),
-            rope_theta=float(config["rope_parameters"]["rope_theta"]),
             rms_norm_eps=config["rms_norm_eps"],
             max_position_len=config["max_position_embeddings"], **kw)
 
@@ -350,10 +460,35 @@ class DecoderLM(nn.Module):
     def n_block(self) -> int:
         return len(self.layer_types)
 
-    def kv_geometry(self) -> Tuple[int, int, int]:
+    @property
+    def latent(self) -> bool:
+        return LATENT in self.layer_types
+
+    def kv_geometry(self) -> Tuple[int, ...]:
         """(layers, KV heads, head dim): a pool row is KV heads *
-        head dim wide."""
+        head dim wide, and a token holds two.  With latent attention
+        (layers, 1, the cached row's width, 1): ONE row a token."""
+        if self.latent:
+            return (self.n_block, 1,
+                    self.kv_lora_rank + self.qk_rope_head_dim, 1)
         return self.n_block, self.n_kv_head, self.head_dim
+
+    def latent_constants(self):
+        """(the rotary frequencies or None for the plain ones, the
+        factor cos and sin carry, the softmax scale) of latent
+        attention: `deepseek_yarn`'s where the config scales its
+        rotary — mscale over mscale_all_dim's factor on cos and sin,
+        the latter's square on (nope + rope) ** -0.5."""
+        scaling = dict(self.rope_scaling or ())
+        d = self.qk_nope_head_dim + self.qk_rope_head_dim
+        if not scaling:
+            return None, 1.0, d ** -0.5
+        factor = scaling["factor"]
+        all_dim = yarn_mscale(factor, scaling.get("mscale_all_dim", 0.0))
+        return (yarn_frequencies(self.qk_rope_head_dim, self.rope_theta,
+                                 scaling),
+                yarn_mscale(factor, scaling.get("mscale", 1.0)) / all_dim,
+                d ** -0.5 * all_dim ** 2)
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -373,13 +508,16 @@ class DecoderLM(nn.Module):
         return tuple(i for i, kind in enumerate(self.mlp_layer_types)
                      if kind == SPARSE)
 
-    def unsupported_features(self) -> Tuple[str, ...]:
-        """Engine features this model refuses (the engine raises at
-        construction when one is asked for): the tensor-parallel
-        placement shards `CausalLM`'s fused qkv by name and knows no
-        grouped heads or stacked experts, and the grouped-query paged
-        kernel reads no int8 pool."""
-        return ("tensor_parallel", "kv_quantization")
+    def unsupported_features(self) -> Dict[str, str]:
+        """Engine features this model refuses, each with its reason
+        (the engine raises at construction when one is asked for)."""
+        return {
+            "tensor_parallel": "the tensor-parallel placement shards "
+                               "`CausalLM`'s fused qkv by name and has "
+                               "no rule for grouped heads, stacked "
+                               "experts or a latent row all heads read",
+            "kv_quantization": "neither the grouped-query paged kernel "
+                               "nor the latent one reads an int8 pool"}
 
     # -- the forward pass ----------------------------------------------
 
@@ -410,31 +548,102 @@ class DecoderLM(nn.Module):
             additive_mask = (1.0 - token_mask[:, None, None, :]
                              .astype(jnp.float32)) * -1e9
 
+        def latent_attention(i, blk, a_in):
+            # one layer's latent attention over `a_in` (normalised):
+            # (its output before the o projection [b, t, h * dv], the
+            # rows it caches [b, t, row]) — module docstring
+            r, dn = self.kv_lora_rank, self.qk_nope_head_dim
+            dr, dv = self.qk_rope_head_dim, self.v_head_dim
+            inv_freq, turn, scale = self.latent_constants()
+            q = dense(h * (dn + dr), f"{blk}_q")(a_in) \
+                .reshape(b, t, h, dn + dr)
+            q = norm(f"{blk}_q_norm")(q)
+
+            def turned(x):
+                x = rotary(x, positions, self.rope_theta, inv_freq)
+                return x if turn == 1.0 else (x * turn).astype(x.dtype)
+            q_rope = turned(q[..., dn:])
+            row = dense(r + dr, f"{blk}_kv_a")(a_in)
+            c = norm(f"{blk}_kv_a_norm")(row[..., :r])
+            k_r = turned(row[..., None, r:])[:, :, 0]
+            row = jnp.concatenate([c, k_r], axis=-1)      # [b, t, r + dr]
+            w_b = Kernel((r, h * (dn + dv)), pd, name=f"{blk}_kv_b")() \
+                .astype(cd).reshape(r, h, dn + dv)
+            if kv_pool is not None and t == 1:
+                # absorbed: the key up-projection into the query, the
+                # value up-projection after the sum over the rows
+                q_abs = jnp.concatenate([
+                    jnp.einsum("bhd,rhd->bhr", q[:, 0, :, :dn],
+                               w_b[..., :dn]).astype(cd),
+                    q_rope[:, 0]], axis=-1)
+                o = latent_decode_attention(
+                    q_abs, row[:, 0], kv_pool, block_tables, ctx_len,
+                    layer=i, value_width=r, scale=scale, impl=impl)
+                a = jnp.einsum("bhr,rhd->bhd", o.astype(cd),
+                               w_b[..., dn:])[:, None]
+                return a, row
+
+            def expand(rows):
+                # cached rows [b, n, r + dr] -> every head's key
+                # [b, n, h, dn + dr] and value [b, n, h, dv]
+                kv = jnp.einsum("bnr,rhd->bnhd", rows[..., :r].astype(cd),
+                                w_b)
+                shared = jnp.broadcast_to(
+                    rows[:, :, None, r:].astype(cd),
+                    kv.shape[:3] + (dr,))
+                return (jnp.concatenate([kv[..., :dn], shared], -1),
+                        kv[..., dn:])
+            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+            ctx = {}
+            if kv_pool is not None:
+                cached = latent_paged_context(kv_pool, block_tables,
+                                              layer=i, width=r + dr)
+            elif ctx_k is not None:
+                cached = ctx_k[i][:, :, 0]
+            else:
+                cached = None
+                ctx = dict(mask=additive_mask, causal=True)
+            if cached is not None:
+                ctx_keys, ctx_vals = expand(cached)
+                ctx = dict(ctx_k=ctx_keys, ctx_v=ctx_vals, ctx_len=ctx_len)
+            a = dot_product_attention(q, *expand(row), compute_dtype=cd,
+                                      scale=scale, **ctx)
+            return a, row
+
         new_k, new_v, counts = [], [], []
         for i, (attn_kind, ffn_kind) in enumerate(
                 zip(self.layer_types, self.mlp_layer_types)):
             blk = f"block_{i}"
             window = self.sliding_window if attn_kind == SLIDING else None
             with jax.named_scope(
+                    "attn.latent" if attn_kind == LATENT else
                     "attn.window" if window else "attn.full"):
                 a_in = norm(f"{blk}_attn_norm")(x)
-                q = dense(h * hd, f"{blk}_q")(a_in).reshape(b, t, h, hd)
-                k = dense(g * hd, f"{blk}_k")(a_in).reshape(b, t, g, hd)
-                v = dense(g * hd, f"{blk}_v")(a_in).reshape(b, t, g, hd)
-                q = norm(f"{blk}_q_norm")(q)
-                k = norm(f"{blk}_k_norm")(k)
-                if window:
-                    q = rotary(q, positions, self.rope_theta)
-                    k = rotary(k, positions, self.rope_theta)
-                new_k.append(k.astype(jnp.float32))
-                new_v.append(v.astype(jnp.float32))
-                a = attend(q, k, v, layer=i, window=window,
-                           mask=additive_mask, ctx_k=ctx_k, ctx_v=ctx_v,
-                           ctx_len=ctx_len, kv_pool=kv_pool,
-                           kv_scale=kv_scale, block_tables=block_tables,
-                           impl=impl, compute_dtype=cd)
+                if attn_kind == LATENT:
+                    a, row = latent_attention(i, blk, a_in)
+                    new_k.append(row[:, :, None].astype(jnp.float32))
+                else:
+                    q = dense(h * hd, f"{blk}_q")(a_in) \
+                        .reshape(b, t, h, hd)
+                    k = dense(g * hd, f"{blk}_k")(a_in) \
+                        .reshape(b, t, g, hd)
+                    v = dense(g * hd, f"{blk}_v")(a_in) \
+                        .reshape(b, t, g, hd)
+                    q = norm(f"{blk}_q_norm")(q)
+                    k = norm(f"{blk}_k_norm")(k)
+                    if window:
+                        q = rotary(q, positions, self.rope_theta)
+                        k = rotary(k, positions, self.rope_theta)
+                    new_k.append(k.astype(jnp.float32))
+                    new_v.append(v.astype(jnp.float32))
+                    a = attend(q, k, v, layer=i, window=window,
+                               mask=additive_mask, ctx_k=ctx_k,
+                               ctx_v=ctx_v, ctx_len=ctx_len,
+                               kv_pool=kv_pool, kv_scale=kv_scale,
+                               block_tables=block_tables, impl=impl,
+                               compute_dtype=cd)
                 a = dense(self.hidden_size, f"{blk}_o")(
-                    a.reshape(b, t, h * hd).astype(cd))
+                    a.reshape(b, t, -1).astype(cd))
             x = x + a.astype(jnp.float32)
             f_in = norm(f"{blk}_ffn_norm")(x)
             if ffn_kind == DENSE:
@@ -457,8 +666,8 @@ class DecoderLM(nn.Module):
             self.sow(MOE_COUNTS, "tokens", jnp.stack(counts),
                      reduce_fn=lambda _, new: new, init_fn=lambda: None)
         logits = dense(self.vocab, "lm_head")(norm("final_norm")(x))
-        return (logits.astype(jnp.float32),
-                jnp.stack(new_k), jnp.stack(new_v))
+        return (logits.astype(jnp.float32), jnp.stack(new_k),
+                jnp.stack(new_v) if new_v else None)
 
 
 class ExpertCounters:

@@ -102,6 +102,7 @@ from analytics_zoo_tpu.serving.generation.kv_cache import (
     PagedKVCache,
     RecurrentStatePool,
     pool_geometry,
+    pool_rows,
     state_geometry,
 )
 from analytics_zoo_tpu.resilience.faults import (
@@ -326,7 +327,8 @@ class GenerationEngine:
         n_layers, kv_heads, head_dim = pool_geometry(model)
         self.cache = PagedKVCache(
             n_layers, num_blocks, block_size, kv_heads, head_dim,
-            dtype=cache_dtype, quantization=kv_quantization)
+            dtype=cache_dtype, quantization=kv_quantization,
+            rows=pool_rows(model))
         #: functional scale state fed to the jitted steps alongside
         #: `cache.kv` — a 1-element placeholder when quantization is
         #: off (the steps return it untouched)
@@ -470,6 +472,14 @@ class GenerationEngine:
         reg.gauge("generation_preemptions",
                   fn=lambda: self.scheduler.n_preemptions,
                   help="sequences preempted under cache pressure")
+        reg.gauge("generation_kv_row_bytes",
+                  help="bytes a cached token holds over all layers "
+                       "(logical: unquantized, a latent row's padding "
+                       "left out)").set(self.cache.token_nbytes)
+        reg.gauge("generation_kv_rows_per_token",
+                  help="rows a cached token holds in a layer: 2 (a key "
+                       "and a value) or 1 (a latent row)"
+                  ).set(self.cache.rows)
         if self.state_pool is not None:
             reg.gauge("generation_state_slots_in_use",
                       fn=lambda: len(self.scheduler.slotted()),

@@ -46,6 +46,20 @@ for h*d >= 64.  Reads dequantize in the paged-attention kernel (or the
 XLA fallback) — a dequantized pool never exists in HBM.
 `logical_nbytes` vs `physical_nbytes` report both sides for the
 `memory_kv_pool_*` gauges (docs/observability.md).
+
+The latent form (`rows=1`): a model whose attention caches ONE row a
+token a layer — a compressed latent every head reads, with the one
+rotated key all heads share behind it (decoder.py,
+`latent_attention`) — says so in its `kv_geometry()`, and the pool is
+[n_layers, 1, num_blocks * block_size, width]: the same array with
+one row where the K/V form has two, so every function here and every
+block program (copy, spill, restore) follows it by its shape.  A
+latent row's columns need fill no whole lane tiles (576 is four and a
+half), so the pool stores them padded to the next multiple of
+`LANES` — what the chip's tiling would store anyway, made explicit so
+that a kernel's block is whole tiles; the padding is written as zeros,
+`gather_kv` cuts it off, `logical_nbytes` leaves it out and
+`physical_nbytes` counts it.
 """
 
 from __future__ import annotations
@@ -59,6 +73,8 @@ from jax.sharding import PartitionSpec as P
 
 #: block id 0 is never allocated; see module docstring
 NULL_BLOCK = 0
+#: columns of a lane tile: a latent row is stored padded to a multiple
+LANES = 128
 #: the pool under tensor parallelism: a head shard is a contiguous
 #: slice of the merged heads * head_dim axis
 KV_TP_SPEC = P(None, None, None, "tp")
@@ -87,14 +103,23 @@ def dequantize_kv_tokens(q, scale):
 def pool_geometry(model) -> Tuple[int, int, int]:
     """(layers, KV heads, head dim) of the pool `model` needs: what
     the model says of itself (`kv_geometry()`: grouped heads, a head
-    that is not hidden / heads), else the plain multi-head reading of
-    `CausalLM`'s fields.  The one place the pool's geometry is read
-    from a model."""
+    that is not hidden / heads, one latent "head" as wide as the
+    cached row), else the plain multi-head reading of `CausalLM`'s
+    fields.  With `pool_rows`, the one place the pool's geometry is
+    read from a model."""
     geometry = getattr(model, "kv_geometry", None)
     if geometry is not None:
-        return tuple(int(n) for n in geometry())
+        return tuple(int(n) for n in geometry()[:3])
     return (int(model.n_block), int(model.n_head),
             int(model.hidden_size) // int(model.n_head))
+
+
+def pool_rows(model) -> int:
+    """Rows a cached token holds in a layer: 2 (a key and a value), or
+    the fourth number of a `kv_geometry()` that has one (1: the latent
+    form)."""
+    geometry = getattr(model, "kv_geometry", lambda: ())()
+    return int(geometry[3]) if len(geometry) > 3 else 2
 
 
 def state_geometry(model):
@@ -108,7 +133,7 @@ def state_geometry(model):
 
 
 def block_view(x, block_size: int):
-    """The pool [L, 2, slots, h*d] (or its scale vectors [L, 2, slots])
+    """The pool [L, rows, slots, h*d] (or its scale vectors [L, 2, slots])
     with the slot axis split into [num_blocks, block_size] — what the
     paged kernel's BlockSpecs index by block-table entry.  A bitcast
     wherever `block_size` rows fill whole tiles (16 for bf16, 8 for
@@ -116,35 +141,44 @@ def block_view(x, block_size: int):
     return x.reshape(*x.shape[:2], -1, block_size, *x.shape[3:])
 
 
-def _row_index(n_layers: int, slots):
+def _row_index(kv, slots):
     """(layer, k/v, slot) index arrays that broadcast to
-    [L, 2, *slots.shape]: a gather or scatter through them moves single
+    [L, rows, *slots.shape] of pool `kv` (rows: 2, or 1 in the latent
+    form): a gather or scatter through them moves single
     ROWS of the pool, which XLA does on the pool as it lies (indexing
     `kv[:, :, slots]` instead makes the window span the layer and k/v
     axes, and XLA relays the whole pool out to bring them inward)."""
+    n_layers, rows = kv.shape[:2]
     ones = (1,) * slots.ndim
     return (jnp.arange(n_layers).reshape(n_layers, 1, *ones),
-            jnp.arange(2).reshape(1, 2, *ones), slots[None, None])
+            jnp.arange(rows).reshape(1, rows, *ones), slots[None, None])
 
 
-def gather_kv(kv, kv_scale, tok_idx, n_head: int):
+def gather_kv(kv, kv_scale, tok_idx, n_head: int,
+              head_dim: Optional[int] = None):
     """Token slots `tok_idx` [...] of every layer as (ctx_k, ctx_v)
     [L, ..., heads, head_dim] — the concat-oracle, chunk-prefill and
-    verify read paths.  Heads are split out of what was GATHERED,
-    never of the pool; an int8 pool dequantizes here by `kv_scale`
-    [L, 2, slots]."""
-    idx = _row_index(kv.shape[0], tok_idx)
+    verify read paths; of a latent pool (the cached rows, None):
+    there is one row a token.  Heads are split out of what was
+    GATHERED, never of the pool, and columns past heads * `head_dim`
+    (a latent row's padding) are cut off it; an int8 pool dequantizes
+    here by `kv_scale` [L, 2, slots]."""
+    idx = _row_index(kv, tok_idx)
     rows = kv[idx]
+    if head_dim is not None and n_head * head_dim != rows.shape[-1]:
+        rows = rows[..., :n_head * head_dim]
     rows = rows.reshape(*rows.shape[:-1], n_head, -1)
     if kv.dtype == jnp.int8:
         rows = dequantize_kv_tokens(rows, kv_scale[idx])
-    return rows[:, 0], rows[:, 1]
+    return rows[:, 0], (rows[:, 1] if kv.shape[1] == 2 else None)
 
 
 def write_kv(kv, kv_scale, dest, new_k, new_v):
     """Write new_k / new_v [L, n, heads, head_dim] into token slots
     `dest` [n] of every layer — the one pool write of the prefill,
-    chunk, decode and verify programs.  ONE scatter of rows: index
+    chunk, decode and verify programs; a latent pool takes its one row
+    a token as `new_k`, `new_v` None, and stores it padded with zeros
+    to the pool's columns.  ONE scatter of rows: index
     (layer, k/v, slot), window a single merged row, so XLA updates the
     donated pool in place whatever `n` is (a `kv.at[:, 0, dest]` window
     spans the layer axis and costs two whole-pool relayouts).  An int8
@@ -154,13 +188,17 @@ def write_kv(kv, kv_scale, dest, new_k, new_v):
     passes through.  Duplicate slots only ever name the null block
     (dead lanes, padding), where any winner is harmless."""
     L, n = new_k.shape[:2]
-    rows = jnp.stack([new_k, new_v], axis=1)       # [L, 2, n, h, d]
-    idx = _row_index(L, dest)
+    rows = jnp.stack([new_k] if new_v is None else [new_k, new_v],
+                     axis=1)                       # [L, rows, n, h, d]
+    idx = _row_index(kv, dest)
     if kv.dtype == jnp.int8:
         rows, scales = quantize_kv_tokens(rows)
         kv_scale = kv_scale.at[idx].set(scales)
-    kv = kv.at[idx].set(rows.reshape(L, 2, n, -1).astype(kv.dtype))
-    return kv, kv_scale
+    rows = rows.reshape(L, kv.shape[1], n, -1).astype(kv.dtype)
+    if rows.shape[-1] != kv.shape[-1]:
+        rows = jnp.pad(rows, ((0, 0),) * 3
+                       + ((0, kv.shape[-1] - rows.shape[-1]),))
+    return kv.at[idx].set(rows), kv_scale
 
 
 class RecurrentStatePool:
@@ -320,11 +358,19 @@ class PagedKVCache:
 
     def __init__(self, n_layers: int, num_blocks: int, block_size: int,
                  n_head: int, head_dim: int, dtype=jnp.float32,
-                 quantization: Optional[str] = None):
+                 quantization: Optional[str] = None, rows: int = 2):
         if quantization not in (None, "int8"):
             raise ValueError(f"unsupported KV quantization "
                              f"{quantization!r}; use None or 'int8'")
+        if rows not in (1, 2):
+            raise ValueError(f"a cached token holds 1 or 2 rows a "
+                             f"layer, not {rows}")
+        if rows == 1 and quantization is not None:
+            raise ValueError("a latent pool has no quantized form")
         self.n_layers = n_layers
+        #: rows a token holds in a layer: a key and a value, or 1 (the
+        #: latent form)
+        self.rows = rows
         self.num_blocks = num_blocks
         self.block_size = block_size
         self.n_head = n_head
@@ -334,8 +380,12 @@ class PagedKVCache:
         #: when quantization is off)
         self.logical_dtype = jnp.dtype(dtype)
         store = jnp.int8 if quantization == "int8" else dtype
+        #: columns of a row as stored: a latent row padded to whole
+        #: lane tiles (module docstring)
+        self.row_width = n_head * head_dim if rows == 2 \
+            else -(-n_head * head_dim // LANES) * LANES
         self.kv = jnp.zeros(
-            (n_layers, 2, num_blocks * block_size, n_head * head_dim),
+            (n_layers, rows, num_blocks * block_size, self.row_width),
             store)
         #: per-token-slot dequant scales (int8 mode only) — functional
         #: state like `kv`: the jitted steps take and return it
@@ -351,11 +401,11 @@ class PagedKVCache:
 
     @property
     def slab_shape(self) -> Tuple[int, int, int, int]:
-        """One block's rows across every layer, [L, 2, block_size,
-        h*d] — the unit the host tier spills and restores (its scales
-        are `slab_shape[:3]`)."""
-        return (self.n_layers, 2, self.block_size,
-                self.n_head * self.head_dim)
+        """One block's rows across every layer, [L, rows, block_size,
+        columns as stored] — the unit the host tier spills and
+        restores (its scales are `slab_shape[:3]`)."""
+        return (self.n_layers, self.rows, self.block_size,
+                self.row_width)
 
     def read_block(self, blk: int):
         """Block `blk`'s slab and its scales (None unquantized), still
@@ -377,9 +427,17 @@ class PagedKVCache:
     @property
     def logical_nbytes(self) -> int:
         """Bytes the same pool would occupy unquantized at
-        `logical_dtype` — physical/logical is the residency win the
-        `memory_kv_pool_*` gauges report."""
-        return self.kv.size * self.logical_dtype.itemsize
+        `logical_dtype`, a latent row's padding left out —
+        physical/logical is the residency win the `memory_kv_pool_*`
+        gauges report."""
+        return (self.kv.size // self.row_width * self.n_head
+                * self.head_dim * self.logical_dtype.itemsize)
+
+    @property
+    def token_nbytes(self) -> int:
+        """Logical bytes one cached token holds over all layers."""
+        return (self.n_layers * self.rows * self.n_head * self.head_dim
+                * self.logical_dtype.itemsize)
 
     @property
     def nbytes(self) -> int:

@@ -21,6 +21,7 @@ from analytics_zoo_tpu.serving.generation.kv_cache import (
     admit_state,
     block_view,
     gather_kv,
+    pool_geometry,
     write_kv,
 )
 from analytics_zoo_tpu.serving.generation.sampling import sample_tokens
@@ -35,7 +36,11 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
     `decode`, `spec_verify`, `copy_block`, `restore_block`), the last
     None under tensor parallelism (the host tier is off there).
 
-    `block_size`, `n_head`: the pool's block length and KV heads;
+    `block_size`, `n_head`: the pool's block length and KV heads (the
+    rows a token holds and their width follow the pool and the model:
+    a latent model hands back one row a token as its `new_k`, None as
+    its `new_v`, and is handed a gathered context the same way —
+    kv_cache.py);
     `quantized`: an int8 pool with scales beside it; `paged`: decode
     and verify read the pool through the paged kernel (else the
     gather+concat oracle); `width`: a lane row's width
@@ -50,6 +55,7 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
     the bucketed families (None: speculation is off)."""
     bs = block_size
     max_pos = model.max_position_len
+    head_dim = pool_geometry(model)[2]
     # buffer donation lets XLA update the KV pool (and its scale
     # vectors) in place; the CPU backend ignores donation and
     # warns, so only donate off-CPU
@@ -91,9 +97,14 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         # the context gathered out of the pool by token slot
         # (kv_cache.gather_kv) and attended by the concat read
         # path: the parity oracle, and the chunk step's read
-        ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head)
+        ctx_k, ctx_v = gather_kv(kv, kv_scale, tok_idx, n_head, head_dim)
         return apply(params, tokens, pos, token_mask=real,
                      ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len, **kw)
+
+    def each(cut, *new):
+        # `cut` of each kind of row a model call made (`new_v` is None
+        # where a token holds one row)
+        return tuple(None if x is None else cut(x) for x in new)
 
     def prefill(params, kv, kv_scale, lanes, request):
         # request = [slot | the lane's row | tokens, bucket-padded]
@@ -114,7 +125,7 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
             + jnp.arange(B) % bs
         dest = jnp.where(jnp.arange(B) < length, dest, 0)
         kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                new_k[:, 0], new_v[:, 0])
+                                *each(lambda x: x[:, 0], new_k, new_v))
         last = logits[0, length - 1]
         rng, sub = jax.random.split(lanes["rng"])
         nxt = sample_tokens(last[None], sub, temperature[None],
@@ -159,8 +170,9 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         dest = block_tables[jnp.arange(S), ctx_len // bs] * bs \
             + ctx_len % bs
         dest = jnp.where(active, dest, 0)   # dead lanes → null block
-        kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                new_k[:, :, 0], new_v[:, :, 0])
+        kv, kv_scale = write_kv(
+            kv, kv_scale, dest,
+            *each(lambda x: x[:, :, 0], new_k, new_v))
         last = jnp.where(active[:, None], logits[:, 0], 0.0)
         rng, sub = jax.random.split(lanes["rng"])
         nxt = sample_tokens(last, sub, temperature, top_k)
@@ -196,7 +208,7 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
             + (start + rel) % bs
         dest = jnp.where(rel < length, dest, 0)
         kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                new_k[:, 0], new_v[:, 0])
+                                *each(lambda x: x[:, 0], new_k, new_v))
         last = logits[0, length - 1]
         rng, sub = jax.random.split(rng)
         nxt = sample_tokens(last[None], sub, temperature, top_k)[0]
@@ -236,11 +248,10 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
                             abs_pos // bs] * bs + abs_pos % bs
         dest = jnp.where((rel[None] < length[:, None])
                          & active[:, None], dest, 0).reshape(-1)
-        L = new_k.shape[0]
         kv, kv_scale = write_kv(
             kv, kv_scale, dest,
-            new_k.reshape(L, S * W, *new_k.shape[-2:]),
-            new_v.reshape(L, S * W, *new_v.shape[-2:]))
+            *each(lambda x: x.reshape(x.shape[0], S * W, *x.shape[-2:]),
+                  new_k, new_v))
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (kv, kv_scale, greedy) + counts
 

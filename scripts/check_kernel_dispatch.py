@@ -27,7 +27,10 @@ einsum rule PLUS a
 stricter one: no direct Pallas imports (`ops.pallas.*`,
 `jax.experimental.pallas`, `pallas_call`).  Decode attention must go
 through `ops.attention.paged_decode_attention` /
-`dot_product_attention` — a raw concat-attend einsum or a privately
+`latent_decode_attention` (a model that caches one latent row a
+token) / `dot_product_attention` — a raw concat-attend einsum, a raw
+scores-softmax over cached latent rows ("shw,scw" scores, "shc,scv"
+combine) or a privately
 wired kernel in the engine (or an attention shortcut inside the
 prefix-cache/chunked-prefill machinery) would silently bitrot the
 decode path off the tuned paged kernel (or pin it to one kernel
@@ -56,6 +59,8 @@ PATTERNS = (
     (re.compile(r"bqhd,bkhd|bhqk,bkhd"),
      "use ops.attention.dot_product_attention / "
      "ops.pallas.flash_attention"),
+    (re.compile(r"shw,scw|shc,scv"),
+     "use ops.attention.latent_decode_attention"),
 )
 
 #: the decode path additionally may not wire kernels privately — the

@@ -299,13 +299,28 @@ def layer_with(config, held):
         scale=config["routed_scaling_factor"])
 
 
-def test_the_shares_add_up(lm):
+def share_cases(shape):
+    """(the uncut layer's configuration, its reference, the shares):
+    `kexaone` — the toy's 8 experts top-2 in uneven shares;
+    `sarvam` — `sarvam_105b_ep8_serve`'s own shape, 128 experts top-8
+    over eight chips of 16, in front of latent attention."""
+    if shape == "kexaone":
+        return (toy_config(experts_held=[0, 8]), ref,
+                ((0, 2), (2, 4), (6, 1), (7, 1)))
+    from benchmarks.reference import sarvam_mla_ref
+    from test_latent_attention import toy_config as latent_config
+    return (latent_config(num_experts=128, num_experts_per_tok=8,
+                          experts_held=[0, 128]), sarvam_mla_ref,
+            tuple((16 * rank, 16) for rank in range(8)))
+
+
+@pytest.mark.parametrize("shape", ["kexaone", "sarvam"])
+def test_the_shares_add_up(shape):
     """Over all the `experts_held` slices of one layer, the routed
     parts plus the shared expert counted once equal the uncut
     reference's layer output — with the program's layer and with the
     reference's own share."""
-    config, _, params = lm
-    whole = toy_config(experts_held=[0, 8])
+    whole, ref, shares = share_cases(shape)
     model = DecoderLM.from_config(whole)
     p = seeded(model, seed=5)["block_2_moe"]
     x = jnp.asarray(np.random.default_rng(6).normal(size=(1, 19, 64)),
@@ -326,13 +341,13 @@ def test_the_shares_add_up(lm):
                                           experts_held=(first, 0))
         return np.asarray(mine[0]) - np.asarray(only_shared), counts
 
-    parts = [share(first, count)
-             for first, count in ((0, 2), (2, 4), (6, 1), (7, 1))]
+    parts = [share(first, count) for first, count in shares]
     shared, _ = ref.expert_layer(x[0], p, whole, experts_held=(0, 0))
     total = sum(part for part, _ in parts) + np.asarray(shared)
     np.testing.assert_allclose(total, np.asarray(want), atol=TOL, rtol=0)
     # every assignment was computed by exactly one share
-    assert sum(int(c[:-2].sum()) for _, c in parts) == 19 * 2
+    assert sum(int(c[:-2].sum()) for _, c in parts) \
+        == 19 * whole["num_experts_per_tok"]
 
 
 @pytest.mark.parametrize("tokens", [1, 64])

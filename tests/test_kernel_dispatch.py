@@ -44,6 +44,9 @@ def test_lint_detects_violation():
     assert matches("from flax.linen import LayerNorm")
     assert matches('s = jnp.einsum("bqhd,bkhd->bhqk", q, k)')
     assert matches('o = jnp.einsum("bhqk,bkhd->bqhd", p, v)')
+    # a scores-softmax over cached latent rows, outside ops/
+    assert matches('s = jnp.einsum("shw,scw->shc", q_abs, rows)')
+    assert matches('o = jnp.einsum("shc,scv->shv", p, rows[..., :512])')
     # the sanctioned dispatch forms stay legal
     assert not matches("x = OpsLayerNorm(name=\"ln1\")(x)")
     assert not matches(
@@ -68,6 +71,16 @@ def test_lint_detects_violation():
         "paged_decode_attention")
     assert not gen_matches("a = paged_decode_attention(q, k, v, kp, "
                            "vp, tables, ctx_len)")
+    # ... and so does the latent dispatch point, with the projections
+    # a model folds around it
+    assert gen_matches('s = jnp.einsum("shw,scw->shc", q_abs, rows)')
+    assert not gen_matches(
+        "from analytics_zoo_tpu.ops.attention import "
+        "latent_decode_attention")
+    assert not gen_matches("o = latent_decode_attention(q_abs, row, pool, "
+                           "tables, ctx_len, layer=i, value_width=r, "
+                           "scale=scale)")
+    assert not gen_matches('q_abs = jnp.einsum("bhd,rhd->bhr", q, w_uk)')
     # serving/generation IS scanned — and the prefix-cache (PR 8),
     # speculation (PR 15) and host-tier (PR 18) subsystems actually
     # live under that root, so a raw einsum or a private Pallas wire

@@ -107,6 +107,32 @@ def _paged_gqa(block_gather, window):
     return build
 
 
+def _latent(block_gather):
+    """The latent kernel at `sarvam_105b_ep8_serve`'s decode shapes:
+    128 lanes, 64 heads on rows of 576 columns stored 640 wide (values:
+    the first 512), block 16, tables for a 5,120 context, the whole
+    5-layer bf16 pool of one row a token handed over, the last layer
+    read."""
+    def build(place):
+        from analytics_zoo_tpu.ops.pallas.paged_attention import (
+            latent_decode_pallas)
+        lanes, h, w, bs = 128, 64, 640, 16
+        mb = 5120 // bs
+        nb = lanes * mb + 1
+        args = [place((lanes, h, w), jnp.bfloat16),
+                place((lanes, w), jnp.bfloat16),
+                place((5, 1, nb, bs, w), jnp.bfloat16),
+                place((lanes, mb), jnp.int32), place((lanes,), jnp.int32)]
+
+        def fn(q, new, pool, tbl, cl):
+            return latent_decode_pallas(
+                q, new, pool, tbl, cl, layer=4, value_width=512,
+                scale=192 ** -0.5 * 1.36889 ** 2,
+                block_gather=block_gather, interpret=False)
+        return fn, args
+    return build
+
+
 def _paged_window(place):
     """The multi-head kernel with a window (no model serves it yet; it
     shares the index map and the mask with the grouped one)."""
@@ -203,6 +229,10 @@ CASES = {
     "paged_gqa_bf16_g8_window128": _paged_gqa(8, 128),
     "paged_gqa_bf16_g1_window128": _paged_gqa(1, 128),
     "paged_bf16_g8_window128": _paged_window,
+    # None = `LATENT_BLOCK_GATHER` (32), what the latent op asks for
+    "latent_bf16_default": _latent(None),
+    "latent_bf16_g1": _latent(1),
+    "latent_bf16_g8": _latent(8),
     "layer_norm_fwd_bwd_bf16": _layer_norm(jnp.bfloat16),
     "layer_norm_fwd_bwd_f32": _layer_norm(jnp.float32),
     "bias_gelu_fwd_bf16": _bias_gelu,
@@ -293,7 +323,8 @@ def _expert_models():
     """One expert layer of each expert configuration of the benchmark
     at its published widths (`kexaone_236b_ep8_serve`: gated experts
     at the hidden size; `nemotron3_super_120b_ep4_serve`: un-gated, in
-    a latent space), with the lanes its cell decodes."""
+    a latent space; `sarvam_105b_ep8_serve`: gated, behind latent
+    attention), with the lanes its cell decodes."""
     from analytics_zoo_tpu.serving.generation import DecoderLM
     from analytics_zoo_tpu.serving.generation.hybrid import HybridLM
     bf16 = dict(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
@@ -315,10 +346,24 @@ def _expert_models():
             num_experts_per_tok=22, experts_held=(0, 128),
             routed_scaling_factor=5.0, norm_eps=1e-5,
             max_position_len=262144, **bf16)),
+        # the same gated experts at a hidden size of 4096, behind
+        # latent attention: the latent kernel in the same program
+        "sarvam": (128, DecoderLM(
+            vocab=32768, hidden_size=4096, n_head=64, n_kv_head=64,
+            head_dim=576, layer_types=("latent_attention",),
+            mlp_layer_types=("sparse",), intermediate_size=16384,
+            moe_intermediate_size=2048, num_experts=128,
+            num_experts_per_tok=8, experts_held=(0, 16),
+            routed_scaling_factor=2.5, kv_lora_rank=512,
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            rope_theta=10000.0, rms_norm_eps=1e-6, rope_scaling=dict(
+                beta_fast=32, beta_slow=1, factor=40, mscale=1,
+                mscale_all_dim=1, original_max_position_embeddings=4096),
+            **bf16)),
     }
 
 
-@pytest.mark.parametrize("config", ["kexaone", "nemotron3"])
+@pytest.mark.parametrize("config", ["kexaone", "nemotron3", "sarvam"])
 def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
     """The `decode` program of each expert configuration, lowered for
     the described v5e from shapes alone (no weight is made): every
@@ -331,7 +376,7 @@ def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
     from analytics_zoo_tpu.ops import grouped
     from analytics_zoo_tpu.serving.generation import lane_state, steps
     from analytics_zoo_tpu.serving.generation.kv_cache import (
-        PagedKVCache, pool_geometry)
+        PagedKVCache, pool_geometry, pool_rows)
     lanes_n, model = _expert_models()[config]
     # the dispatchers ask the backend whether to take their Pallas
     # form, the builder whether to donate: here it is a TPU
@@ -348,7 +393,8 @@ def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
         model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
                    jnp.arange(8)[None])["params"],
         PagedKVCache(layers, 1 + lanes_n * blocks, bs, kv_heads,
-                     head_dim, dtype=jnp.bfloat16).kv,
+                     head_dim, dtype=jnp.bfloat16,
+                     rows=pool_rows(model)).kv,
         jax.random.PRNGKey(0)))
     args = jax.tree_util.tree_map(
         lambda x: place(x.shape, x.dtype),
