@@ -143,6 +143,12 @@ TURN_S = 0.0003
 DRAINS = ("preempt", "cow", "host_restore", "chunk", "verify", "evict",
           "error", "idle")
 
+#: what a program's sampler ran (the suffixes of
+#: `generation_sample_rounds_total_<path>`; sampling.py): the argmax
+#: alone, the draw beside it, the sort over the vocabulary inside the
+#: draw
+SAMPLE_PATHS = ("argmax", "draw", "sort")
+
 
 class _Prefill(NamedTuple):
     """A prefill enqueued and not collected: its first token is still
@@ -460,6 +466,12 @@ class GenerationEngine:
                 help="times what was in flight was collected before "
                      f"the next round was enqueued: {reason}")
             for reason in DRAINS}
+        self._c_sampled = {
+            path: reg.counter(
+                f"generation_sample_rounds_total_{path}",
+                help="decode rounds and prefills whose sampler ran, by "
+                     f"what the host's lanes asked of it: {path}")
+            for path in SAMPLE_PATHS}
         reg.gauge("generation_cache_occupancy",
                   fn=self.cache.allocator.occupancy,
                   help="fraction of KV blocks held by live sequences")
@@ -845,6 +857,16 @@ class GenerationEngine:
         if reason:
             self._finish(seq, reason)
 
+    def _sampled(self, drawing, sorting) -> None:
+        """Count the sampler path of a program just enqueued, as the
+        host knows its lanes: some of them with a temperature
+        (`drawing`), some of those with `top_k` too (`sorting`).  The
+        device decides from its own rows (sampling.py) and can be a
+        round ahead of this: a lane the step itself stopped, its last
+        token not collected yet, still counts here."""
+        path = "argmax" if not drawing else "sort" if sorting else "draw"
+        self._c_sampled[path].inc()
+
     def _end_step(self, rec) -> None:
         """Close a step record: the goodput commit (counters, the
         timeline ring, the memory sampler) is accounting like the rest,
@@ -923,6 +945,7 @@ class GenerationEngine:
                     self.params, self.cache.kv, self._kv_scale,
                     lanes.state, request)
                 self._store_kv_state(kv, scl)
+                self._sampled(seq.temperature > 0, seq.top_k > 0)
                 if self.state_pool is not None:
                     self._c_state_resets.inc()
                     if seq.n_preempted:
@@ -1019,6 +1042,7 @@ class GenerationEngine:
                     jnp.full(1, seq.temperature, jnp.float32),
                     jnp.full(1, seq.top_k, jnp.int32), state["rng"])
                 self._store_kv_state(kv, scl)
+                self._sampled(seq.temperature > 0, seq.top_k > 0)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence
                 moe = jax.device_get(moe)
@@ -1347,6 +1371,8 @@ class GenerationEngine:
                             self.params, self.cache.kv, self._kv_scale,
                             self._lanes.state, patch)
                     self._store_kv_state(kv, scl)
+                    self._sampled(self.scheduler.n_drawing,
+                                  self.scheduler.n_sorting)
                     # the oldest in flight: the previous round, if any
                     if self._in_flight \
                             and isinstance(self._in_flight[0], _Decode):

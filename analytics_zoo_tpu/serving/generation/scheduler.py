@@ -168,8 +168,26 @@ class SlotScheduler:
         #: lanes whose holder or block table changed since the engine
         #: last told the device (lane_state.py reads and clears it)
         self.touched: set = set()
+        #: lanes held by a request with a temperature, and those of
+        #: them with `top_k` too: what the round's sampler will be
+        #: asked for (sampling.py), kept in step where a lane changes
+        #: hands (`_seat`) so that nobody walks the lanes to know it
+        self.n_drawing = 0
+        self.n_sorting = 0
 
     # ------------------------------------------------------------------
+
+    def _seat(self, seq: Sequence, slot: Optional[int]) -> None:
+        """`seq` takes lane `slot`, or (None) leaves the lane it
+        holds: the one place a lane changes hands."""
+        lane = seq.slot if slot is None else slot
+        self.slots[lane] = None if slot is None else seq
+        self.touched.add(lane)
+        seq.slot = slot
+        if seq.temperature > 0:
+            n = -1 if slot is None else 1
+            self.n_drawing += n
+            self.n_sorting += n * (seq.top_k > 0)
 
     def submit(self, seq: Sequence) -> None:
         if seq.context_len + seq.max_new_tokens > self.max_context:
@@ -256,9 +274,7 @@ class SlotScheduler:
                           context_len=victim.context_len)
         self.cache.allocator.free(victim.block_table)
         victim.block_table = []
-        self.touched.add(victim.slot)
-        self.slots[victim.slot] = None
-        victim.slot = None
+        self._seat(victim, None)
         victim.status = "waiting"
         victim.prefill_pos = 0
         victim.prefix_tokens = 0
@@ -451,12 +467,10 @@ class SlotScheduler:
             seq.block_table = cached_blocks + blocks
             seq.prefill_pos = n_cached
             seq.prefix_tokens = n_cached
-            seq.slot = free_slots[0]
+            self._seat(seq, free_slots[0])
             seq.status = "prefilling" if self.chunk_mode else "running"
             seq._admit_order = self._admit_counter
             self._admit_counter += 1
-            self.slots[seq.slot] = seq
-            self.touched.add(seq.slot)
             if not self.chunk_mode:
                 budget -= bucket
             admitted.append(seq)
@@ -486,9 +500,7 @@ class SlotScheduler:
             self.cache.allocator.free(seq.block_table)
             seq.block_table = []
         if seq.slot is not None:
-            self.touched.add(seq.slot)
-            self.slots[seq.slot] = None
-            seq.slot = None
+            self._seat(seq, None)
         seq.status = "finished"
         seq.finish_reason = reason
         flight_recorder.record("sched_release", uid=seq.uid,

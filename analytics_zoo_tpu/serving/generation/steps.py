@@ -175,7 +175,7 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
             *each(lambda x: x[:, :, 0], new_k, new_v))
         last = jnp.where(active[:, None], logits[:, 0], 0.0)
         rng, sub = jax.random.split(lanes["rng"])
-        nxt = sample_tokens(last, sub, temperature, top_k)
+        nxt = sample_tokens(last, sub, temperature, top_k, active)
         out = {"rows": lane_state.advanced(rows, nxt), "rng": rng}
         if stateful:
             out["recurrent"] = stepped[0]

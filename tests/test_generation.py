@@ -10,6 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from analytics_zoo_tpu.observability.registry import MetricsRegistry
 from analytics_zoo_tpu.serving.generation import (
     BlockAllocator,
     CausalLM,
@@ -17,6 +18,7 @@ from analytics_zoo_tpu.serving.generation import (
     PagedKVCache,
     sample_tokens,
 )
+from analytics_zoo_tpu.serving.generation.engine import SAMPLE_PATHS
 
 VOCAB = 61
 
@@ -341,6 +343,169 @@ def test_sampling_controls():
     top4 = np.argsort(np.asarray(logits), -1)[:, -4:]
     for row, tok in enumerate(np.asarray(k4)):
         assert tok in top4[row]
+
+
+def sample_tokens_plain(logits, rng, temperature, top_k):
+    """The sampler as it was before its work was chosen on the device
+    (PR 38): the sort and the draw for every round, whatever the lanes
+    ask for.  Kept as the plain reference."""
+    vocab = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1)
+    desc = jnp.sort(logits, axis=-1)[:, ::-1]
+    kk = jnp.clip(jnp.where(top_k > 0, top_k, vocab), 1, vocab) - 1
+    thresh = jnp.take_along_axis(desc, kk[:, None], axis=-1)
+    filtered = jnp.where(logits >= thresh, logits, -jnp.inf)
+    scaled = filtered / jnp.maximum(temperature, 1e-6)[:, None]
+    sampled = jax.random.categorical(rng, scaled, axis=-1)
+    return jnp.where(temperature > 0.0, sampled, greedy).astype(jnp.int32)
+
+
+SAMPLER_LANES, SAMPLER_VOCAB = 6, 997
+#: (temperature, top_k, live) a lane, by what the round's sampler has
+#: to run for them
+SAMPLER_ROUNDS = {
+    "all_greedy": ([0.0] * 6, [0] * 6, [1] * 6),
+    "temperature_only": ([0.7, 1.0, 1.3, 2.0, 0.2, 5.0], [0] * 6, [1] * 6),
+    "top_k_on_every_lane": ([0.7, 1.0, 1.3, 2.0, 0.2, 5.0],
+                            [1, 2, 40, 500, 7, 996], [1] * 6),
+    "mixed": ([0.0, 0.0, 0.9, 0.0, 1.5, 0.0], [0, 0, 5, 0, 0, 0],
+              [1] * 6),
+    "top_k_at_and_past_the_vocabulary": (
+        [1.0] * 6, [997, 998, 5000, 2 ** 31 - 1, 996, 0], [1] * 6),
+    "top_k_without_a_temperature": ([0.0] * 6, [3, 0, 40, 1, 0, 997],
+                                    [1] * 6),
+    "a_temperature_beside_top_k_on_a_greedy_lane": (
+        [0.0, 1.2, 0.0, 0.8, 0.0, 0.0], [9, 0, 0, 0, 3, 0], [1] * 6),
+    "dead_lane_with_stale_fields": ([0.0, 0.0, 3.0, 0.0, 0.0, 0.0],
+                                    [0, 0, 40, 0, 0, 0],
+                                    [1, 1, 0, 1, 1, 0]),
+    "dead_lane_beside_a_sampling_lane": ([0.0, 1.1, 3.0, 0.0, 0.0, 0.0],
+                                         [0, 0, 1, 0, 7, 0],
+                                         [1, 1, 0, 1, 1, 0]),
+}
+
+
+def sampler_round(name):
+    """(temperature, top_k, live) of `SAMPLER_ROUNDS[name]` as the
+    sampler takes them."""
+    temperature, top_k, live = SAMPLER_ROUNDS[name]
+    return (jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(live, bool))
+
+
+def sampler_logits(tied: bool):
+    logits = np.random.default_rng(38).normal(
+        size=(SAMPLER_LANES, SAMPLER_VOCAB)).astype(np.float32)
+    if tied:
+        # few distinct values: the row's best, its k-th largest and its
+        # smallest are each shared by many entries
+        logits = np.round(logits)
+    return jnp.asarray(logits)
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+@pytest.mark.parametrize("name", list(SAMPLER_ROUNDS))
+def test_sampler_serves_the_tokens_of_its_plain_form(name, tied):
+    """Whatever a round's lanes ask for, a live lane's token is the one
+    the unconditional sampler gives from the same key — and every
+    lane's where every lane is live."""
+    temperature, top_k, live = sampler_round(name)
+    logits = sampler_logits(tied)
+    for seed in range(3):
+        key = jax.random.PRNGKey(seed)
+        got = np.asarray(jax.jit(sample_tokens)(
+            logits, key, temperature, top_k, live))
+        want = np.asarray(sample_tokens_plain(logits, key, temperature,
+                                              top_k))
+        live = np.asarray(live)
+        np.testing.assert_array_equal(got[live], want[live])
+        assert got.dtype == np.int32
+
+
+def test_a_dead_lane_keeps_no_branch_alive():
+    """Which branch ran, read off the dead lane's own token: beside
+    live greedy lanes it is the argmax though its stale row asks for a
+    draw (no draw was made); beside a live lane with a temperature and
+    no `top_k` it is a free draw though its stale `top_k` is 1 (no
+    sort was made)."""
+    logits = sampler_logits(False)
+    best = np.argmax(np.asarray(logits), -1)
+    key = jax.random.PRNGKey(11)
+
+    stale = sampler_round("dead_lane_with_stale_fields")
+    plain = np.asarray(sample_tokens_plain(logits, key, *stale[:2]))
+    assert plain[2] != best[2]            # the draw would have shown
+    np.testing.assert_array_equal(sample_tokens(logits, key, *stale), best)
+    # top_k = 1 under the sort is the argmax; without it, a draw at
+    # temperature 3 over 997 logits
+    beside = sample_tokens(
+        logits, key, *sampler_round("dead_lane_beside_a_sampling_lane"))
+    assert beside[2] != best[2]
+
+
+def sample_rounds(engine):
+    """(`generation_sample_rounds_total_<path>` by path, the decode
+    rounds and prefills the engine served)."""
+    value = engine.registry.counter
+    return ({path: value(f"generation_sample_rounds_total_{path}").value
+             for path in SAMPLE_PATHS},
+            engine._h_decode.calls + engine._h_prefill.calls)
+
+
+@pytest.mark.parametrize("sampling, path", [
+    ({}, "argmax"), ({"top_k": 5}, "argmax"),
+    ({"temperature": 0.9}, "draw"),
+    ({"temperature": 0.9, "top_k": 5}, "sort")],
+    ids=["greedy", "top_k_without_a_temperature", "temperature", "top_k"])
+def test_sample_rounds_name_the_path_the_lanes_ask_for(lm, sampling,
+                                                       path):
+    """One increment a decode round and a prefill, all of them under
+    the path the run's lanes ask for; the scheduler's two counts are
+    back at nought when every lane is released."""
+    model, params = lm
+    engine = GenerationEngine(model, params, max_slots=2, block_size=8,
+                              max_context=64, registry=MetricsRegistry())
+    streams = [engine.submit(list(range(3, 9 + n)), max_new_tokens=5 + n,
+                             **sampling) for n in range(3)]
+    engine.run_until_idle()
+    assert [len(s.tokens()) for s in streams] == [5, 6, 7]
+    counts, served = sample_rounds(engine)
+    assert served >= 3 + 7
+    assert counts[path] == served == sum(counts.values())
+    assert (engine.scheduler.n_drawing, engine.scheduler.n_sorting) \
+        == (0, 0)
+
+
+def test_sample_rounds_follow_the_lanes_through_a_mixed_run(lm):
+    """A `top_k` request beside greedy ones costs its rounds the sort
+    and no round after it (one round's lead at most: the round
+    enqueued before its last token was collected); a preempted lane
+    leaves the counts as a released one does."""
+    model, params = lm
+    engine = GenerationEngine(model, params, max_slots=3, block_size=8,
+                              max_context=64, registry=MetricsRegistry())
+    engine.submit(list(range(5, 12)), max_new_tokens=20)
+    engine.submit(list(range(7, 12)), max_new_tokens=4, temperature=0.8,
+                  top_k=3)
+    engine.submit(list(range(2, 12)), max_new_tokens=20, temperature=0.0,
+                  top_k=7)
+    engine.step()
+    sched = engine.scheduler
+    assert (sched.n_drawing, sched.n_sorting) == (1, 1)
+    with engine._lock:
+        engine._drain("preempt")
+        while sched.slotted():
+            sched._preempt_newest()
+    assert (sched.n_drawing, sched.n_sorting) == (0, 0)
+    engine.run_until_idle()
+    assert (sched.n_drawing, sched.n_sorting) == (0, 0)
+    counts, served = sample_rounds(engine)
+    assert sum(counts.values()) == served
+    assert counts["draw"] == 0
+    # the sampled request's prefills (one, and one after the
+    # preemption) and its three or four decode rounds
+    assert 2 + 3 <= counts["sort"] <= 2 + 5
+    assert counts["argmax"] >= 4 + 15
 
 
 # ----------------------------------------------------------------------

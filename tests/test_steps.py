@@ -69,6 +69,19 @@ def build(model, counted, tp=None):
         counted=counted, tp=tp, prefill_variants=3)
 
 
+def state_by_hand(model):
+    """((params, pool, scales), lane state, a lane row's width) for
+    `build`'s programs: an empty pool and no lane active."""
+    layers, kv_heads, head_dim = pool_geometry(model)
+    cache = PagedKVCache(layers, 1 + LANES * BLOCKS_A_LANE, BS, kv_heads,
+                         head_dim)
+    width = lane_state.TABLE + BLOCKS_A_LANE
+    lanes = {"rows": jnp.zeros((LANES, width), jnp.int32),
+             "rng": jax.random.PRNGKey(3)}
+    return ((init(model), cache.kv, jnp.zeros((1,), jnp.float32)),
+            lanes, width)
+
+
 @pytest.mark.parametrize("make, counted", [(causal_lm, False),
                                            (decoder_lm, True)],
                          ids=["CausalLM", "DecoderLM"])
@@ -81,23 +94,65 @@ def test_builder_needs_no_engine(make, counted):
     built = build(model, counted)
     assert [s.family for s in built] == list(FAMILIES)
     assert [s.argnames for s in built] == list(FAMILIES.values())
-    layers, kv_heads, head_dim = pool_geometry(model)
-    cache = PagedKVCache(layers, 1 + LANES * BLOCKS_A_LANE, BS, kv_heads,
-                         head_dim)
-    width = lane_state.TABLE + BLOCKS_A_LANE
-    lanes = {"rows": jnp.zeros((LANES, width), jnp.int32),
-             "rng": jax.random.PRNGKey(3)}
+    state, lanes, width = state_by_hand(model)
     decode = built[2]
     kv, scale, nxt, last, after, *counts = decode(
-        init(model), cache.kv, jnp.zeros((1,), jnp.float32), lanes,
-        jnp.zeros((LANES, 1 + width), jnp.int32))
-    assert kv.shape == cache.kv.shape and nxt.shape == (LANES,)
+        *state, lanes, jnp.zeros((LANES, 1 + width), jnp.int32))
+    assert kv.shape == state[1].shape and nxt.shape == (LANES,)
     assert not np.asarray(last).any()            # dead lanes' logits
     np.testing.assert_array_equal(after["rows"], lanes["rows"])
     assert not np.array_equal(after["rng"], lanes["rng"])
     assert len(counts) == int(counted)
     if counted:
         assert not np.asarray(counts[0]).any()   # no lane, no token
+
+
+def sampler_work(jaxpr, vocab, inside=False):
+    """(primitive, inside a `cond` branch) of every sort over the
+    vocabulary and every draw of random bits in `jaxpr`, however deep."""
+    from jax._src import core
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "random_bits" or (
+                name == "sort"
+                and eqn.invars[0].aval.shape[-1:] == (vocab,)):
+            found.append((name, inside))
+        for sub in core.jaxprs_in_params(eqn.params):
+            found += sampler_work(sub, vocab, inside or name == "cond")
+    return found
+
+
+@pytest.mark.parametrize("family", ["prefill", "chunk_prefill", "decode"])
+@pytest.mark.parametrize("make, counted", [(causal_lm, False),
+                                           (decoder_lm, True)],
+                         ids=["CausalLM", "DecoderLM"])
+def test_sort_and_draw_sit_inside_a_branch(make, counted, family):
+    """The sampler's sort over the vocabulary and its random bits are
+    traced into every program that samples, and only ever inside a
+    `cond` branch: a round whose live lanes are greedy runs neither
+    (sampling.py).  The key's split stays outside, so the key sequence
+    does not depend on the branch."""
+    model = make()
+    built = dict(zip(FAMILIES, build(model, counted)))
+    state, lanes, width = state_by_hand(model)
+    args = {
+        "prefill": (lanes, jnp.zeros((1 + width + 8,), jnp.int32)),
+        "chunk_prefill": (
+            jnp.zeros((1, 8), jnp.int32), jnp.int32(0), jnp.int32(5),
+            jnp.zeros((BLOCKS_A_LANE,), jnp.int32),
+            jnp.zeros((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
+            lanes["rng"]),
+        "decode": (lanes, jnp.zeros((LANES, 1 + width), jnp.int32)),
+    }[family]
+    jaxpr = jax.make_jaxpr(built[family].fn)(*state, *args).jaxpr
+    work = sampler_work(jaxpr, model.vocab)
+    assert {name for name, _ in work} == {"sort", "random_bits"}
+    assert all(inside for _, inside in work), work
+    (program,) = jaxpr.eqns               # the jitted program itself
+    top = {eqn.primitive.name
+           for eqn in program.params["jaxpr"].jaxpr.eqns}
+    assert {"random_split", "argmax", "cond"} <= top
 
 
 def test_both_placements_wrap_the_same_families():

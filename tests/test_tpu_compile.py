@@ -319,6 +319,28 @@ def test_tuning_table_grouped_matmuls_compile_for_v5e(topo):
         _assert_kernel_compiles(build, _one_chip(topo), key)
 
 
+def _assert_sampler_in_a_branch(text, vocab, what):
+    """In a compiled step's `text`: the sampler's sort over
+    `f32[lanes, vocab]` is there, inside a conditional's branch, and
+    the entry computation — what every round runs — neither sorts nor
+    copies an array of the logits' shape: handing the logits to the
+    conditional moves them nowhere (sampling.py; PERF.md section 6,
+    PR 38).  A relayout the sort wants for itself sits in its branch
+    and is paid with it."""
+    import re
+    over_vocab = re.compile(rf"= \(?f32\[\d+,{vocab}\]\S*,? "
+                            r".*\b(?:copy|sort)\(")
+    entry, sorts, inside = [], [], False
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace():      # a computation's first line
+            inside = ln.startswith("ENTRY")
+        elif over_vocab.search(ln):
+            (entry if inside else sorts).append(ln.strip()[:300])
+    assert not entry, f"{what}: every round pays for {entry}"
+    assert any(" sort(" in ln and "/cond/branch_1_fun/" in ln
+               for ln in sorts), f"{what}: {sorts}"
+
+
 def _expert_models():
     """One expert layer of each expert configuration of the benchmark
     at its published widths (`kexaone_236b_ep8_serve`: gated experts
@@ -370,7 +392,8 @@ def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
     grouped product is the Pallas kernel — no `ragged-dot` left, whose
     TPU lowering multiplies a whole row tile for every group (PERF.md
     section 6, PR 36) — and no stacked expert kernel is copied on the
-    way to it."""
+    way to it.  The sampler at its end sorts in a branch alone, at the
+    cell's lanes and held vocabulary."""
     import re
 
     from analytics_zoo_tpu.ops import grouped
@@ -416,6 +439,7 @@ def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
     copies = [ln.strip()[:200] for ln in text.splitlines()
               if re.search(rf"= (?:{shapes})\S* copy\(", ln)]
     assert not copies, f"stacked expert kernels copied: {copies}"
+    _assert_sampler_in_a_branch(text, model.vocab, config)
 
 
 def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
@@ -427,7 +451,9 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
     a quarter of the pool.  The pool relaid out whole was 75% of
     serving's device time until PR 29 (PERF.md section 6); the cure
     engages on every step or not at all, so this compile is its
-    guard."""
+    guard.  And the sampler's (PR 38): its sort over the vocabulary
+    survives in a conditional's branch alone, and handing the logits
+    to that conditional copies them nowhere."""
     import re
 
     from analytics_zoo_tpu.serving.generation import (
@@ -477,6 +503,7 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
         copies = [ln.strip()[:200] for ln in text.splitlines()
                   if pool_copy.search(ln)]
         assert not copies, f"{name} copies the whole pool: {copies}"
+        _assert_sampler_in_a_branch(text, model.vocab, name)
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < pool_bytes // 4 + allowance[name], (
             f"{name}: {temp} bytes of temporaries beside a pool of "
