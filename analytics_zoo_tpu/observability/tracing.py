@@ -21,17 +21,29 @@ of the profiler's own trace, on the clock of the device's events, so an
 idle gap of the device can be put down to the span that was open in it.
 With no session open the annotation records nothing.  `phase(name)` is
 that annotation alone, for the phases of a hot loop.
+
+While a session records — `enabled()` — and only then, the program
+also reads what no reader of the trace can: a thread's own CPU clock.
+`mark(name, **counts)` is an instant annotation ``azt:<name>[k=v,…]``;
+a `LoopClock` charges a loop thread's CPU to the innermost open
+`phase()` in one round of every few and marks that round's sums, so
+that a phase's wall in the trace can be set against the CPU the thread
+had in it: the rest is the thread standing without the interpreter
+lock, or without a core.  `request_clock` and `mark_request` do the
+same for the thread that carries a request, one request in every
+tenth of a second.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
 from collections import deque
 from contextlib import contextmanager
 from contextvars import ContextVar
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 from analytics_zoo_tpu.observability.registry import (
     get_registry,
@@ -92,6 +104,28 @@ class Span:
 #: what every span is called in a profiler trace, before its own name
 TRACE_PREFIX = "azt:"
 _annotation = None
+#: the loop clocks inside a clocked round now, by their thread
+_clocked: Dict[int, "LoopClock"] = {}
+
+
+def _trace_annotation():
+    global _annotation
+    if _annotation is None:
+        # here and not at the top: a host-only consumer of this package
+        # imports no jax, and the import initialises no backend
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
+def enabled() -> bool:
+    """Whether a profiler session is recording, so that an annotation
+    made now lands in its trace (some 20 ns a call).  A process that
+    has not imported jax has no session, and is not made to import it
+    (the streaming client in a host-only process)."""
+    if _annotation is None and "jax" not in sys.modules:
+        return False
+    return (_annotation or _trace_annotation()).is_enabled()
 
 
 def phase(name: str):
@@ -99,14 +133,200 @@ def phase(name: str):
     round): a context manager that is the profiler annotation
     ``azt:<name>`` and nothing else — no `Span`, no ring entry, no
     histogram, no lock, no clock read.  It shows only in a profiler
-    trace, never in `recent_spans`."""
-    global _annotation
-    if _annotation is None:
-        # here and not at the top: a host-only consumer of this package
-        # imports no jax, and the import initialises no backend
-        from jax.profiler import TraceAnnotation
-        _annotation = TraceAnnotation
-    return _annotation(TRACE_PREFIX + name)
+    trace, never in `recent_spans`.  On a thread whose `LoopClock` is
+    inside a clocked round (a profiler session records) its two ends
+    are also that clock's boundaries."""
+    span = (_annotation or _trace_annotation())(TRACE_PREFIX + name)
+    if _clocked:
+        clock = _clocked.get(threading.get_ident())
+        if clock is not None:
+            return _ClockedPhase(span, clock, name)
+    return span
+
+
+def mark(name: str, **counts: int) -> None:
+    """An instant in a profiler trace: the annotation
+    ``azt:<name>[k=v,k=v,…]``, opened and closed at once, the counts
+    whole numbers in the event's name (a reader of the trace keeps an
+    event's name and drops its other fields).  With no session
+    recording nothing is formatted."""
+    if not enabled():
+        return
+    stats = ",".join(f"{k}={int(v)}" for k, v in counts.items())
+    with _annotation(f"{TRACE_PREFIX}{name}[{stats}]"):
+        pass
+
+
+#: seconds between two requests whose thread's CPU is clocked, for one
+#: mark's name: a read is a call into the kernel that keeps the
+#: interpreter lock, and a server that ends a hundred requests a
+#: second must not pay four hundred of them for it
+REQUEST_GAP_S = 0.1
+_request_due: Dict[str, float] = {}
+
+
+def request_clock(name: str) -> Optional[int]:
+    """A request begins on this thread.  Its CPU clock now, to be
+    taken from the reading at the request's end for the mark `name`
+    (``cpu.handler``, ``cpu.client``) — or None: of the requests that
+    begin within `REQUEST_GAP_S` of one that was clocked for the name,
+    none is.  Asked whether or not a session records: a request may
+    well begin before the session that sees it end."""
+    t = now()
+    if t < _request_due.get(name, 0.0):
+        return None
+    _request_due[name] = t + REQUEST_GAP_S
+    return time.thread_time_ns()
+
+
+def mark_request(name: str, cpu0: Optional[int], tokens: int) -> None:
+    """The request clocked at `cpu0` ends: where a session records,
+    the mark ``azt:<name>[tokens=…,cpu=…]``, the tokens it carried and
+    the microseconds of this thread's CPU it took."""
+    if cpu0 is not None and enabled():
+        mark(name, tokens=tokens,
+             cpu=(time.thread_time_ns() - cpu0) // 1000)
+
+
+class LoopClock:
+    """A loop thread's own CPU clock (`time.thread_time_ns`) by phase,
+    read only while a profiler session records, and then in one round
+    of `EVERY`: the observer must not become what is observed (a read
+    is a call into the kernel, 0.3 µs on Linux and 6 µs and more in a
+    sandbox that traps it, a dozen and a half a round).
+
+    The loop calls `arm()` where a round begins and, if that said yes,
+    `mark(name)` once the round's spans are closed.  The first round a
+    session records is clocked, and every `EVERY`-th after it; the
+    others cost `arm()` and `mark()` a comparison each, but for the one
+    before a clocked round, at whose end the clock is read once: a
+    clocked stretch runs from the end of the round before it to its
+    own (the turn between the two is the round's), the first from its
+    own beginning.  Within the stretch every `phase()` the thread opens
+    or closes is a boundary: the CPU since the boundary before it goes
+    to the bucket of the innermost phase open until then (the clock is
+    read only where that bucket changes: a phase inside one of its own
+    bucket costs no read).  `phases` names the bucket of a phase
+    ``<prefix><key>`` (counts in brackets cut off); a phase in `under`
+    gives its bucket to everything opened inside it; any other phase
+    of the prefix, and the time no phase is open (between rounds too),
+    goes to the last of `buckets`; a phase of another prefix is no
+    boundary of this loop's partition and stays with the phase around
+    it.  The mark carries the stretch: microseconds of wall, and
+    microseconds of this thread's CPU in each bucket.
+
+    The clock belongs to the thread: a reading is only subtracted from
+    one the same thread made, so a round on another thread than the
+    last starts anew, as the first round of a session does.  What the
+    numbers are worth is the platform's: under gVisor the thread clock
+    moves in steps of 10 ms (a sampled clock: sums over many stretches
+    hold, one stretch's fields do not)."""
+
+    #: of so many rounds one is clocked
+    EVERY = 8
+
+    def __init__(self, prefix: str, buckets: Sequence[str],
+                 phases: Dict[str, str], under: Dict[str, str]):
+        self.prefix = prefix
+        self.buckets = tuple(buckets)
+        self.phases = dict(phases)
+        self.under = dict(under)
+        self._rest = self.buckets[-1]
+        self._whole = frozenset(self.under.values())
+        self._thread: Optional[int] = None
+        #: rounds to go before the next clocked one (0: this one)
+        self._skip = 0
+        self._stack: List[str] = []
+        self._spent = dict.fromkeys(self.buckets, 0)
+        self._last, self._wall = 0, 0.0
+
+    def bucket(self, name: str, outer: Optional[str] = None) -> str:
+        """Where the CPU under phase `name` goes when it is opened
+        inside a phase of bucket `outer` (None: inside none)."""
+        outer = self._rest if outer is None else outer
+        if outer in self._whole or not name.startswith(self.prefix):
+            return outer
+        key = name[len(self.prefix):].partition("[")[0]
+        return self.under.get(key) or self.phases.get(key, self._rest)
+
+    def arm(self) -> bool:
+        """A round begins.  Whether a profiler session records: only
+        then does the round end in `mark()`, and only in a clocked
+        round is a clock read at the round's phases."""
+        if not enabled():
+            self._thread = None
+            return False
+        ident = threading.get_ident()
+        if ident != self._thread:
+            self._thread, self._skip = ident, 0
+            self._begin()
+        elif not self._skip:
+            self._charge(self._rest)
+        if not self._skip:
+            _clocked[ident] = self
+        return True
+
+    def _begin(self) -> None:
+        """A clocked stretch begins here."""
+        self._spent = dict.fromkeys(self.buckets, 0)
+        self._last = time.thread_time_ns()
+        self._wall = now()
+
+    def _charge(self, bucket: str) -> None:
+        cpu = time.thread_time_ns()
+        self._spent[bucket] += cpu - self._last
+        self._last = cpu
+
+    def _open(self, name: str) -> None:
+        stack = self._stack
+        outer = stack[-1] if stack else self._rest
+        bucket = self.bucket(name, outer)
+        if bucket != outer:
+            self._charge(outer)
+        stack.append(bucket)
+
+    def _close(self) -> None:
+        stack = self._stack
+        bucket = stack.pop()
+        if bucket != (stack[-1] if stack else self._rest):
+            self._charge(bucket)
+
+    def mark(self, name: str) -> None:
+        """The round armed is over.  A clocked one leaves its mark,
+        ``azt:<name>[…]``, and no boundary of this thread's is the
+        clock's until the next clocked round."""
+        if self._skip:
+            self._skip -= 1
+            if not self._skip:
+                self._begin()
+            return
+        _clocked.pop(self._thread, None)
+        self._charge(self._rest)
+        wall = now()
+        mark(name, wall=(wall - self._wall) * 1e6,
+             **{b: ns // 1000 for b, ns in self._spent.items()})
+        # where every round is clocked the next stretch begins here
+        self._spent = dict.fromkeys(self.buckets, 0)
+        self._wall = wall
+        self._skip = self.EVERY - 1
+
+
+class _ClockedPhase:
+    """A phase on a thread whose clock is armed: the annotation, opened
+    inside the clock's boundary and closed before it."""
+
+    __slots__ = ("_span", "_clock", "_name")
+
+    def __init__(self, span, clock: LoopClock, name: str):
+        self._span, self._clock, self._name = span, clock, name
+
+    def __enter__(self):
+        self._clock._open(self._name)
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._clock._close()
 
 
 def current_span() -> Optional[Span]:

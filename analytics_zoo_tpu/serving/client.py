@@ -20,7 +20,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from analytics_zoo_tpu.observability import trace_context
+from analytics_zoo_tpu.observability import trace_context, tracing
 from analytics_zoo_tpu.serving.codec import decode_ndarray, encode_ndarray
 
 
@@ -199,6 +199,10 @@ class InputQueue:
         self.last_traceparent = None
         self.last_model = None
         self.last_retries = 0
+        # what this thread asks of the interpreter lock a request:
+        # marked at the done line where a profiler session records
+        # then (this process's: a client beside the server it calls)
+        cpu0 = tracing.request_clock("cpu.client")
         max_attempts = retry.max_attempts if retry is not None else 1
         resp = None
         for attempt in range(1, max_attempts + 1):
@@ -252,6 +256,8 @@ class InputQueue:
                         f"serving error: {msg['error']}")
                 if msg.get("done"):
                     self.last_generate = msg
+                    tracing.mark_request("cpu.client", cpu0,
+                                         msg.get("n_tokens", 0))
                     return
                 yield msg["token"]
         raise RuntimeError("generation stream ended without a "

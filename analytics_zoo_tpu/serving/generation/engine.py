@@ -149,6 +149,24 @@ DRAINS = ("preempt", "cow", "host_restore", "chunk", "verify", "evict",
 #: draw
 SAMPLE_PATHS = ("argmax", "draw", "sort")
 
+#: the fields of the `cpu.loop` mark a clocked round leaves in a
+#: profiler trace (`step`) behind its microseconds of wall, and which
+#: of the loop's phases, as the innermost open one, the stepping
+#: thread's CPU is charged to.  It is the rule by which the benchmark
+#: charges the device's idle time to the same spans
+#: (`benchmarks/harness/span_metrics.py`, held to this by a test): a
+#: decode span's own time goes with its dispatch, everything under a
+#: `prefill` is the prefill's, and `wait`, `housekeeping` and the time
+#: between rounds are `off_round`
+CPU_BUCKETS = ("schedule", "prefill_host", "dispatch", "fetch",
+               "account", "emit", "off_round")
+CPU_PHASE = {"round": "schedule", "admit": "schedule",
+             "capacity": "schedule", "stage": "dispatch",
+             "dispatch": "dispatch", "decode": "dispatch",
+             "spec_verify": "dispatch", "fetch": "fetch",
+             "account": "account", "emit": "emit"}
+CPU_UNDER = {"prefill": "prefill_host"}
+
 
 class _Prefill(NamedTuple):
     """A prefill enqueued and not collected: its first token is still
@@ -549,6 +567,10 @@ class GenerationEngine:
         #: and, once the round's decode step is enqueued, its collection
         self._clock_prefill = step_clock("generation_prefill")
         self._clock_decode = step_clock("generation_decode")
+        #: the stepping thread's CPU by phase, read and marked in one
+        #: round of every few while a profiler session records (`step`)
+        self._cpu = tracing.LoopClock("generation.", CPU_BUCKETS,
+                                      CPU_PHASE, CPU_UNDER)
         #: speculative verify rounds get their own goodput track, so
         #: the Perfetto timeline shows them as distinct slices next to
         #: generation_decode (docs/observability.md)
@@ -1514,7 +1536,20 @@ class GenerationEngine:
         needs the host exact collects what is in flight first
         (`_drain`), and `run_until_idle()` and `generate()` return
         with nothing in flight.  Per request the order of emissions is
-        what it always was: the first token, then one a round."""
+        what it always was: the first token, then one a round.
+
+        While a profiler session records, in one round of every few
+        (`LoopClock.EVERY`) the calling thread's CPU clock is read
+        where the round's phases change hands, and the sums leave as
+        one mark after it (`CPU_PHASE`)."""
+        recording = self._cpu.arm()
+        try:
+            return self._round()
+        finally:
+            if recording:
+                self._cpu.mark("cpu.loop")
+
+    def _round(self) -> bool:
         with self._lock, tracing.phase("generation.round"):
             exact = self._exact_round()
             did = exact is not None and self._drain(exact)

@@ -138,6 +138,7 @@ from analytics_zoo_tpu.observability import (
     request_log,
     trace,
     trace_context,
+    tracing,
 )
 from analytics_zoo_tpu.serving.codec import (
     ARROW_CONTENT_TYPE,
@@ -634,6 +635,10 @@ class ServingServer:
                            if tparent is not None else {})
                 with trace("serving.generate", prompt=len(tokens),
                            request_id=rid, **span_kw) as span:
+                    # what this thread asks of the interpreter lock
+                    # a request: marked at the stream's end where a
+                    # profiler session records then
+                    cpu0 = tracing.request_clock("cpu.handler")
                     kw = dict(
                         max_new_tokens=int(req.get("max_new_tokens",
                                                    32)),
@@ -711,6 +716,7 @@ class ServingServer:
                              "finish_reason": stream.finish_reason,
                              "request_id": rid})
                             + "\n")
+                        tracing.mark_request("cpu.handler", cpu0, n)
                     except Exception as e:
                         # stream died mid-flight (engine stop/stuck,
                         # queue timeout): terminate the chunked body

@@ -1,0 +1,6 @@
+"""CPU the in-process streaming clients' threads took a token they carried, over the traced `cpu.client` marks: what they ask of the loop's interpreter lock."""
+from benchmarks.harness.cpu_marks import read as _read
+
+
+def read(ctx):
+    return _read(ctx, "client_us_per_token")

@@ -26,6 +26,8 @@ if ROOT not in sys.path:
 from analytics_zoo_tpu.observability import goodput, request_log, tracing
 from analytics_zoo_tpu.serving.generation import (
     CausalLM, GenerationEngine, lane_state)
+from analytics_zoo_tpu.serving.generation import engine as engine_module
+from benchmarks.harness import cpu_marks as bench_marks
 from benchmarks.harness import span_metrics
 from benchmarks.harness.trace_reduce import Trace
 from benchmarks.harness.tracing import Tracer
@@ -330,6 +332,198 @@ def test_a_step_records_phase_is_its_lap():
                                                   abs=2e-6)
 
 
+# --- the CPU marks: whose turn on the interpreter lock --------------------
+
+def cpu_marks(trace, kind):
+    """(start, fields) of the `cpu.<kind>` marks, by start, as the
+    benchmark parses them."""
+    return bench_marks.marks(trace, kind)
+
+
+def one_request(engine, n=5):
+    """A request through the HTTP server and the streaming client;
+    the loop thread runs the rounds."""
+    from analytics_zoo_tpu.serving import InputQueue, ServingServer
+    server = ServingServer(generation_engine=engine).start()
+    try:
+        client = InputQueue(server.host, server.port)
+        assert len(client.generate_tokens(PROMPTS[0][0],
+                                          max_new_tokens=n)) == n
+    finally:
+        server.stop()
+        engine.stop()
+
+
+def test_without_a_session_no_clock_is_read_in_a_round(engine, monkeypatch):
+    """No profiler session: the rounds read no CPU clock, arm no clock
+    and make no mark, whatever thread steps; a request costs its
+    handler and its client one clock read each, at its start."""
+    reads, made = [], []
+    real_clock, real_mark = time.thread_time_ns, tracing.mark
+    monkeypatch.setattr(time, "thread_time_ns",
+                        lambda: reads.append(1) or real_clock())
+    monkeypatch.setattr(tracing, "mark", lambda name, **kw: (
+        made.append(name), real_mark(name, **kw))[1])
+    monkeypatch.setattr(tracing, "_request_due", {})
+    assert not tracing.enabled()
+    streams = [engine.submit(p, max_new_tokens=n) for p, n in PROMPTS]
+    engine.run_until_idle()                 # the caller's thread
+    assert [len(s.tokens()) for s in streams] == [4, 6, 8]
+    serve(engine)                           # the loop's thread
+    assert reads == [] and made == [] and not tracing._clocked
+    one_request(engine)
+    assert len(reads) == 2 and made == [] and not tracing._clocked
+    # and a request that begins within a tenth of a second of one
+    # that was clocked costs neither a read
+    monkeypatch.setattr(tracing, "REQUEST_GAP_S", 3600.0)
+    tracing._request_due.clear()
+    one_request(engine)
+    one_request(engine)
+    assert len(reads) == 4 and made == []
+
+
+def test_one_round_of_every_few_is_clocked_and_marked(engine, monkeypatch):
+    """Under a CPU clock that moves a millisecond a read: a bucket is
+    charged 1,000 µs for every time the clock changed hands out of
+    it, whatever the platform's real clock is worth."""
+    reads = []
+    monkeypatch.setattr(
+        time, "thread_time_ns",
+        lambda: reads.append(1) or len(reads) * 1_000_000)
+    monkeypatch.setattr(engine._cpu, "EVERY", 3, raising=False)
+    streams = [engine.submit(p, max_new_tokens=12)
+               for p, _ in PROMPTS[:2]]
+    engine.step()               # no session yet: nothing to reach back to
+    time.sleep(0.3)
+    assert reads == []
+    in_round = []
+    with session() as tracer:
+        streams.append(engine.submit(PROMPTS[2][0], max_new_tokens=12))
+        for _ in range(7):
+            before = len(reads)
+            engine.step()
+            in_round.append(len(reads) - before)
+        trace = reduced(tracer)
+    engine.run_until_idle()
+    assert [len(s.tokens()) for s in streams] == [12, 12, 12]
+    rounds = azt(trace, "generation.round")
+    marks = cpu_marks(trace, "loop")
+    assert len(rounds) == 7 and len(marks) == 3
+    # rounds 0, 3 and 6 are clocked; the round before a clocked one
+    # reads the clock once, at its end; the others never
+    assert in_round[1::3] == [0, 0] and in_round[2::3] == [1, 1]
+    assert all(n > 6 for n in in_round[0::3])
+    buckets = engine_module.CPU_BUCKETS
+    for i, (at, fields) in zip((0, 3, 6), marks):
+        # behind its round and before the next: under no phase
+        assert rounds[i][2] <= at
+        assert i == 6 or at < rounds[i + 1][1]
+        assert list(fields) == ["wall", *buckets]
+        # every read of the stretch but its first charged one bucket
+        stretch = in_round[i] + (in_round[i - 1] if i else 0)
+        assert sum(fields[b] for b in buckets) == (stretch - 1) * 1000
+        # this thread's clock changed hands out of every phase a
+        # decode round has, and out of a prefill where there was one
+        assert all(fields[b] >= 1000 for b in buckets
+                   if b != "prefill_host")
+        assert (fields["prefill_host"] >= 1000) == (i == 0)
+    # the first mark covers its own round, not the 0.3 s before the
+    # session; a later one reaches back to the end of the round before
+    assert marks[0][1]["wall"] < 300_000
+    for i, (at, fields) in zip((3, 6), marks[1:]):
+        assert fields["wall"] == pytest.approx(
+            (at - rounds[i - 1][2]) / 1e3, rel=0.05, abs=500)
+
+
+def test_the_loop_threads_rounds_and_a_request_leave_their_marks(
+        engine, monkeypatch):
+    monkeypatch.setattr(tracing, "_request_due", {})
+    with session() as tracer:
+        one_request(engine)
+        trace = reduced(tracer)
+    rounds = azt(trace, "generation.round")
+    marks = cpu_marks(trace, "loop")
+    assert len(marks) == -(-len(rounds) // engine._cpu.EVERY) > 0
+    buckets = engine_module.CPU_BUCKETS
+    for (at, fields), clocked in zip(marks,
+                                     rounds[::engine._cpu.EVERY]):
+        assert clocked[2] <= at
+        assert all(v >= 0 for v in fields.values())
+        # a thread has no more CPU than wall, but for a tick of its
+        # clock (10 ms where the platform samples it)
+        assert sum(fields[b] for b in buckets) \
+            <= fields["wall"] * 1.02 + 10_000
+    # the benchmark's reduction reads the program's own marks
+    read = bench_marks.reduced({"trace": trace})
+    assert read["rounds"] == len(marks) and read["round"] > 0
+    for kind in ("handler", "client"):
+        (_, fields), = cpu_marks(trace, kind)
+        assert fields["tokens"] == 5 and fields["cpu"] >= 0, kind
+        assert read[kind + "_us_per_token"] == fields["cpu"] / 5
+    # the marks are no span of the loop's: its readers see none
+    assert not [s for s in span_metrics.engine_spans(trace)
+                if "cpu" in s.name]
+
+
+def test_one_request_in_a_tenth_of_a_second_is_clocked(monkeypatch):
+    """A name's clock is due again `REQUEST_GAP_S` after the request
+    it was last read for began, each name on its own; a request that
+    was not clocked leaves no mark, nor does one without a session."""
+    t, made = [100.0], []
+    monkeypatch.setattr(tracing, "_request_due", {})
+    monkeypatch.setattr(tracing, "now", lambda: t[0])
+    monkeypatch.setattr(tracing, "mark",
+                        lambda name, **kw: made.append((name, kw)))
+    assert tracing.request_clock("cpu.handler") is not None
+    assert tracing.request_clock("cpu.client") is not None
+    t[0] += tracing.REQUEST_GAP_S / 2
+    assert tracing.request_clock("cpu.handler") is None
+    t[0] += tracing.REQUEST_GAP_S / 2
+    cpu0 = tracing.request_clock("cpu.handler")
+    assert cpu0 is not None
+    assert tracing.request_clock("cpu.handler") is None
+    tracing.mark_request("cpu.handler", cpu0, 7)      # no session
+    monkeypatch.setattr(tracing, "enabled", lambda: True)
+    tracing.mark_request("cpu.handler", None, 7)      # not clocked
+    assert made == []
+    tracing.mark_request("cpu.handler", cpu0, 7)
+    (name, fields), = made
+    assert name == "cpu.handler" and fields["tokens"] == 7
+    assert fields["cpu"] >= 0
+
+
+def test_the_loops_cpu_goes_where_its_idle_time_goes():
+    """The program's rule for a phase's CPU is the benchmark's rule
+    for the device's idle time under the same spans: every span of
+    PERF.md's table under every chain of parents a round has."""
+    clock = tracing.LoopClock("generation.", engine_module.CPU_BUCKETS,
+                              engine_module.CPU_PHASE,
+                              engine_module.CPU_UNDER)
+
+    def program(chain):
+        bucket = None
+        for name in chain:
+            bucket = clock.bucket("generation." + name, bucket)
+        return bucket
+
+    def reader(chain):
+        span = span_metrics.Span(chain[-1].split("[")[0], 0, 0,
+                                 tuple(chain[:-1]))
+        return span_metrics.phase_of(span) or "off_round"
+
+    names = sorted(set(span_metrics.LEAF_PHASE)
+                   | {"prefill", "wait", "housekeeping",
+                      "decode[l=3,w=0]"})
+    chains = [parents + (name,) for name in names for parents in (
+        (), ("round",), ("round", "decode"), ("round", "prefill"),
+        ("round", "decode", "prefill"), ("round", "spec_verify"))]
+    assert ({c: program(c) for c in chains}, engine_module.CPU_BUCKETS,
+            program(("round", "dispatch")) and
+            clock.bucket("serving.run_batch", "dispatch")) == (
+        {c: reader(c) for c in chains},
+        span_metrics.PHASES + ("off_round",), "dispatch")
+
+
 def test_the_annotation_is_made_in_one_place():
     made = []
     for path in glob.glob(os.path.join(ROOT, "analytics_zoo_tpu", "**",
@@ -342,7 +536,8 @@ def test_the_annotation_is_made_in_one_place():
 
 # --- PERF.md's span table and the code name the same spans ---------------
 
-SPAN_CALL = re.compile(r"(?:\btrace|\bphase)\(\s*f?[\"']([A-Za-z0-9_.]+)")
+SPAN_CALL = re.compile(
+    r"(?:\btrace|\bphase|\bmark(?:_request)?)\(\s*f?[\"']([A-Za-z0-9_.]+)")
 
 
 def spans_in_the_code():
@@ -369,6 +564,7 @@ def spans_in_perf_md():
 def test_perf_md_names_every_span_and_no_other():
     code, doc = spans_in_the_code(), spans_in_perf_md()
     assert {"generation.round", "generation.decode", "spmd.input_wait",
-            "estimator.fit", "serving.http_request"} <= code
+            "estimator.fit", "serving.http_request", "cpu.loop",
+            "cpu.handler", "cpu.client"} <= code
     assert code - doc == set(), "spans of the code PERF.md does not name"
     assert doc - code == set(), "spans PERF.md names that no code opens"
