@@ -149,6 +149,13 @@ DRAINS = ("preempt", "cow", "host_restore", "chunk", "verify", "evict",
 #: draw
 SAMPLE_PATHS = ("argmax", "draw", "sort")
 
+#: which pool writer a dispatched prefill's program was built with (the
+#: suffixes of `generation_prefill_pool_writes_total_<form>`;
+#: kv_cache.py): whole blocks for a whole prompt (`prefill`), single
+#: rows for a chunk, whose start need not be a block's
+#: (`chunk_prefill`)
+POOL_WRITES = ("block", "row")
+
 #: the fields of the `cpu.loop` mark a clocked round leaves in a
 #: profiler trace (`step`) behind its microseconds of wall, and which
 #: of the loop's phases, as the innermost open one, the stepping
@@ -490,6 +497,12 @@ class GenerationEngine:
                 help="decode rounds and prefills whose sampler ran, by "
                      f"what the host's lanes asked of it: {path}")
             for path in SAMPLE_PATHS}
+        self._c_pool_writes = {
+            form: reg.counter(
+                f"generation_prefill_pool_writes_total_{form}",
+                help="prefills dispatched, by how their program writes "
+                     f"the prompt's keys and values into the pool: {form}")
+            for form in POOL_WRITES}
         reg.gauge("generation_cache_occupancy",
                   fn=self.cache.allocator.occupancy,
                   help="fraction of KV blocks held by live sequences")
@@ -967,6 +980,7 @@ class GenerationEngine:
                     self.params, self.cache.kv, self._kv_scale,
                     lanes.state, request)
                 self._store_kv_state(kv, scl)
+                self._c_pool_writes["block"].inc()
                 self._sampled(seq.temperature > 0, seq.top_k > 0)
                 if self.state_pool is not None:
                     self._c_state_resets.inc()
@@ -1064,6 +1078,7 @@ class GenerationEngine:
                     jnp.full(1, seq.temperature, jnp.float32),
                     jnp.full(1, seq.top_k, jnp.int32), state["rng"])
                 self._store_kv_state(kv, scl)
+                self._c_pool_writes["row"].inc()
                 self._sampled(seq.temperature > 0, seq.top_k > 0)
             with rec.phase("generation.fetch", "device_compute"):
                 nxt = int(nxt)            # token fetch = device fence

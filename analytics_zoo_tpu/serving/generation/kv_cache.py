@@ -19,11 +19,20 @@ minor pair of (heads, head_dim) = (12, 64) fits neither, so the old
 every program and back out (three whole-pool copies a decode step);
 a merged row of 768 is six full lanes and a block of 16 rows one
 sublane tile, so `block_view` is a bitcast and a paged-kernel DMA
-lands on whole tiles.  The write is `write_kv`: ONE scatter of rows
-whose window is a single row, which XLA performs in place on the
-donated pool (a scatter whose window spans the layer axis made it
-move that axis inward around the write: two more whole-pool copies).
-A head shard under tensor parallelism is a contiguous slice of the
+lands on whole tiles.  The pool has two writers, each ONE scatter
+that XLA performs in place on the donated pool, indexed by (layer,
+k/v, ...) so that no window spans the layer axis (one that did made
+XLA move that axis inward around the write: two more whole-pool
+copies).  `write_kv` moves single rows to token slots: what decode
+(one row a lane), a chunk (a start that need not be a block's) and
+verify have.  `write_kv_blocks` moves whole blocks of the block view
+to block ids: a whole prompt's rows, which start a block and run on
+from there (the `prefill` program).  The chip pays a scatter by the
+window, not by its bytes — 0.11 us a row whatever its width — so a
+1,024-token prompt's 24,576 rows cost 2.9 ms where its 1,536 blocks
+cost 0.27 (PERF.md section 6, PR 40).  Which writer a program takes
+follows from which program it is, and from nothing else.  A head
+shard under tensor parallelism is a contiguous slice of the
 merged axis (`KV_TP_SPEC`).  Block 0 is reserved as the NULL block:
 inactive slots' table entries (and padding writes) all point at it,
 so dead lanes scribble harmlessly instead of branching — that is what
@@ -145,7 +154,8 @@ def _row_index(kv, slots):
     """(layer, k/v, slot) index arrays that broadcast to
     [L, rows, *slots.shape] of pool `kv` (rows: 2, or 1 in the latent
     form): a gather or scatter through them moves single
-    ROWS of the pool, which XLA does on the pool as it lies (indexing
+    ROWS of the pool — whole blocks of its block view, `slots` then
+    being block ids — which XLA does on the pool as it lies (indexing
     `kv[:, :, slots]` instead makes the window span the layer and k/v
     axes, and XLA relays the whole pool out to bring them inward)."""
     n_layers, rows = kv.shape[:2]
@@ -173,32 +183,76 @@ def gather_kv(kv, kv_scale, tok_idx, n_head: int,
     return rows[:, 0], (rows[:, 1] if kv.shape[1] == 2 else None)
 
 
-def write_kv(kv, kv_scale, dest, new_k, new_v):
-    """Write new_k / new_v [L, n, heads, head_dim] into token slots
-    `dest` [n] of every layer — the one pool write of the prefill,
-    chunk, decode and verify programs; a latent pool takes its one row
-    a token as `new_k`, `new_v` None, and stores it padded with zeros
-    to the pool's columns.  ONE scatter of rows: index
-    (layer, k/v, slot), window a single merged row, so XLA updates the
-    donated pool in place whatever `n` is (a `kv.at[:, 0, dest]` window
-    spans the layer axis and costs two whole-pool relayouts).  An int8
-    pool quantizes on write (per-token-slot symmetric scales —
-    `quantize_kv_tokens`), so a dequantized pool never exists and
-    appends never touch already-written slots; otherwise `kv_scale`
-    passes through.  Duplicate slots only ever name the null block
-    (dead lanes, padding), where any winner is harmless."""
+def _stored_rows(kv, new_k, new_v):
+    """new_k / new_v [L, n, heads, head_dim] as pool `kv` stores them:
+    (rows [L, rows, n, columns] — a key and a value, or the one latent
+    row padded with zeros to the pool's columns — in the pool's dtype,
+    their scales [L, rows, n] or None).  An int8 pool quantizes here,
+    a token at a time (`quantize_kv_tokens`), so a dequantized pool
+    never exists and appends never touch already-written slots."""
     L, n = new_k.shape[:2]
     rows = jnp.stack([new_k] if new_v is None else [new_k, new_v],
                      axis=1)                       # [L, rows, n, h, d]
-    idx = _row_index(kv, dest)
+    scales = None
     if kv.dtype == jnp.int8:
         rows, scales = quantize_kv_tokens(rows)
-        kv_scale = kv_scale.at[idx].set(scales)
     rows = rows.reshape(L, kv.shape[1], n, -1).astype(kv.dtype)
     if rows.shape[-1] != kv.shape[-1]:
         rows = jnp.pad(rows, ((0, 0),) * 3
                        + ((0, kv.shape[-1] - rows.shape[-1]),))
+    return rows, scales
+
+
+def write_kv(kv, kv_scale, dest, new_k, new_v):
+    """Write new_k / new_v [L, n, heads, head_dim] into token slots
+    `dest` [n] of every layer — the pool write of the programs whose
+    rows fall where they fall: decode (one a lane), a chunk (a start
+    that need not be a block's) and verify; a latent pool takes its
+    one row a token as `new_k`, `new_v` None (`_stored_rows`).  ONE
+    scatter of rows: index (layer, k/v, slot), window a single merged
+    row, so XLA updates the donated pool in place whatever `n` is (a
+    `kv.at[:, 0, dest]` window spans the layer axis and costs two
+    whole-pool relayouts).  An int8 pool's scales are written beside
+    its rows; otherwise `kv_scale` passes through.  Duplicate slots
+    only ever name the null block (dead lanes, padding), where any
+    winner is harmless."""
+    rows, scales = _stored_rows(kv, new_k, new_v)
+    idx = _row_index(kv, dest)
+    if scales is not None:
+        kv_scale = kv_scale.at[idx].set(scales)
     return kv.at[idx].set(rows), kv_scale
+
+
+def write_kv_blocks(kv, kv_scale, blocks, new_k, new_v, block_size: int):
+    """`write_kv` for rows that start a block and run on from there —
+    a whole prompt's, positions 0..n-1 (the `prefill` program): new_k
+    / new_v [L, n, heads, head_dim] go into pool blocks `blocks`
+    [ceil(n / block_size)], in order.  ONE scatter into `block_view`
+    whose index is (layer, k/v, block) and whose window is a whole
+    block [block_size, columns] — the chip pays a scatter by the
+    window, not by its bytes, and a block is block_size rows in one
+    (whole tile rows of the pool, so still in place on the donated
+    array; the reshape back is the bitcast `block_view` is).  Every
+    block named is written WHOLE: the caller names the null block for
+    those past the prompt's last row, and the rows of the last real
+    block past that row take whatever `new_k` holds there (the padded
+    positions' finite keys and values; zeros short of a whole block) —
+    slots no reader looks at, every read being cut at the lane's
+    context length, until a decode round has written them."""
+    short = blocks.shape[0] * block_size - new_k.shape[1]
+    if short:
+        new_k, new_v = (
+            x if x is None else jnp.pad(x, ((0, 0), (0, short), (0, 0),
+                                            (0, 0)))
+            for x in (new_k, new_v))
+    rows, scales = _stored_rows(kv, new_k, new_v)
+    idx = _row_index(kv, blocks)
+    if scales is not None:
+        kv_scale = block_view(kv_scale, block_size).at[idx].set(
+            block_view(scales, block_size)).reshape(kv_scale.shape)
+    kv = block_view(kv, block_size).at[idx].set(
+        block_view(rows, block_size)).reshape(kv.shape)
+    return kv, kv_scale
 
 
 class RecurrentStatePool:

@@ -18,11 +18,13 @@ from analytics_zoo_tpu.observability import profiling
 from analytics_zoo_tpu.serving.generation import lane_state
 from analytics_zoo_tpu.serving.generation.decoder import MOE_COUNTS
 from analytics_zoo_tpu.serving.generation.kv_cache import (
+    NULL_BLOCK,
     admit_state,
     block_view,
     gather_kv,
     pool_geometry,
     write_kv,
+    write_kv_blocks,
 )
 from analytics_zoo_tpu.serving.generation.sampling import sample_tokens
 
@@ -121,11 +123,16 @@ def build_steps(model, *, block_size: int, n_head: int, quantized: bool,
         token_mask = (jnp.arange(B) < length)[None]
         (logits, new_k, new_v, *fresh), counts = apply(
             params, tokens, pos[None], token_mask=token_mask)
-        dest = block_table[jnp.arange(B) // bs] * bs \
-            + jnp.arange(B) % bs
-        dest = jnp.where(jnp.arange(B) < length, dest, 0)
-        kv, kv_scale = write_kv(kv, kv_scale, dest,
-                                *each(lambda x: x[:, 0], new_k, new_v))
+        # a prompt starts its first block, so its rows go in by whole
+        # blocks (kv_cache.write_kv_blocks); those wholly past
+        # `length` name the null block, as padding rows do elsewhere
+        # (an index past a short table is clamped, then masked)
+        nth = jnp.arange(-(-B // bs))
+        blocks = jnp.where(nth * bs < length, block_table[nth],
+                           NULL_BLOCK)
+        kv, kv_scale = write_kv_blocks(
+            kv, kv_scale, blocks,
+            *each(lambda x: x[:, 0], new_k, new_v), bs)
         last = logits[0, length - 1]
         rng, sub = jax.random.split(lanes["rng"])
         nxt = sample_tokens(last[None], sub, temperature[None],

@@ -175,6 +175,166 @@ def test_write_kv_rows_match_the_layerwise_scatter(quantization, n_rows):
                                atol=tol)
 
 
+POOLS = {
+    # kind: (PagedKVCache keywords, heads, head_dim)
+    "bf16": (dict(dtype=jnp.bfloat16), 2, 8),
+    "int8": (dict(quantization="int8"), 2, 8),
+    # one row a token, its 100 columns stored padded to 128
+    "latent": (dict(rows=1), 1, 100),
+}
+
+
+@pytest.mark.parametrize("n, length", [
+    (16, 8), (16, 6), (16, 1), (16, 16), (14, 14), (14, 3)],
+    ids=["blocks", "mid_block", "one", "whole_bucket",
+         "ragged_bucket", "ragged_bucket_short"])
+@pytest.mark.parametrize("kind", list(POOLS))
+def test_write_kv_blocks_match_the_row_write(kind, n, length):
+    """`write_kv_blocks` — a prompt's rows by whole blocks, ONE scatter
+    into the block view — against `write_kv` with the same rows by
+    token slot, on a seeded pool: every slot below `length` bit-equal,
+    scales included; every block that is neither the prompt's nor the
+    null block as it was before.  (The last real block's rows past
+    `length` differ by design: the block form writes the block
+    whole.)"""
+    from analytics_zoo_tpu.serving.generation.kv_cache import (
+        write_kv, write_kv_blocks)
+    kw, h, d = POOLS[kind]
+    L, nb, bs = 3, 9, 4
+    rng = np.random.default_rng(40 + n + length)
+    c = PagedKVCache(n_layers=L, num_blocks=nb, block_size=bs, n_head=h,
+                     head_dim=d, **kw)
+    if kind == "int8":
+        kv = jnp.asarray(rng.integers(-127, 128, c.kv.shape), jnp.int8)
+        scale = jnp.asarray(rng.uniform(0.01, 0.1, c.kv_scale.shape),
+                            jnp.float32)
+    else:
+        kv = jnp.asarray(rng.normal(size=c.kv.shape), c.kv.dtype)
+        scale = jnp.zeros((1,), jnp.float32)    # the engine's placeholder
+    table = 1 + rng.permutation(nb - 1)[:-(-n // bs)]
+    pos = np.arange(n)
+    dest = jnp.asarray(np.where(
+        pos < length, table[pos // bs] * bs + pos % bs, 0), jnp.int32)
+    blocks = jnp.asarray(np.where(
+        np.arange(len(table)) * bs < length, table, 0), jnp.int32)
+    new_k = jnp.asarray(rng.normal(size=(L, n, h, d)), jnp.float32)
+    new_v = None if c.rows == 1 else jnp.asarray(
+        rng.normal(size=(L, n, h, d)), jnp.float32)
+    want_kv, want_scale = write_kv(kv, scale, dest, new_k, new_v)
+    got_kv, got_scale = write_kv_blocks(kv, scale, blocks, new_k, new_v,
+                                        bs)
+    assert got_kv.shape == kv.shape and got_kv.dtype == kv.dtype
+    live = np.asarray(dest)[:length]
+    assert len(set(live)) == length and live.min() >= bs
+    np.testing.assert_array_equal(np.asarray(got_kv)[:, :, live],
+                                  np.asarray(want_kv)[:, :, live])
+    assert np.asarray(got_kv)[:, :, live].any()
+    written = set(np.asarray(blocks).tolist()) | {0}
+    others = np.concatenate(
+        [np.arange(b * bs, (b + 1) * bs) for b in range(nb)
+         if b not in written])
+    np.testing.assert_array_equal(np.asarray(got_kv)[:, :, others],
+                                  np.asarray(kv)[:, :, others])
+    if kind == "int8":
+        np.testing.assert_array_equal(np.asarray(got_scale)[:, :, live],
+                                      np.asarray(want_scale)[:, :, live])
+        np.testing.assert_array_equal(np.asarray(got_scale)[:, :, others],
+                                      np.asarray(scale)[:, :, others])
+    else:
+        assert got_scale is scale
+    if kind == "latent":        # the padding columns are written as zeros
+        assert not np.asarray(got_kv)[:, :, live, d:].any()
+
+
+def test_a_prompt_that_ends_mid_block_decodes_what_a_recompute_does(lm):
+    """The `prefill` program writes a prompt's last block WHOLE
+    (`write_kv_blocks`): its rows past the prompt hold the padded
+    positions' keys and values.  A prompt of 21 tokens over blocks of
+    16, then 28 decode rounds (through the rest of that block and on
+    into the next): every round's logits are the full recompute's at
+    that position and the tokens the concat oracle's — each of those
+    rows is written by a decode round before any round reads it."""
+    model, params = lm
+    prompt = list(np.random.default_rng(40).integers(0, VOCAB, 21))
+    served = {}
+    for attention in ("paged", "concat"):
+        engine = GenerationEngine(
+            model, params, max_slots=2, block_size=16, max_context=64,
+            prefill_buckets=[32, 64], decode_attention=attention,
+            registry=MetricsRegistry())
+        step, taken = engine._decode_jit.fn, []
+
+        def tapped(*args, step=step, taken=taken):
+            out = step(*args)
+            taken.append(np.asarray(out[3][0]))
+            return out
+        engine._decode_jit.fn = tapped
+        tokens = engine.generate(prompt, max_new_tokens=29)
+        served[attention] = tokens, np.stack(taken)
+        counts = engine.registry.snapshot()
+        assert counts["generation_prefill_pool_writes_total_block"] == 1
+        assert counts["generation_prefill_pool_writes_total_row"] == 0
+    tokens, logits = served["paged"]
+    assert tokens == served["concat"][0] and len(tokens) == 29
+    seq = prompt + tokens
+    want, _, _ = model.apply(
+        {"params": params}, jnp.asarray(seq)[None],
+        jnp.arange(len(seq))[None], token_mask=jnp.ones((1, len(seq))))
+    # decode round i reads the context up to the i-th served token
+    want = np.asarray(want[0])[len(prompt):len(prompt) + len(logits)]
+    assert len(logits) >= 28
+    for got in (logits, served["concat"][1]):
+        np.testing.assert_allclose(got, want, atol=1e-4)
+
+
+def _toy(kind):
+    """(model, params) of each kind of model a serving cell runs, at
+    toy widths: the other test files' own."""
+    if kind == "CausalLM":
+        model = CausalLM(vocab=VOCAB, hidden_size=32, n_head=4, n_block=2,
+                         intermediate_size=64, max_position_len=256)
+        return model, model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+            jnp.arange(8)[None])["params"]
+    import importlib
+    module, cls = {
+        "DecoderLM": ("test_decoder_lm", "DecoderLM"),
+        "HybridLM": ("test_hybrid_lm", "HybridLM"),
+        "latent_DecoderLM": ("test_latent_attention", "DecoderLM"),
+    }[kind]
+    cases = importlib.import_module(module)
+    model = getattr(cases, cls).from_config(cases.toy_config())
+    return model, cases.seeded(model)
+
+
+@pytest.mark.parametrize("kind", ["CausalLM", "DecoderLM", "HybridLM",
+                                  "latent_DecoderLM"])
+def test_every_whole_prompt_prefill_writes_blocks(kind):
+    """Each serving cell's kind of model, at toy widths, through the
+    engine as the cells configure it (no prefix cache, no chunks):
+    `generation_prefill_pool_writes_total_block` counts every prefill
+    dispatched — admissions and a preempted lane's resume — and `_row`
+    (a chunk's write of single rows) stays 0."""
+    model, params = _toy(kind)
+    vocab = model.vocab
+    engine = GenerationEngine(model, params, max_slots=3, block_size=4,
+                              max_context=64, num_blocks=14,
+                              prefill_buckets=[8, 16, 32, 64],
+                              registry=MetricsRegistry())
+    engine.warmup()
+    rng = np.random.default_rng(41)
+    streams = [engine.submit(list(rng.integers(0, vocab, n)),
+                             max_new_tokens=12)
+               for n in (5, 17, 9, 22, 13)]
+    engine.run_until_idle()
+    assert all(len(s.tokens()) == 12 for s in streams)
+    counts = engine.registry.snapshot()
+    prefills = len(streams) + engine.scheduler.n_preemptions
+    assert counts["generation_prefill_pool_writes_total_block"] == prefills
+    assert counts["generation_prefill_pool_writes_total_row"] == 0
+    assert counts["generation_prefill_seconds"]["calls"] == prefills
+
+
 # ----------------------------------------------------------------------
 # logit equivalence: KV-cached decode == full-sequence recompute
 # ----------------------------------------------------------------------

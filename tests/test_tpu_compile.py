@@ -508,3 +508,24 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
         assert temp < pool_bytes // 4 + allowance[name], (
             f"{name}: {temp} bytes of temporaries beside a pool of "
             f"{pool_bytes}")
+        if name != "prefill":
+            continue
+        # a prompt's rows go in by whole blocks (PR 40,
+        # kv_cache.write_kv_blocks): the one scatter left is on the
+        # block view (as it lies, or its leading axes merged) with a
+        # window of a whole block, and none on the row view, whose
+        # window is a row — 1,024 of them cost the chip what 1,024
+        # blocks would
+        scatters = [ln.strip() for ln in text.splitlines()
+                    if re.search(r"= bf16\[[\d,]+\]\S* scatter\(", ln)]
+        on_rows = re.compile(
+            rf"= bf16\[(?:{l},2,{slots}|{l * 2 * slots}),{hd}\]")
+        on_blocks = re.compile(
+            rf"= bf16\[(?:{l},2,{slots // 16}|{l * 2 * slots // 16})"
+            rf",16,{hd}\]")
+        assert not [ln[:200] for ln in scatters if on_rows.search(ln)]
+        by_block = [ln for ln in scatters if on_blocks.search(ln)]
+        assert len(by_block) == 1, [ln[:200] for ln in scatters]
+        window = re.search(r"update_window_dims=\{([\d,]+)\}",
+                           by_block[0]).group(1)
+        assert len(window.split(",")) == 2, by_block[0][:400]
