@@ -246,7 +246,8 @@ def _paged_context(kv_pool, kv_scale, layer, which, block_tables, h):
     """Every lane's table blocks of `layer`'s K (which=0) or V (1),
     gathered out of the pool's block view as a [S, C, h, d] context and
     dequantized when scales ride along.  Heads are split out of what
-    was gathered, never of the pool."""
+    was gathered, never of the pool; a traced `layer` is one more index
+    of the same gather."""
     blocks = kv_pool[layer, which, block_tables]     # [S, MB, bs, h*d]
     s, mb, bs, hd = blocks.shape
     ctx = blocks.reshape(s, mb * bs, h, hd // h)
@@ -284,9 +285,11 @@ def paged_decode_attention(q, new_k, new_v, kv_pool, block_tables,
     (it attends to itself in addition to the cache).
     kv_pool: [n_layers, 2, num_blocks, block_size, heads * head_dim] —
     the WHOLE paged pool in the form it is stored in (the block view of
-    `PagedKVCache.kv`; block 0 reserved as the null block), `layer`
-    (static) the layer to read: no per-layer slice of the pool is ever
-    an operand.  int8 pools pass `kv_scale` [n_layers, 2, num_blocks,
+    `PagedKVCache.kv`; block 0 reserved as the null block), `layer` the
+    layer to read — a Python int, or a traced int32 scalar (a looped
+    decoder's pool slot, serving/generation/decoder.py), which the
+    kernel takes by scalar prefetch and the XLA form as one more index
+    of its gather: no per-layer slice of the pool is ever an operand.  int8 pools pass `kv_scale` [n_layers, 2, num_blocks,
     block_size] f32 per-token-slot dequant scales
     (serving/generation/kv_cache.py's quantized mode).
     block_tables: [S, max_blocks] int32; ctx_len: [S] int32 — cached

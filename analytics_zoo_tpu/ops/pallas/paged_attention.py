@@ -15,10 +15,12 @@ engine, never as a materialized context tensor.
 
 The operand is the WHOLE pool in the form it is stored in,
 [n_layers, 2, num_blocks, block_size, heads * head_dim] (the block
-view of `PagedKVCache.kv`, a bitcast), with the layer a static index
-in the K and V index maps `(layer, 0|1, table[lane, j], 0, 0)`: no
-per-layer slice of the pool is ever an operand, so XLA materializes
-none.  A staged tile is [block_size, h*d] — rows of 768 lanes for
+view of `PagedKVCache.kv`, a bitcast), with the layer an index of the
+K and V index maps `(layer, 0|1, table[lane, j], 0, 0)` — a constant
+where the caller's layer is a Python int, read from one more
+scalar-prefetch operand where it is traced (a looped decoder's pool
+slot): no per-layer slice of the pool is ever an operand, so XLA
+materializes none.  A staged tile is [block_size, h*d] — rows of 768 lanes for
 GPT-2's 12 x 64, lane-dense and tile-exact, where a [bs, 12, 64] tile
 padded every (12, 64) plane to (16, 128).  Heads never get an axis of
 their own inside the kernel: with E the [h*d, h] head-indicator matrix
@@ -73,6 +75,7 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -137,6 +140,30 @@ def _first_block(cl, window: Optional[int], bs: int):
     if window is None:
         return 0
     return jnp.maximum(cl - (window - 1), 0) // bs
+
+
+def _layer_operand(layer):
+    """How the pool's `layer` reaches the index maps: (the scalar-prefetch
+    operands it adds, the maps' reading of it from the refs they are
+    handed after the table and the lengths).  A Python int stays a
+    constant of the maps — the lowering every static caller has always
+    had; a traced int32 scalar (the slot of a looped decoder's layer
+    application, decoder.py) rides in as one more scalar-prefetch
+    operand [1] that the maps read from SMEM, so the DMA is aimed at
+    that layer's blocks and no per-layer slice of the pool is ever an
+    operand."""
+    if isinstance(layer, (int, np.integer)):
+        return (), lambda *_: int(layer)
+    return ((jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),),
+            lambda lyr: lyr[0])
+
+
+def _past_layer_ref(kernel):
+    """`kernel` behind the traced layer's prefetch ref: the index maps
+    read it, the body never does."""
+    def body(tbl_ref, cl_ref, _layer_ref, *refs):
+        return kernel(tbl_ref, cl_ref, *refs)
+    return body
 
 
 def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
@@ -334,7 +361,9 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     key/value (it attends to itself), heads merged like the pool's rows.
     kv_pool: [n_layers, 2, num_blocks, block_size, h*d] — the whole
     paged pool (block 0 = the null block; any float dtype, or int8 with
-    scales); `layer` (static) picks the layer in the index maps.
+    scales); `layer` picks the layer in the index maps: a Python int
+    (static) or a traced int32 scalar, which rides in by scalar
+    prefetch (`_layer_operand`).
     kv_scale: [n_layers, 2, num_blocks, block_size] f32 per-token-slot
     dequant scales (required iff the pool is quantized).
     block_tables: [S, max_blocks] int32; ctx_len: [S] int32 valid
@@ -377,6 +406,11 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
         num_j = min(num_j, -(-reach // g))
     block_tables = block_tables.astype(jnp.int32)
     ctx_len = jnp.asarray(ctx_len, jnp.int32)
+    by_layer, at_layer = _layer_operand(layer)
+    prefetch = (block_tables, ctx_len) + by_layer
+
+    def body(kernel):
+        return _past_layer_ref(kernel) if by_layer else kernel
 
     def _entry(si, j, i, tbl, cl):
         # the table entry grid step j's i-th block reads: counted from
@@ -396,22 +430,22 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
                              f"q_per_kv {q_per_kv}")
         kv_heads, d = h // q_per_kv, head_dim
         heads = pl.BlockSpec((None, h, d),
-                             lambda si, j, tbl, cl: (si, 0, 0))
+                             lambda si, j, tbl, cl, *_: (si, 0, 0))
 
         def per_query_head(x):     # [S, kv_heads*d] -> [S, h, d]
             return jnp.repeat(x.reshape(s, kv_heads, d), q_per_kv, axis=1)
 
         pool = [pl.BlockSpec(
             (None, None, None, bs, kv_heads * d),
-            lambda si, j, tbl, cl, w=w, i=i: (
-                layer, w, _entry(si, j, i, tbl, cl), 0, 0))
+            lambda si, j, tbl, cl, *lyr, w=w, i=i: (
+                at_layer(*lyr), w, _entry(si, j, i, tbl, cl), 0, 0))
             for w in (0, 1) for i in range(g)]
         out = pl.pallas_call(
-            partial(_kernel_gqa, g=g, bs=bs, num_j=num_j,
-                    kv_heads=kv_heads, r=q_per_kv, d=d, window=window,
-                    scale=1.0 / (head_dim ** 0.5)),
+            body(partial(_kernel_gqa, g=g, bs=bs, num_j=num_j,
+                         kv_heads=kv_heads, r=q_per_kv, d=d,
+                         window=window, scale=1.0 / (head_dim ** 0.5))),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=(s, num_j),
+                num_scalar_prefetch=len(prefetch), grid=(s, num_j),
                 in_specs=[heads, heads, heads] + pool, out_specs=heads,
                 scratch_shapes=[
                     pltpu.VMEM((h, d), jnp.float32),
@@ -419,17 +453,18 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
                     pltpu.VMEM((h, _STAT_LANES), jnp.float32)]),
             out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
             interpret=interpret,
-        )(block_tables, ctx_len, q.reshape(s, h, d),
+        )(*prefetch, q.reshape(s, h, d),
           per_query_head(new_k), per_query_head(new_v),
           *[kv_pool] * (2 * g))
         return out.reshape(s, hd)
 
     # a [1, hd] block of an [S, hd] array breaks Mosaic's (8, 128)
     # block rule; of the [S, 1, hd] view it is the last two dims whole
-    lane = pl.BlockSpec((None, 1, hd), lambda si, j, tbl, cl: (si, 0, 0))
+    lane = pl.BlockSpec((None, 1, hd),
+                        lambda si, j, tbl, cl, *_: (si, 0, 0))
 
     def _fixed(shape):
-        return pl.BlockSpec(shape, lambda si, j, tbl, cl: (0, 0))
+        return pl.BlockSpec(shape, lambda si, j, tbl, cl, *_: (0, 0))
 
     def _pool_spec(which, i, rows):
         # K (which=0) or V (1) block table[lane, j*g + i] of `layer`;
@@ -437,8 +472,8 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
         # (1, bs) of the scales' [..., num_blocks, 1, bs] view
         return pl.BlockSpec(
             (None, None, None) + rows,
-            lambda si, j, tbl, cl: (layer, which,
-                                    _entry(si, j, i, tbl, cl), 0, 0))
+            lambda si, j, tbl, cl, *lyr: (
+                at_layer(*lyr), which, _entry(si, j, i, tbl, cl), 0, 0))
 
     e = (jnp.arange(hd)[:, None] // head_dim
          == jnp.arange(h)[None, :]).astype(jnp.bfloat16)
@@ -454,7 +489,7 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
             *kv_scale.shape[:3], 1, bs)] * (2 * g)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(s, num_j),
         in_specs=in_specs,
         out_specs=lane,
@@ -465,12 +500,13 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
         ],
     )
     return pl.pallas_call(
-        partial(_kernel, g=g, bs=bs, num_j=num_j, quantized=quantized,
-                scale=1.0 / (head_dim ** 0.5), window=window),
+        body(partial(_kernel, g=g, bs=bs, num_j=num_j,
+                     quantized=quantized, scale=1.0 / (head_dim ** 0.5),
+                     window=window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, hd), jnp.float32),
         interpret=interpret,
-    )(block_tables, ctx_len, *args)[:, 0]
+    )(*prefetch, *args)[:, 0]
 
 
 #: pool blocks a grid step of the latent kernel reads where the caller

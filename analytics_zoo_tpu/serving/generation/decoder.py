@@ -55,10 +55,42 @@ rotation's frequencies and the factor on `scale` are `deepseek_yarn`'s
 cached rows `[layers, batch, t, 1, row]`, its `new_v` None, and a
 gathered context comes back the same way (kv_cache.py's latent form).
 
+The looped form (`total_ut_steps` T > 1; Ouro's LoopLM, as `ouro`
+configures it).  The whole stack of L layers runs T times a token over
+ONE set of weights, the residual stream carried from step to step and
+the final norm closing every step (the next one starts from its
+output, the head reads the last):
+
+    for t in 0..T-1:  for l in 0..L-1:  x = layer_l(x, slot t * L + l)
+                      x = RMSNorm_final(x)
+    logits = x W_head
+
+A layer met again at another step has keys and values of its own:
+application (t, l) reads and writes pool slot `t * L + l`, so the pool
+holds T * L slots (`kv_geometry()`), and every mode — a whole prompt,
+a gathered context, the paged pool, a verify window — numbers them so.
+The layers' weights are stacked, one leaf a projection with a leading
+axis of L (`loop_q` `[L, hidden, heads * head_dim]`, ...), and the
+forward is a `lax.scan` over layers inside a `lax.scan` over steps: a
+compiled program holds ONE layer body whatever L and T, and the slot
+is a traced int32 scalar, which `ops.attention` carries into the paged
+kernel by scalar prefetch (no per-layer slice of the pool is ever an
+operand).  Such a stack is of one kind — one attention kind, dense
+FFNs — and its new keys and values come back in the dtype they were
+computed in (bfloat16 as served: the pool's), not widened to float32.
+Three switches of any layer, each off unless a configuration says
+otherwise: rotary on `full_attention` layers too (`rotary_full`), no
+RMSNorm over the heads of q and k (`qk_norm` False), and an RMSNorm on
+each sub-layer's output before it joins the residual stream
+(`sandwich_norm`: `x += RMSNorm(attn(RMSNorm(x)))`, the same round the
+FFN).
+
 Weights are held in `param_dtype` (bfloat16 as served: at 6144 wide a
 float32 tree cast every step would be twice the chip) under leaves
 named `kernel` / `embedding` / `scale` / `bias` only; a layer's held
-experts are ONE stacked `kernel` `[held, in, out]` a projection.
+experts are ONE stacked `kernel` `[held, in, out]` a projection, a
+looped stack's layers one stacked `kernel` or `scale` a projection or
+norm.
 """
 
 from __future__ import annotations
@@ -79,7 +111,7 @@ from analytics_zoo_tpu.ops.attention import (
     paged_decode_attention,
     paged_verify_attention,
 )
-from analytics_zoo_tpu.ops.normalization import RMSNorm
+from analytics_zoo_tpu.ops.normalization import RMSNorm, rms_norm
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 LATENT = "latent_attention"
@@ -205,6 +237,18 @@ class Kernel(nn.Module):
     @nn.compact
     def __call__(self):
         return self.param("kernel", nn.initializers.normal(0.02),
+                          self.shape, self.param_dtype)
+
+
+class Scales(nn.Module):
+    """Norm scales of a looped stack's layers, stacked: [layers, width],
+    ones, under the leaf name every norm's scale has."""
+    shape: Tuple[int, ...]
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones_init(),
                           self.shape, self.param_dtype)
 
 
@@ -368,6 +412,16 @@ class DecoderLM(nn.Module):
     #: the config's `rope_scaling` (`deepseek_yarn`) as sorted items,
     #: or None: plain frequencies
     rope_scaling: Optional[Tuple[Tuple[str, Any], ...]] = None
+    #: the looped form (module docstring): the stack runs this many
+    #: times a token over one set of weights
+    total_ut_steps: int = 1
+    #: rotary on `full_attention` layers as on window layers (EXAONE
+    #: rotates its window layers only)
+    rotary_full: bool = False
+    #: RMSNorm over each head of q and k
+    qk_norm: bool = True
+    #: RMSNorm on each sub-layer's output before the residual add
+    sandwich_norm: bool = False
     compute_dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     paged_attention_impl: Optional[str] = None
@@ -403,6 +457,17 @@ class DecoderLM(nn.Module):
         if self.n_head % self.n_kv_head:
             raise ValueError(f"{self.n_head} query heads over "
                              f"{self.n_kv_head} KV heads")
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
+        if self.total_ut_steps > 1 and (
+                len(set(self.layer_types)) != 1
+                or set(self.mlp_layer_types) != {DENSE}
+                or self.latent):
+            raise ValueError(
+                "a looped stack runs its layers as ONE body over stacked "
+                "weights: one attention kind (window or full) and dense "
+                f"FFNs, not {sorted(set(self.layer_types))} over "
+                f"{sorted(set(self.mlp_layer_types))}")
 
     @classmethod
     def from_config(cls, config, **kw) -> "DecoderLM":
@@ -411,7 +476,11 @@ class DecoderLM(nn.Module):
         `mlp_layer_types`, `num_key_value_heads`, `num_experts`, ...)
         or, where it has a `kv_lora_rank`, by `sarvam_mla`'s
         (`first_k_dense_replace`, `qk_nope_head_dim`, `rope_scaling`,
-        ...; its `head_dim` is the cached row's width);
+        ...; its `head_dim` is the cached row's width), or, where it
+        has a `total_ut_steps`, by `ouro`'s (dense layers looped that
+        many times, rotary on every layer, sandwich norms, no q/k norm,
+        a top-level `rope_theta`; an `early_exit_threshold` under 1
+        asks for per-token exits, which are not served);
         `experts_held` = [first id, count] is this chip's share of the
         experts (all of them when absent).  `kw`: the fields no
         `config.json` has (dtypes, the paged kernel's impl)."""
@@ -432,6 +501,19 @@ class DecoderLM(nn.Module):
                 v_head_dim=config["v_head_dim"],
                 rope_scaling=config.get("rope_scaling"),
                 rope_theta=float(config["rope_theta"]), **kw)
+        elif "total_ut_steps" in config:
+            if config.get("early_exit_threshold", 1.0) < 1.0:
+                raise ValueError(
+                    "an early_exit_threshold under 1 lets a token leave "
+                    "the loop early, and its later steps' pool slots "
+                    "would have to be filled for the tokens after it: "
+                    "not served")
+            kw = dict(
+                layer_types=tuple(config["layer_types"]),
+                mlp_layer_types=(DENSE,) * config["num_hidden_layers"],
+                total_ut_steps=int(config["total_ut_steps"]),
+                rotary_full=True, qk_norm=False, sandwich_norm=True,
+                rope_theta=float(config["rope_theta"]), **kw)
         else:
             kw = dict(
                 layer_types=tuple(config["layer_types"]),
@@ -450,7 +532,7 @@ class DecoderLM(nn.Module):
             num_shared_experts=config.get("num_shared_experts", 1),
             routed_scaling_factor=config.get("routed_scaling_factor", 1.0),
             norm_topk_prob=config.get("norm_topk_prob", True),
-            sliding_window=config.get("sliding_window", 0),
+            sliding_window=config.get("sliding_window") or 0,
             rms_norm_eps=config["rms_norm_eps"],
             max_position_len=config["max_position_embeddings"], **kw)
 
@@ -465,13 +547,16 @@ class DecoderLM(nn.Module):
         return LATENT in self.layer_types
 
     def kv_geometry(self) -> Tuple[int, ...]:
-        """(layers, KV heads, head dim): a pool row is KV heads *
-        head dim wide, and a token holds two.  With latent attention
-        (layers, 1, the cached row's width, 1): ONE row a token."""
+        """(pool slots, KV heads, head dim): a pool row is KV heads *
+        head dim wide, and a token holds two; a slot a layer, or
+        `total_ut_steps` a layer in the looped form (slot t * layers +
+        l).  With latent attention (layers, 1, the cached row's width,
+        1): ONE row a token."""
         if self.latent:
             return (self.n_block, 1,
                     self.kv_lora_rank + self.qk_rope_head_dim, 1)
-        return self.n_block, self.n_kv_head, self.head_dim
+        return (self.n_block * self.total_ut_steps, self.n_kv_head,
+                self.head_dim)
 
     def latent_constants(self):
         """(the rotary frequencies or None for the plain ones, the
@@ -610,6 +695,15 @@ class DecoderLM(nn.Module):
                                       scale=scale, **ctx)
             return a, row
 
+        if self.total_ut_steps > 1:
+            x, new_k, new_v = self._looped(
+                x, positions, additive_mask, dict(
+                    ctx_k=ctx_k, ctx_v=ctx_v, ctx_len=ctx_len,
+                    kv_pool=kv_pool, kv_scale=kv_scale,
+                    block_tables=block_tables, impl=impl))
+            logits = dense(self.vocab, "lm_head")(x.astype(cd))
+            return logits.astype(jnp.float32), new_k, new_v
+
         new_k, new_v, counts = [], [], []
         for i, (attn_kind, ffn_kind) in enumerate(
                 zip(self.layer_types, self.mlp_layer_types)):
@@ -629,9 +723,10 @@ class DecoderLM(nn.Module):
                         .reshape(b, t, g, hd)
                     v = dense(g * hd, f"{blk}_v")(a_in) \
                         .reshape(b, t, g, hd)
-                    q = norm(f"{blk}_q_norm")(q)
-                    k = norm(f"{blk}_k_norm")(k)
-                    if window:
+                    if self.qk_norm:
+                        q = norm(f"{blk}_q_norm")(q)
+                        k = norm(f"{blk}_k_norm")(k)
+                    if window or self.rotary_full:
                         q = rotary(q, positions, self.rope_theta)
                         k = rotary(k, positions, self.rope_theta)
                     new_k.append(k.astype(jnp.float32))
@@ -644,6 +739,8 @@ class DecoderLM(nn.Module):
                                compute_dtype=cd)
                 a = dense(self.hidden_size, f"{blk}_o")(
                     a.reshape(b, t, -1).astype(cd))
+                if self.sandwich_norm:
+                    a = norm(f"{blk}_attn_post_norm")(a)
             x = x + a.astype(jnp.float32)
             f_in = norm(f"{blk}_ffn_norm")(x)
             if ffn_kind == DENSE:
@@ -660,6 +757,8 @@ class DecoderLM(nn.Module):
                     dtype=cd, param_dtype=pd, name=f"{blk}_moe")(
                         f_in, token_mask)
                 counts.append(n_tokens)
+            if self.sandwich_norm:
+                f = norm(f"{blk}_ffn_post_norm")(f)
             x = x + f.astype(jnp.float32)
 
         if counts:
@@ -668,6 +767,85 @@ class DecoderLM(nn.Module):
         logits = dense(self.vocab, "lm_head")(norm("final_norm")(x))
         return (logits.astype(jnp.float32), jnp.stack(new_k),
                 jnp.stack(new_v) if new_v else None)
+
+    def _looped(self, x, positions, additive_mask, cache):
+        """The looped form's stack (module docstring): x [b, t, hidden]
+        float32 -> (x after the last step's final norm, new_k, new_v
+        [T * L slots, b, t, KV heads, head_dim] in the compute dtype).
+        `cache`: the call's mode, as `attend` takes it.  Called from the
+        compact `__call__`, so the stacked leaves are its own."""
+        b, t = x.shape[:2]
+        h, g, hd = self.n_head, self.n_kv_head, self.head_dim
+        d, ff = self.hidden_size, self.intermediate_size
+        n_layers, cd, pd = self.n_block, self.compute_dtype, \
+            self.param_dtype
+        eps = self.rms_norm_eps
+        window = (self.sliding_window
+                  if self.layer_types[0] == SLIDING else None)
+        turned = bool(window) or self.rotary_full
+
+        def stacked(name, *shape):
+            return Kernel((n_layers,) + shape, pd, name=f"loop_{name}")()
+        w = {name: stacked(name, *shape) for name, shape in (
+            ("q", (d, h * hd)), ("k", (d, g * hd)), ("v", (d, g * hd)),
+            ("o", (h * hd, d)), ("gate", (d, ff)), ("up", (d, ff)),
+            ("down", (ff, d)))}
+        norms = ["attn_norm", "ffn_norm"] + (
+            ["attn_post_norm", "ffn_post_norm"] if self.sandwich_norm
+            else [])
+        w.update((name, Scales((n_layers, d), pd, name=f"loop_{name}")())
+                 for name in norms)
+        if self.qk_norm:
+            w.update((name, Scales((n_layers, hd), pd,
+                                   name=f"loop_{name}")())
+                     for name in ("q_norm", "k_norm"))
+        final = Scales((d,), pd, name="final_norm")()
+
+        def mm(a, kernel):
+            return jnp.dot(a.astype(cd), kernel.astype(cd))
+
+        def layer(x, w, slot):
+            # one application of layer l at step t: slot t * L + l
+            with jax.named_scope("attn.loop"):
+                a_in = rms_norm(x, w["attn_norm"], eps=eps, out_dtype=cd)
+                q = mm(a_in, w["q"]).reshape(b, t, h, hd)
+                k = mm(a_in, w["k"]).reshape(b, t, g, hd)
+                v = mm(a_in, w["v"]).reshape(b, t, g, hd)
+                if self.qk_norm:
+                    q = rms_norm(q, w["q_norm"], eps=eps, out_dtype=cd)
+                    k = rms_norm(k, w["k_norm"], eps=eps, out_dtype=cd)
+                if turned:
+                    q = rotary(q, positions, self.rope_theta)
+                    k = rotary(k, positions, self.rope_theta)
+                a = attend(q, k, v, layer=slot, window=window,
+                           mask=additive_mask, compute_dtype=cd, **cache)
+                a = mm(a.reshape(b, t, -1), w["o"])
+                if self.sandwich_norm:
+                    a = rms_norm(a, w["attn_post_norm"], eps=eps,
+                                 out_dtype=cd)
+            x = x + a.astype(jnp.float32)
+            with jax.named_scope("ffn.loop"):
+                f_in = rms_norm(x, w["ffn_norm"], eps=eps, out_dtype=cd)
+                f = mm(nn.silu(mm(f_in, w["gate"])) * mm(f_in, w["up"]),
+                       w["down"])
+                if self.sandwich_norm:
+                    f = rms_norm(f, w["ffn_post_norm"], eps=eps,
+                                 out_dtype=cd)
+            return x + f.astype(jnp.float32), (k.astype(cd), v.astype(cd))
+
+        def step(x, t_step):
+            def one(x, layer_and_index):
+                weights, index = layer_and_index
+                return layer(x, weights, t_step * n_layers + index)
+            x, kv = jax.lax.scan(
+                one, x, (w, jnp.arange(n_layers, dtype=jnp.int32)))
+            return rms_norm(x, final, eps=eps, out_dtype=jnp.float32), kv
+
+        x, (ks, vs) = jax.lax.scan(
+            step, x, jnp.arange(self.total_ut_steps, dtype=jnp.int32))
+        slots = (self.total_ut_steps * n_layers,)
+        return (x, ks.reshape(slots + ks.shape[2:]),
+                vs.reshape(slots + vs.shape[2:]))
 
 
 class ExpertCounters:

@@ -523,6 +523,14 @@ class GenerationEngine:
                   help="rows a cached token holds in a layer: 2 (a key "
                        "and a value) or 1 (a latent row)"
                   ).set(self.cache.rows)
+        reg.gauge("generation_loop_steps",
+                  help="times the model's layer stack runs for a token: "
+                       "1, or a looped decoder's total_ut_steps"
+                  ).set(getattr(model, "total_ut_steps", 1))
+        reg.gauge("generation_kv_layer_slots",
+                  help="pool slots a cached token holds rows in: one a "
+                       "layer, or one a layer a step of a looped decoder"
+                  ).set(self.cache.n_layers)
         if self.state_pool is not None:
             reg.gauge("generation_state_slots_in_use",
                       fn=lambda: len(self.scheduler.slotted()),

@@ -219,6 +219,25 @@ def _paged_tp(place):
                 place((s, mb), jnp.int32), place((s,), jnp.int32)]
 
 
+def _paged_traced_layer(place):
+    """The paged kernel as a looped decoder's decode calls it
+    (`ouro_2p6b_serve`: 16 lanes, 16 heads x 128, tables of 20 blocks,
+    a pool of 4 x 48 = 192 slots): the slot a traced int32 scalar,
+    carried in by scalar prefetch, at the table's gather of 8."""
+    from analytics_zoo_tpu.ops.pallas.paged_attention import (
+        paged_decode_pallas)
+    s, hd, bs, mb = 16, 16 * 128, 16, 20
+
+    def fn(q, nk, nv, kv, tbl, cl, slot):
+        return paged_decode_pallas(q, nk, nv, kv, tbl, cl, layer=slot,
+                                   head_dim=128, block_gather=8)
+    lane = place((s, hd), jnp.float32)
+    return fn, [lane, lane, lane,
+                place((192, 2, s * mb + 1, bs, hd), jnp.bfloat16),
+                place((s, mb), jnp.int32), place((s,), jnp.int32),
+                place((), jnp.int32)]
+
+
 CASES = {
     "paged_bf16_g1": _paged(jnp.bfloat16, 1),
     # 8 = what ops/tuning/default_tables.json names for this key
@@ -239,6 +258,7 @@ CASES = {
     "flash_fwd_bwd_b32_t128": _flash(32, 128),
     "flash_fwd_bwd_b8_t512": _flash(8, 512),
     "paged_bf16_g8_tp4": _paged_tp,
+    "paged_bf16_g8_traced_layer": _paged_traced_layer,
 }
 
 
