@@ -20,38 +20,53 @@ K and V index maps `(layer, 0|1, table[lane, j], 0, 0)` — a constant
 where the caller's layer is a Python int, read from one more
 scalar-prefetch operand where it is traced (a looped decoder's pool
 slot): no per-layer slice of the pool is ever an operand, so XLA
-materializes none.  A staged tile is [block_size, h*d] — rows of 768 lanes for
-GPT-2's 12 x 64, lane-dense and tile-exact, where a [bs, 12, 64] tile
-padded every (12, 64) plane to (16, 128).  Heads never get an axis of
-their own inside the kernel: with E the [h*d, h] head-indicator matrix
-(E[c, k] = 1 where merged column c belongs to head k),
+materializes none.  A staged tile is [n, h*d], n = block_gather *
+block_size rows of 768 lanes for GPT-2's 12 x 64, lane-dense and
+tile-exact, where a [bs, 12, 64] tile padded every (12, 64) plane to
+(16, 128).
 
-    scores [n, h]   = ((K * q) @ E) * d**-0.5
-    out    [1, h*d] = sum over rows of ((p @ E.T) * V)
+Heads never get an axis of their own in the pool, and the tile goes to
+the MXU as it lies, in the pool's dtype.  The lane's query is laid out
+block-diagonally once, at its first grid step: Q_bd [h, h*d], row k
+holding head k's columns of q and zeros elsewhere (rows padded to a
+whole bf16 tile).  Then, over the n rows of a grid step,
 
-and the usual online softmax (running max / denominator in [1, h],
-output in [1, h*d], f32 VMEM scratch — the flash_attention bookkeeping
-at q_len=1) runs between them over the n = block_gather * block_size
-rows of a grid step at once, masked by the lane's `ctx_len`.  The new
-token's self-attention is folded into the initialization (a decode
-token always attends to itself); finalize divides by the denominator.
-Both matmuls run on the MXU in ONE bf16 pass and are still exact to
-f32: E is 0/1, exact in bf16, and the f32 operand is split into three
-bf16 pieces stacked along the rows (`_dot_exact`) — left to itself
-Mosaic rounds an f32 operand to bf16 (a gap of 2.4e-3 to the XLA form
-on N(0, 1) data, my chip run, PR 29, against 1.3e-6 this way).
+    scores [h, n]   = (Q_bd @ K.T) * d**-0.5     (positions along lanes)
+    acc    [h, h*d] = acc * alpha + p @ V        (only row k's head-k
+                                                  columns are kept)
+
+with the usual online softmax between them (running max / denominator
+in [h, 128], f32 VMEM scratch — the flash_attention bookkeeping at
+q_len=1), masked by the lane's `ctx_len`.  The new token's
+self-attention is folded into the initialization (a decode token always
+attends to itself); finalize divides by the denominator and keeps each
+row's own head.  The tile is never cast to f32: a bf16 K or V tile is
+the MXU's operand as stored, and the f32 operand against it (Q_bd, p)
+goes in as three bf16 pieces stacked along the rows, so each product is
+ONE bf16 pass with the tile loaded once and still exact to f32
+(`_split3`, `_dot_ready`) — left to itself Mosaic rounds an f32 operand
+to bf16 (a gap of 2.4e-3 to the XLA form on N(0, 1) data against
+1.3e-6 this way, on one TPU v5e).  An f32 copy of the tile would cost
+more vector work than the rest of the step together, and buy nothing:
+the products accumulate in f32 either way.  A tile of fewer than
+`_BLOCK_DIAGONAL_ROWS` rows (one block of 16: a table without a
+tuned gather) keeps the head-indicator body, which is faster there
+(`_kernel_indicator`: with E the [h*d, h] 0/1 indicator of each merged
+column's head, scores [n, h] = ((K * q) @ E) * d**-0.5 and out =
+sum over rows of ((p @ E.T) * V), both products exact the same way).
 
 Quantized pools (int8 KV, serving/generation/kv_cache.py): when
-`kv_scale` [n_layers, 2, num_blocks, block_size] rides along, the
-kernel dequantizes ON READ by folding each token's scale into its ROW
-of the scores / probabilities (s_row *= k_scale[row]; p_row *=
-v_scale[row]) — algebraically identical to scaling K/V rows, but on
-the [n, h] tile and never materializing a dequantized block.
+`kv_scale` [n_layers, 2, num_blocks, block_size] rides along, the int8
+tile is read as bf16 (exact) and the kernel dequantizes ON READ by
+folding each token's scale into its position of the scores /
+probabilities (s *= k_scale; p *= v_scale, a [1, n] row) —
+algebraically identical to scaling K/V rows, but never materializing a
+dequantized block.
 
 The tunable is `block_gather` (G): how many pool blocks one grid step
 processes.  G > 1 passes the pool G times with G table-indexed
 BlockSpecs, so one grid step streams G blocks and amortizes the
-per-step softmax bookkeeping and both matmuls over a G*block_size-row
+per-step softmax bookkeeping and both products over a G*block_size-row
 tile — the decode analog of flash's block_k.  Registered with
 `ops/tuning` under the fwd-only key family
 
@@ -85,6 +100,29 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_GATHER = 1
 #: candidate VMEM ceiling (same headroom discipline as flash_attention)
 _VMEM_BUDGET = 12 * 1024 * 1024
+#: lanes the running max / denominator of a kernel with query rows are
+#: kept over (every lane holds the same number: a [rows, 1] scratch is
+#: no whole tile)
+_STAT_LANES = 128
+
+
+#: the multi-head kernel's block-diagonal queries have a row a head,
+#: padded up to whole bf16 tiles of 16 rows so that their three pieces
+#: stack on tile boundaries (the rows past the heads stay zero)
+_ROW_TILE = 16
+#: staged tile rows (block_gather * block_size) from which the
+#: multi-head form takes the block-diagonal body (`_kernel`); a smaller
+#: tile takes the head-indicator body (`_kernel_indicator`).  Measured
+#: on one TPU v5e at GPT-2 small's decode shapes (32 lanes, 12 heads x
+#: 64, tables of 64 blocks of 16), the indicator body against the
+#: block-diagonal one, us a call: every grid step live, 16 rows, 1,015
+#: against 1,150; contexts 64-672, 32 rows, 347 / 367 against 329 /
+#: 350 (two seeds); 64 rows, 312 / 329 against 267 / 276
+_BLOCK_DIAGONAL_ROWS = 32
+
+
+def _head_rows(h: int) -> int:
+    return -(-h // _ROW_TILE) * _ROW_TILE
 
 
 def paged_decode_candidates(bs: int, mb: int, h: int, d: int,
@@ -92,41 +130,41 @@ def paged_decode_candidates(bs: int, mb: int, h: int, d: int,
                             ) -> List[Dict[str, int]]:
     """The autotuner's candidate grid: block-gather widths that fit the
     VMEM budget and don't exceed the per-lane table.  Per grid step the
-    kernel holds the k+v tiles [g*bs, h*d] in the pool's dtype (double
-    buffered by the pipeline), some six f32 working copies of that tile
-    (the two casts, K*q and its three bf16 pieces, p spread over the
-    columns, p*V), both indicator matrices (bf16, padded to 128 lanes /
-    16 sublanes), the q/new-token/output rows and the [1, h*d]
-    accumulator."""
+    kernel holds the k+v tiles [n = g*bs, h*d] in the pool's dtype
+    (double buffered by the pipeline), the q/new-token/output rows (8
+    sublanes each, double buffered) and the scale rows, and what its
+    body works in.  The block-diagonal body (n >= _BLOCK_DIAGONAL_ROWS):
+    both tiles as the product reads them (concatenated; bf16 where the
+    pool is int8), the lane's block-diagonal queries [3hp, h*d] bf16
+    (hp: the heads padded to whole row tiles), the scores' and the
+    values' f32 products [3hp, n] and [3hp, h*d], the [hp, h*d]
+    accumulator and the m / l statistics.  The head-indicator body:
+    some six f32 copies of the tile, both indicators (bf16, padded to
+    128 lanes / 16 sublanes) and its [1, h*d] scratch."""
     hd = h * d
+    hp = _head_rows(h)
     out = []
     for g in (1, 2, 4, 8):
         if g > max(1, mb):
             continue
         n = g * bs
-        vmem = (2 * 2 * n * hd * itemsize       # k+v tiles, 2 buffers
-                + 6 * n * hd * 4                # f32 working set
-                + 2 * hd * 128 * 2 + 2 * 16 * hd * 2   # E, E.T
-                + 2 * 4 * hd * 4                # q, new_k, new_v, o
-                + hd * 4 + 2 * 8 * 128 * 4      # o/m/l scratch
-                + 2 * 2 * g * 8 * 128 * 4)      # scale rows
+        if n >= _BLOCK_DIAGONAL_ROWS:
+            work = (2 * n * hd * max(itemsize, 2)   # the tiles as read
+                    + 3 * hp * hd * 2               # queries, 3 pieces
+                    + 3 * hp * (n + hd) * 4         # the two products
+                    + hp * hd * 4                   # accumulator
+                    + 2 * hp * _STAT_LANES * 4)     # m, l
+        else:
+            work = (6 * n * hd * 4                  # f32 working set
+                    + 2 * hd * 128 * 2 + 2 * 16 * hd * 2   # E, E.T
+                    + hd * 4 + 2 * 8 * 128 * 4)     # o/m/l scratch
+        vmem = (2 * 2 * n * hd * itemsize           # k+v tiles, 2 buffers
+                + work
+                + 4 * 2 * 8 * hd * 4                # q, new_k, new_v, o
+                + 2 * 2 * g * 8 * 128 * 4)          # scale rows
         if vmem <= _VMEM_BUDGET:
             out.append({"block_gather": g})
     return out or [{"block_gather": DEFAULT_BLOCK_GATHER}]
-
-
-def _dot_exact(x, w):
-    """x [r, k] f32 @ w [k, n] bf16 in ONE bf16 MXU pass, exact to f32
-    where w is exact in bf16 (the 0/1 indicators): x = hi + mid + lo in
-    bf16 pieces, stacked along the rows so the weights load once."""
-    r = x.shape[0]
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(jnp.float32)
-    mid = rest.astype(jnp.bfloat16)
-    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    y = jnp.dot(jnp.concatenate([hi, mid, lo], axis=0), w,
-                preferred_element_type=jnp.float32)
-    return y[:r] + y[r:2 * r] + y[2 * r:]
 
 
 def _rows(xs):
@@ -166,15 +204,144 @@ def _past_layer_ref(kernel):
     return body
 
 
-def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
-            g: int, bs: int, num_j: int, quantized: bool, scale: float,
+def _split3(x):
+    """x f32 [r, k] as its three bf16 pieces stacked along the rows."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
+def _dot_ready(x, w, contract_w: int):
+    """x times a K or V tile `w` as it lies in the pool, contracting w's
+    axis `contract_w` (1: x @ w.T, 0: x @ w), to f32 [r, n].  Where w
+    is bf16, x is an f32 operand's three bf16 pieces [3r, k]
+    (`_split3`): ONE bf16 MXU pass, the tile loaded once, exact to f32
+    — left to itself Mosaic rounds an f32 operand to bf16 (a gap of
+    2.4e-3 to the XLA form on N(0, 1) data against 1.3e-6 this way, on
+    one TPU v5e).  Any other tile (the f32 pools of the CPU tests)
+    meets x [r, k] in f32 at the highest precision."""
+    dims = (((1,), (contract_w,)), ((), ()))
+    if w.dtype == jnp.bfloat16:
+        r = x.shape[0] // 3
+        y = jax.lax.dot_general(x, w, dims,
+                                preferred_element_type=jnp.float32)
+        return y[:r] + y[r:2 * r] + y[2 * r:]
+    return jax.lax.dot_general(x, w.astype(jnp.float32), dims,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_f32(x, w, contract_w: int):
+    """x [r, k] f32 times a pool tile `w` (`_dot_ready`), split here."""
+    return _dot_ready(_split3(x) if w.dtype == jnp.bfloat16 else x, w,
+                      contract_w)
+
+
+def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int, bs: int,
+            num_j: int, d: int, quantized: bool, scale: float,
             window: Optional[int] = None):
     # scalar prefetch: tbl_ref [S, MB] block tables, cl_ref [S] ctx
-    # lengths.  q/nk/nv_ref: [1, h*d] lane rows; e_ref [h*d, h] and
-    # et_ref [h, h*d] the head indicators (fetched once: their block
-    # never moves).  rest: g gathered K blocks [bs, h*d], g V blocks,
-    # (g k-scale + g v-scale [1, bs] rows when quantized), then o_ref
-    # [1, h*d] and the o/m/l VMEM scratch carried across the block axis.
+    # lengths.  q/nk/nv_ref: [1, h*d] lane rows.  rest: g gathered K
+    # blocks [bs, h*d], g V blocks, (g k-scale + g v-scale [1, bs] rows
+    # when quantized), then o_ref [1, h*d] and the VMEM scratch carried
+    # across the block axis: qd, the lane's queries block-diagonal —
+    # row k holds head k's columns of q, zeros elsewhere, the h rows
+    # padded to hp — as the staged tile's product takes them (three
+    # bf16 pieces [3hp, h*d], or f32 [hp, h*d] beside an f32 tile); the
+    # o [hp, h*d] accumulator, of which only row k's head-k columns
+    # count; the running max / denominator m, l [hp, 128].
+    rest = list(rest)
+    ks = [rest.pop(0) for _ in range(g)]
+    vs = [rest.pop(0) for _ in range(g)]
+    kscl = [rest.pop(0) for _ in range(g)] if quantized else None
+    vscl = [rest.pop(0) for _ in range(g)] if quantized else None
+    o_ref, qd_scr, o_scr, m_scr, l_scr = rest
+    hp, hd = o_scr.shape
+    pieces = qd_scr.dtype == jnp.bfloat16
+    s_idx = pl.program_id(0)
+    j = pl.program_id(1)
+    n = g * bs
+
+    def own_head():      # [hp, hd]: row k's columns of head k
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+        return (col >= row * d) & (col < row * d + d)
+
+    def ready(x):        # an f32 operand as the tile's product takes it
+        return _split3(x) if pieces else x
+
+    def tile(refs):      # [n, hd] as staged; int8 read as bf16 (exact)
+        return _rows([r[...].astype(jnp.bfloat16)
+                      if r.dtype == jnp.int8 else r[...] for r in refs])
+
+    def lanes(refs):     # g [1, bs] scale rows as one [1, n] row
+        xs = [r[...] for r in refs]
+        return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1)
+
+    @pl.when(j == 0)
+    def _init():
+        # the new token always attends to itself: seed the online
+        # softmax with its own score (p_self = exp(0) = 1, l = 1,
+        # o = new_v) instead of a NEG_INF/0 init — no empty-context
+        # special case, no 0/0 at finalize
+        qd = jnp.where(own_head(), q_ref[...].astype(jnp.float32), 0.0)
+        qd_scr[...] = ready(qd)
+        s_self = (qd * nk_ref[...].astype(jnp.float32)).sum(
+            axis=1, keepdims=True) * scale               # [hp, 1]
+        m_scr[...] = jnp.broadcast_to(s_self, m_scr.shape)
+        l_scr[...] = jnp.ones_like(l_scr)
+        o_scr[...] = jnp.broadcast_to(nv_ref[...].astype(jnp.float32),
+                                      o_scr.shape)
+
+    cl = cl_ref[s_idx]
+    # the rows of this grid step start at position `base`: group j of
+    # the table, counted from the first block the window reaches into
+    base = _first_block(cl, window, bs) * bs + j * n
+
+    # block groups entirely past the lane's context are all-masked:
+    # skip their compute (the DMAs still stream by, cheaply — the
+    # shapes stay static, which is the zero-recompile contract)
+    @pl.when(base < cl)
+    def _compute():
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
+        valid = pos < cl                                 # [1, n]
+        if window is not None:
+            valid = valid & (pos > cl - window)
+        # every head's scores in ONE product with the tile as it lies
+        s = _dot_ready(qd_scr[...], tile(ks), 1) * scale  # [hp, n]
+        if quantized:
+            s = s * lanes(kscl)          # dequant-on-read, by position
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:, 0:1]                           # [hp, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0:1] * alpha + p.sum(axis=1, keepdims=True)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        if quantized:
+            p = p * lanes(vscl)
+        o_scr[...] = o_scr[...] * alpha + _dot_ready(ready(p), tile(vs), 0)
+
+    @pl.when(j == num_j - 1)
+    def _finalize():
+        o = o_scr[...] / l_scr[:, 0:1]
+        o_ref[...] = jnp.where(own_head(), o, 0.0).sum(
+            axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _kernel_indicator(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref,
+                      *rest, g: int, bs: int, num_j: int, quantized: bool,
+                      scale: float, window: Optional[int] = None):
+    # the multi-head form on a staged tile of fewer than
+    # `_BLOCK_DIAGONAL_ROWS` rows: positions along the sublanes, heads
+    # found through e_ref [h*d, h] and et_ref [h, h*d], the 0/1 head
+    # indicators E and E.T (fetched once: their block never moves) —
+    # scores [n, h] = ((K * q) @ E) * scale, out = sum over rows of
+    # ((p @ E.T) * V) — with the o [1, h*d] / m, l [1, h] scratch.
+    # Refs otherwise as `_kernel`'s.
     rest = list(rest)
     ks = [rest.pop(0) for _ in range(g)]
     vs = [rest.pop(0) for _ in range(g)]
@@ -187,34 +354,26 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
     qv = q_ref[...].astype(jnp.float32)                  # [1, hd]
 
     def heads(x):        # [r, hd] -> [r, h]: each head's sum
-        return _dot_exact(x, e_ref[...])
+        return _dot_f32(x, e_ref[...], 0)
 
     def spread(x):       # [r, h] -> [r, hd]: each head's value, d times
-        return _dot_exact(x, et_ref[...])
+        return _dot_f32(x, et_ref[...], 0)
 
     def up8(x):          # a [1, w] row as 8 sublanes: a whole MXU tile
         return jnp.broadcast_to(x, (8, x.shape[1]))
 
     @pl.when(j == 0)
     def _init():
-        # the new token always attends to itself: seed the online
-        # softmax with its own score (p_self = exp(0) = 1, l = 1,
-        # o = new_v) instead of a NEG_INF/0 init — no empty-context
-        # special case, no 0/0 at finalize
+        # the new token attends to itself: see `_kernel`
         s_self = heads(up8(qv * nk_ref[...].astype(jnp.float32)))
         m_scr[...] = s_self[0:1] * scale                 # [1, h]
         l_scr[...] = jnp.ones_like(l_scr)
         o_scr[...] = nv_ref[...].astype(jnp.float32)
 
     cl = cl_ref[s_idx]
-    # the rows of this grid step start at position `base`: group j of
-    # the table, counted from the first block the window reaches into
     base = j * n if window is None \
         else _first_block(cl, window, bs) * bs + j * n
 
-    # block groups entirely past the lane's context are all-masked:
-    # skip their compute (the DMAs still stream by, cheaply — the
-    # shapes stay static, which is the zero-recompile contract)
     @pl.when(base < cl)
     def _compute():
         kt = _rows([k[...].astype(jnp.float32) for k in ks])  # [n, hd]
@@ -233,7 +392,6 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
             def col(refs):
                 return _rows([jnp.where(eye, r[...], 0.0).sum(
                     axis=1, keepdims=True) for r in refs])
-            # dequant-on-read, folded into the score rows
             s = s * col(kscl)
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[...]
@@ -253,38 +411,6 @@ def _kernel(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, e_ref, et_ref, *rest,
     def _finalize():
         o_ref[...] = (o_scr[...] / spread(up8(l_scr[...]))[0:1]
                       ).astype(o_ref.dtype)
-
-
-#: lanes the running max / denominator of the grouped kernel are kept
-#: over (every lane holds the same number: a [rows, 1] scratch is no
-#: whole tile)
-_STAT_LANES = 128
-
-
-def _split3(x):
-    """x f32 [r, k] as its three bf16 pieces stacked along the rows."""
-    hi = x.astype(jnp.bfloat16)
-    rest = x - hi.astype(jnp.float32)
-    mid = rest.astype(jnp.bfloat16)
-    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    return jnp.concatenate([hi, mid, lo], axis=0)
-
-
-def _dot_f32(x, w, contract_w: int):
-    """x [r, k] f32 times a K or V tile `w` as it lies in the pool,
-    contracting w's axis `contract_w` (1: x @ w.T, 0: x @ w), to f32.
-    A bf16 tile takes ONE bf16 MXU pass over x's three bf16 pieces and
-    is exact to f32, like `_dot_exact`; any other pool dtype (the f32
-    pools of the CPU tests) multiplies in f32."""
-    dims = (((1,), (contract_w,)), ((), ()))
-    if w.dtype == jnp.bfloat16:
-        r = x.shape[0]
-        y = jax.lax.dot_general(_split3(x), w, dims,
-                                preferred_element_type=jnp.float32)
-        return y[:r] + y[r:2 * r] + y[2 * r:]
-    return jax.lax.dot_general(x, w.astype(jnp.float32), dims,
-                               precision=jax.lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
 
 
 def _kernel_gqa(tbl_ref, cl_ref, q_ref, nk_ref, nv_ref, *rest, g: int,
@@ -463,9 +589,6 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
     lane = pl.BlockSpec((None, 1, hd),
                         lambda si, j, tbl, cl, *_: (si, 0, 0))
 
-    def _fixed(shape):
-        return pl.BlockSpec(shape, lambda si, j, tbl, cl, *_: (0, 0))
-
     def _pool_spec(which, i, rows):
         # K (which=0) or V (1) block table[lane, j*g + i] of `layer`;
         # `rows` is the block's own shape: (bs, hd) of the pool,
@@ -475,35 +598,49 @@ def paged_decode_pallas(q, new_k, new_v, kv_pool, block_tables, ctx_len,
             lambda si, j, tbl, cl, *lyr: (
                 at_layer(*lyr), which, _entry(si, j, i, tbl, cl), 0, 0))
 
-    e = (jnp.arange(hd)[:, None] // head_dim
-         == jnp.arange(h)[None, :]).astype(jnp.bfloat16)
-    in_specs = ([lane, lane, lane, _fixed((hd, h)), _fixed((h, hd))]
-                + [_pool_spec(w, i, (bs, hd))
-                   for w in (0, 1) for i in range(g)])
-    args = ([q[:, None], new_k[:, None], new_v[:, None], e, e.T]
-            + [kv_pool] * (2 * g))
+    def _fixed(shape):
+        return pl.BlockSpec(shape, lambda si, j, tbl, cl, *_: (0, 0))
+
+    indicator = g * bs < _BLOCK_DIAGONAL_ROWS
+    in_specs = [lane, lane, lane]
+    args = [q[:, None], new_k[:, None], new_v[:, None]]
+    if indicator:
+        e = (jnp.arange(hd)[:, None] // head_dim
+             == jnp.arange(h)[None, :]).astype(jnp.bfloat16)
+        in_specs += [_fixed((hd, h)), _fixed((h, hd))]
+        args += [e, e.T]
+    in_specs += [_pool_spec(w, i, (bs, hd))
+                 for w in (0, 1) for i in range(g)]
+    args += [kv_pool] * (2 * g)
     if quantized:
         in_specs += [_pool_spec(w, i, (1, bs))
                      for w in (0, 1) for i in range(g)]
         args += [kv_scale.astype(jnp.float32).reshape(
             *kv_scale.shape[:3], 1, bs)] * (2 * g)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(s, num_j),
-        in_specs=in_specs,
-        out_specs=lane,
-        scratch_shapes=[
-            pltpu.VMEM((1, hd), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-        ],
-    )
+    scale = 1.0 / (head_dim ** 0.5)
+    if indicator:
+        kernel = partial(_kernel_indicator, g=g, bs=bs, num_j=num_j,
+                         quantized=quantized, scale=scale, window=window)
+        scratch = [pltpu.VMEM((1, hd), jnp.float32),
+                   pltpu.VMEM((1, h), jnp.float32),
+                   pltpu.VMEM((1, h), jnp.float32)]
+    else:
+        kernel = partial(_kernel, g=g, bs=bs, num_j=num_j, d=head_dim,
+                         quantized=quantized, scale=scale, window=window)
+        hp = _head_rows(h)
+        # the block-diagonal queries as the staged tile's product takes
+        # them: three bf16 pieces beside a bf16 (or int8) tile, else f32
+        scratch = [(pltpu.VMEM((3 * hp, hd), jnp.bfloat16)
+                    if kv_pool.dtype in (jnp.bfloat16, jnp.int8)
+                    else pltpu.VMEM((hp, hd), jnp.float32)),
+                   pltpu.VMEM((hp, hd), jnp.float32),
+                   pltpu.VMEM((hp, _STAT_LANES), jnp.float32),
+                   pltpu.VMEM((hp, _STAT_LANES), jnp.float32)]
     return pl.pallas_call(
-        body(partial(_kernel, g=g, bs=bs, num_j=num_j,
-                     quantized=quantized, scale=1.0 / (head_dim ** 0.5),
-                     window=window)),
-        grid_spec=grid_spec,
+        body(kernel),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), grid=(s, num_j),
+            in_specs=in_specs, out_specs=lane, scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((s, 1, hd), jnp.float32),
         interpret=interpret,
     )(*prefetch, *args)[:, 0]

@@ -39,24 +39,25 @@ L, LAYER = 3, 1
 
 
 def _scene(bs, mb, s=4, h=H, d=D, seed=0, quantized=False,
-           dtype=np.float32):
+           dtype=np.float32, ctx=None):
     """One decode scene: a whole pool in the engine's block view
     [L, 2, nb, bs, h*d] (the layers other than LAYER hold garbage of
     their own — a kernel that read the wrong layer would see it),
     per-lane tables and RAGGED ctx_lens — lane 0 is freshly preempted
     (null table, ctx 0: a dead lane), lane 1 holds a partial first
     block, the last lane is block-aligned full; the rest end inside a
-    block.  Tables beyond each lane's blocks stay null and pool
-    contents are garbage there — the mask must hide all of it."""
+    block (or the lanes' `ctx` as given).  Tables beyond each lane's
+    blocks stay null and pool contents are garbage there — the mask
+    must hide all of it."""
     rng = np.random.default_rng(seed)
     nb = s * mb + 1
     pool = jnp.asarray(rng.normal(size=(L, 2, nb, bs, h, d)), dtype)
     tables = np.zeros((s, mb), np.int32)
     perm = 1 + rng.permutation(nb - 1)
-    ctx = np.zeros(s, np.int32)
     choices = [0, max(1, bs // 2)] + [
         int(rng.integers(1, mb * bs)) for _ in range(max(0, s - 3))
-    ] + [mb * bs]
+    ] + [mb * bs] if ctx is None else list(ctx)
+    ctx = np.zeros(s, np.int32)
     for i in range(s):
         ctx[i] = choices[i]
         used = -(-int(ctx[i]) // bs)
@@ -93,14 +94,21 @@ def _concat_reference(sc):
     return np.asarray(out[:, 0])
 
 
-def _paged(sc, impl, block_gather=None):
-    return np.asarray(paged_decode_attention(
-        jnp.asarray(sc["q"]), jnp.asarray(sc["new_k"]),
-        jnp.asarray(sc["new_v"]), sc["kv_pool"],
-        jnp.asarray(sc["tables"]), jnp.asarray(sc["ctx"]),
-        layer=LAYER, kv_scale=sc["kv_scale"],
-        impl=impl, block_gather=block_gather,
-        interpret=(True if impl == "pallas" else None)))
+def _paged(sc, impl, block_gather=None, traced_layer=False):
+    """`traced_layer`: LAYER reaches the call as a traced int32 scalar,
+    as a looped decoder's pool slot does (the kernel's scalar
+    prefetch, the XLA form's gather index)."""
+    def run(layer):
+        return paged_decode_attention(
+            jnp.asarray(sc["q"]), jnp.asarray(sc["new_k"]),
+            jnp.asarray(sc["new_v"]), sc["kv_pool"],
+            jnp.asarray(sc["tables"]), jnp.asarray(sc["ctx"]),
+            layer=layer, kv_scale=sc["kv_scale"],
+            impl=impl, block_gather=block_gather,
+            interpret=(True if impl == "pallas" else None))
+    if traced_layer:
+        return np.asarray(jax.jit(run)(jnp.int32(LAYER)))
+    return np.asarray(run(LAYER))
 
 
 # ----------------------------------------------------------------------
@@ -133,13 +141,19 @@ def test_pallas_parity_across_block_sizes_and_gather_configs():
                 err_msg=f"bs={bs} cfg={cfg}")
 
 
-#: (heads, head_dim): GPT-2 small's 12 x 64 = 768 merged columns, six
-#: whole lane tiles; and a toy width whose 4 x 16 = 64 is no multiple
-#: of 128, which only has to be correct
-WIDTHS = {"768": (12, 64), "toy64": (4, 16)}
+#: (heads, head_dim, table blocks, lane contexts, layer traced):
+#: GPT-2 small's 12 x 64 = 768 merged columns, six whole lane tiles; a
+#: toy width whose 4 x 16 = 64 is no multiple of 128, which only has to
+#: be correct (both: `_scene`'s contexts, LAYER a Python int); and the
+#: looped decoder's 16 x 128 = 2048 (`ouro_2p6b_serve`: tables of 20
+#: blocks, lanes dead, at one position, mid-block and at a full table,
+#: the pool slot a traced int32 scalar)
+WIDTHS = {"768": (12, 64, 4, None, False),
+          "toy64": (4, 16, 4, None, False),
+          "looped2048": (16, 128, 20, (0, 1, 121, 320), True)}
 
 
-@pytest.mark.parametrize("block_gather", [1, 2, 4])
+@pytest.mark.parametrize("block_gather", [1, 2, 4, 8])
 @pytest.mark.parametrize("pool", ["bf16", "int8"])
 @pytest.mark.parametrize("width", sorted(WIDTHS))
 def test_pallas_on_the_merged_layout(width, pool, block_gather):
@@ -147,15 +161,18 @@ def test_pallas_on_the_merged_layout(width, pool, block_gather):
     [L, 2, nb, bs, h*d], the layer an index — against the XLA fallback
     and, through it, the concat oracle: bf16 and int8 pools, every
     gather width, a context that ends inside a block, a dead lane."""
-    h, d = WIDTHS[width]
-    sc = _scene(bs=16, mb=4, s=4, h=h, d=d, seed=31 + block_gather,
+    h, d, mb, ctx, traced = WIDTHS[width]
+    sc = _scene(bs=16, mb=mb, s=4, h=h, d=d, seed=31 + block_gather,
                 quantized=pool == "int8",
-                dtype=jnp.bfloat16 if pool == "bf16" else np.float32)
+                dtype=jnp.bfloat16 if pool == "bf16" else np.float32,
+                ctx=ctx)
     assert sc["kv_pool"].shape[-1] == h * d
     assert sc["ctx"][0] == 0 and 0 < sc["ctx"][2] % 16   # dead, ragged
+    assert sc["ctx"][-1] == mb * 16                      # a full table
     ref = _paged(sc, "xla")
     np.testing.assert_array_equal(ref, _concat_reference(sc))
-    out = _paged(sc, "pallas", block_gather=block_gather)
+    out = _paged(sc, "pallas", block_gather=block_gather,
+                 traced_layer=traced)
     np.testing.assert_allclose(out, ref, atol=3e-5, rtol=3e-5)
     # the dead lane is pure self-attention, whatever the pool holds
     np.testing.assert_allclose(out[0], sc["new_v"][0], atol=1e-6)
@@ -275,6 +292,17 @@ def test_decode_default_table_entries_resolve(clean_tuner):
                               dtype, "tpu")
         assert key in entries, key
         assert entries[key]["config"]["block_gather"] >= 1
+    # the looped decoder's key (16 lanes, 16 heads x 128, a bf16 pool,
+    # tables of 20 blocks): its row resolves through the dispatch
+    # path's lookup, and its width is one the kernel's VMEM estimate
+    # still offers — gather 8 among them
+    key = tuning.make_key("paged_decode", {"bs": 16, "lanes": 16, "d": 128},
+                          jnp.bfloat16, "tpu")
+    assert key == "paged_decode|tpu|bfloat16|bs=16,d=128,lanes=16"
+    offered = [c["block_gather"]
+               for c in paged_decode_candidates(16, 20, 16, 128, 2)]
+    assert 8 in offered, offered
+    assert entries[key]["config"]["block_gather"] in offered
 
 
 def test_decode_tuning_persists_and_reloads(clean_tuner, tmp_path):

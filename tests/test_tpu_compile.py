@@ -239,7 +239,11 @@ def _paged_traced_layer(place):
 
 
 CASES = {
+    # 16 rows a grid step: the head-indicator body; 32 and up: the
+    # block-diagonal one (`_BLOCK_DIAGONAL_ROWS`), both for each pool
     "paged_bf16_g1": _paged(jnp.bfloat16, 1),
+    "paged_bf16_g2": _paged(jnp.bfloat16, 2),
+    "paged_int8_g1": _paged(jnp.int8, 1),
     # 8 = what ops/tuning/default_tables.json names for this key
     "paged_bf16_g8": _paged(jnp.bfloat16, 8),
     "paged_int8_g8": _paged(jnp.int8, 8),
