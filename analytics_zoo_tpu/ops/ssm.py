@@ -1,6 +1,7 @@
 """Selective state-space (Mamba-2) operators: the causal depthwise
-convolution in front of the scan, the chunked scan a prefill runs, and
-the one-token update a decode round runs.
+convolution in front of the scan (bias-free, also LFM2's short
+convolution: serving/generation/hybrid.py), the chunked scan a prefill
+runs, and the one-token update a decode round runs.
 
 The recurrence, for head `h` of group `g` with state `H_h` [P, S]:
 
@@ -43,9 +44,9 @@ def _check_impl(impl: str) -> None:
 
 def causal_conv(x, kernel, bias, tail=None):
     """Causal depthwise convolution over time: x [b, t, c], kernel
-    [k, c] (tap k-1 on the current position), bias [c], `tail`
-    [k-1, b, c] the k-1 rows before x (None: zeros, a sequence's
-    start).  float32 out, before the activation."""
+    [k, c] (tap k-1 on the current position), bias [c] (None: none),
+    `tail` [k-1, b, c] the k-1 rows before x (None: zeros, a
+    sequence's start).  float32 out, before the activation."""
     k = kernel.shape[0]
     b, t, c = x.shape
     xf = x.astype(jnp.float32)
@@ -56,7 +57,7 @@ def causal_conv(x, kernel, bias, tail=None):
     padded = jnp.concatenate([before, xf], axis=1)      # [b, k-1+t, c]
     w = kernel.astype(jnp.float32)
     out = sum(padded[:, j:j + t] * w[j] for j in range(k))
-    return out + bias.astype(jnp.float32)
+    return out if bias is None else out + bias.astype(jnp.float32)
 
 
 def conv_tail(x, length, k: int):

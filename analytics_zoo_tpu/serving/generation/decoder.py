@@ -265,7 +265,8 @@ class ExpertLayer(nn.Module):
     every one of them, at 1 token or 1,024: the row buffer holds all
     `top_k * tokens` assignments, so none can be dropped — and the
     others are left to the chips that hold them: nothing here stands
-    in for those chips or their traffic.  The shared expert is whole.
+    in for those chips or their traffic.  The shared expert is whole;
+    with `num_shared_experts` 0 there is none, and no branch for it.
 
     Two forms of expert.  `gated` (the default): down(silu(gate x) *
     up x) at the hidden size, the shared expert alike.  Not gated:
@@ -365,16 +366,19 @@ class ExpertLayer(nn.Module):
                                   name="latent_out")(
                     routed.astype(self.dtype)).astype(jnp.float32)
 
-        with jax.named_scope("moe.shared"):
-            shared = (GatedMLP if self.gated else SquaredReluMLP)(
-                self.shared_width or self.width * self.num_shared_experts,
-                dtype=self.dtype, param_dtype=self.param_dtype,
-                name="shared")(flat.astype(self.dtype))
+        shared = None
+        if self.num_shared_experts:
+            with jax.named_scope("moe.shared"):
+                shared = (GatedMLP if self.gated else SquaredReluMLP)(
+                    self.shared_width
+                    or self.width * self.num_shared_experts,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="shared")(flat.astype(self.dtype))
         counts = jnp.concatenate([
             sizes, jnp.stack([mine.sum(), real.sum() * k]
                              ).astype(jnp.int32)])
-        y = (routed + shared.astype(jnp.float32)).reshape(b, t, d)
-        return y, counts
+        y = routed if shared is None else routed + shared.astype(jnp.float32)
+        return y.reshape(b, t, d), counts
 
 
 class DecoderLM(nn.Module):

@@ -654,8 +654,9 @@ class GenerationEngine:
     def recurrent_state(self, slot: int):
         """What the state layers hold for lane `slot` as the device
         has it once everything enqueued has run (a fetch): {"ssm": one
-        [*state shape] array a state layer, "conv": one [rows,
-        channels] a layer}, or None for a model without state layers.
+        [*state shape] array a state layer (none where the layers have
+        no scan), "conv": one [rows, channels] a layer}, or None for a
+        model without state layers.
         A finished lane's stays until its slot is admitted again: the
         state after its prompt and all but the last of its tokens."""
         if self.state_pool is None:

@@ -133,8 +133,9 @@ def pool_rows(model) -> int:
 
 def state_geometry(model):
     """What the recurrent pool holds a lane for `model`: (state
-    layers, (shape, dtype) of a layer's scan state, (shape, dtype) of
-    its convolution tail), as the model says of itself
+    layers, (shape, dtype) of a layer's scan state or None where it
+    has none, (shape, dtype) of its convolution tail), as the model
+    says of itself
     (`state_geometry()`: hybrid.py), or None for a model all of whose
     state is keys and values.  The one place it is read from a model."""
     geometry = getattr(model, "state_geometry", None)
@@ -256,15 +257,17 @@ def write_kv_blocks(kv, kv_scale, blocks, new_k, new_v, block_size: int):
 
 
 class RecurrentStatePool:
-    """The second kind of state: what a state-space layer carries for a
-    lane, of a FIXED size whatever the lane's context, so it is found
-    by the lane's slot and not through a block table.  A state layer
-    holds two arrays, both functional state like `PagedKVCache.kv`
-    (the `decode` and `prefill` programs take them donated, inside the
-    lane state, and hand them back):
+    """The second kind of state: what a state layer carries for a lane,
+    of a FIXED size whatever the lane's context, so it is found by the
+    lane's slot and not through a block table.  A state layer holds
+    one or two arrays, functional state like `PagedKVCache.kv` (the
+    `decode` and `prefill` programs take them donated, inside the lane
+    state, and hand them back):
 
       * "ssm": `[max_slots, *state shape]` (float32: the scan's state
-        is summed into for a lane's whole life);
+        is summed into for a lane's whole life) — a Mamba-2 layer's;
+        an empty tuple where the layers have no scan (LFM2's short
+        convolution: its tail is all its state);
       * "conv": `[rows, max_slots, channels]`, the rows before the
         next position the convolution still needs — the slot axis
         second, so that the two minor axes fill whole tiles (3 rows
@@ -279,18 +282,22 @@ class RecurrentStatePool:
     preempted lane's state is rebuilt by the prefill of its resume."""
 
     def __init__(self, geometry, max_slots: int):
-        self.n_layers, (ssm, ssm_dtype), (conv, conv_dtype) = geometry
+        self.n_layers, ssm, (conv, conv_dtype) = geometry
         self.max_slots = max_slots
-        self.shapes = {
-            "ssm": ((max_slots,) + tuple(ssm), jnp.dtype(ssm_dtype)),
-            "conv": ((conv[0], max_slots) + tuple(conv[1:]),
-                     jnp.dtype(conv_dtype))}
+        self.shapes = {}
+        if ssm is not None:
+            state, ssm_dtype = ssm
+            self.shapes["ssm"] = ((max_slots,) + tuple(state),
+                                  jnp.dtype(ssm_dtype))
+        self.shapes["conv"] = ((conv[0], max_slots) + tuple(conv[1:]),
+                               jnp.dtype(conv_dtype))
 
     def zeros(self):
         """The pool, empty, on the default device."""
-        return {kind: tuple(jnp.zeros(shape, dtype)
+        return {kind: tuple(jnp.zeros(*self.shapes[kind])
                             for _ in range(self.n_layers))
-                for kind, (shape, dtype) in self.shapes.items()}
+                if kind in self.shapes else ()
+                for kind in ("ssm", "conv")}
 
     @property
     def nbytes(self) -> int:

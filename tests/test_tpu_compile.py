@@ -81,16 +81,17 @@ def _paged(pool_dtype, block_gather, lanes=8, d=64, bs=16):
     return build
 
 
-def _paged_gqa(block_gather, window):
-    """The grouped-query kernel at `kexaone_236b_ep8_serve`'s decode
-    shapes: 64 lanes, 64 query heads over 8 KV heads of 128 (pool rows
-    of 1024), block 16, tables for a 1024 context, a bf16 pool; with
-    the 128-position window of a window layer or without."""
+def _paged_gqa(block_gather, window, lanes=64, h=64, d=128,
+               context=1024):
+    """The grouped-query kernel, by default at `kexaone_236b_ep8_serve`'s
+    decode shapes: 64 lanes, 64 query heads over 8 KV heads of 128
+    (pool rows of 1024), block 16, tables for a 1024 context, a bf16
+    pool; with the 128-position window of a window layer or without."""
     def build(place):
         from analytics_zoo_tpu.ops.pallas.paged_attention import (
             paged_decode_pallas)
-        lanes, h, g, d, bs = 64, 64, 8, 128, 16
-        mb = 1024 // bs
+        g, bs = 8, 16
+        mb = context // bs
         nb = lanes * mb + 1
         args = [place((lanes, h * d), jnp.bfloat16),
                 place((lanes, g * d), jnp.bfloat16),
@@ -252,6 +253,11 @@ CASES = {
     "paged_gqa_bf16_g8_window128": _paged_gqa(8, 128),
     "paged_gqa_bf16_g1_window128": _paged_gqa(1, 128),
     "paged_bf16_g8_window128": _paged_window,
+    # `lfm2_24b_a2b_serve`'s decode: 128 lanes, 32 query heads over 8 KV
+    # heads of 64 — a KV head's columns half a lane tile of the staged
+    # block — tables for a 2816 context
+    "paged_gqa_bf16_d64_g1": _paged_gqa(1, None, 128, 32, 64, 2816),
+    "paged_gqa_bf16_d64_g8": _paged_gqa(8, None, 128, 32, 64, 2816),
     # None = `LATENT_BLOCK_GATHER` (32), what the latent op asks for
     "latent_bf16_default": _latent(None),
     "latent_bf16_g1": _latent(1),
@@ -370,11 +376,20 @@ def _expert_models():
     at its published widths (`kexaone_236b_ep8_serve`: gated experts
     at the hidden size; `nemotron3_super_120b_ep4_serve`: un-gated, in
     a latent space; `sarvam_105b_ep8_serve`: gated, behind latent
-    attention), with the lanes its cell decodes."""
+    attention; `lfm2_24b_a2b_serve`: gated, no shared expert), with the
+    lanes its cell decodes."""
     from analytics_zoo_tpu.serving.generation import DecoderLM
     from analytics_zoo_tpu.serving.generation.hybrid import HybridLM
     bf16 = dict(compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     return {
+        # gated experts at the hidden size with no shared expert, behind
+        # attention with q/k norms and rotary positions
+        "lfm2": (128, HybridLM(
+            vocab=65536, hidden_size=2048, pattern="*E", n_head=32,
+            n_kv_head=8, head_dim=64, rope_theta=1e6, qk_norm=True,
+            moe_intermediate_size=1536, moe_gated=True,
+            num_shared_experts=0, num_experts=64, num_experts_per_tok=4,
+            norm_eps=1e-5, max_position_len=128000, **bf16)),
         "kexaone": (64, DecoderLM(
             vocab=19200, hidden_size=6144, n_head=64, n_kv_head=8,
             head_dim=128, layer_types=("full_attention",),
@@ -409,7 +424,8 @@ def _expert_models():
     }
 
 
-@pytest.mark.parametrize("config", ["kexaone", "nemotron3", "sarvam"])
+@pytest.mark.parametrize("config", ["kexaone", "nemotron3", "sarvam",
+                                    "lfm2"])
 def test_expert_decode_holds_the_grouped_kernel(topo, monkeypatch, config):
     """The `decode` program of each expert configuration, lowered for
     the described v5e from shapes alone (no weight is made): every
@@ -553,3 +569,68 @@ def test_engine_steps_leave_the_pool_where_it_lies(topo, monkeypatch):
         window = re.search(r"update_window_dims=\{([\d,]+)\}",
                            by_block[0]).group(1)
         assert len(window.split(",")) == 2, by_block[0][:400]
+
+
+def test_conv_tails_stay_where_they_lie(topo, monkeypatch):
+    """The `decode` and 2048-bucket `prefill` programs of an LFM2 stack
+    cut to two layers (a dense convolution layer, a routed attention
+    layer) at `lfm2_24b_a2b_serve`'s widths and lanes, compiled from
+    shapes for the described v5e: the recurrent pool holds the tail
+    alone (no scan state), both programs alias it — and the KV pool —
+    in place, and neither copies a tail."""
+    import re
+
+    from analytics_zoo_tpu.serving.generation import (
+        HybridLM, RecurrentStatePool, lane_state, steps)
+    from analytics_zoo_tpu.serving.generation.kv_cache import (
+        PagedKVCache, pool_geometry)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = HybridLM.from_config(dict(
+        model_type="lfm2_moe", vocab_size=65536, hidden_size=2048,
+        layer_types=["conv", "full_attention"], num_dense_layers=1,
+        num_attention_heads=32, num_key_value_heads=8, conv_L_cache=3,
+        conv_bias=False, intermediate_size=11776,
+        moe_intermediate_size=1536, num_experts=64, num_experts_per_tok=4,
+        use_expert_bias=True, norm_topk_prob=True,
+        routed_scaling_factor=1.0, norm_eps=1e-5,
+        rope_parameters={"rope_theta": 1000000, "rope_type": "default"},
+        max_position_embeddings=128000), compute_dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16)
+    lanes, bs, blocks = 128, 16, 2816 // 16
+    width = lane_state.TABLE + blocks
+    layers, kv_heads, head_dim = pool_geometry(model)
+    prefill, _, decode = steps.build_steps(
+        model, block_size=bs, n_head=kv_heads, quantized=False,
+        paged=True, width=width, counted=True, stateful=True,
+        prefill_variants=4)[:3]
+    pool = RecurrentStatePool(model.state_geometry(), lanes)
+    assert set(pool.shapes) == {"conv"}
+    params, kv, key, tails = jax.eval_shape(lambda: (
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
+                   jnp.arange(8)[None])["params"],
+        PagedKVCache(layers, 1 + lanes * blocks, bs, kv_heads, head_dim,
+                     dtype=jnp.bfloat16).kv,
+        jax.random.PRNGKey(0), pool.zeros()))
+    assert tails["ssm"] == ()
+    place = _one_chip(topo)
+
+    def like(x):
+        return place(x.shape, x.dtype)
+    state = jax.tree_util.tree_map(like, (
+        params, kv, jax.ShapeDtypeStruct((1,), jnp.float32),
+        {"rows": jax.ShapeDtypeStruct((lanes, width), jnp.int32),
+         "rng": key, "recurrent": tails}))
+    in_place = kv.size * 2 + pool.nbytes
+    tail = re.compile(r"= bf16\[2,128,2048\]\S* copy\(")
+    for name, fn, upload in (
+            ("decode", decode, (lanes, 1 + width)),
+            ("prefill", prefill, (1 + width + 2048,))):
+        compiled = fn.fn.lower(
+            *state, place(upload, jnp.int32)).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" in text, name
+        assert compiled.memory_analysis().alias_size_in_bytes >= in_place
+        copies = [ln.strip()[:200] for ln in text.splitlines()
+                  if tail.search(ln)]
+        assert not copies, f"{name} copies a tail: {copies}"
